@@ -18,7 +18,10 @@ from .callback import (EarlyStopException, early_stopping, log_evaluation,
                        record_evaluation, record_profile, reset_parameter)
 from .config import Config, resolve_params
 from .engine import CVBooster, cv, train
+from .runtime.device import configure_compile_cache
 from .utils.log import register_logger
+
+configure_compile_cache()
 
 __version__ = "0.1.0"
 

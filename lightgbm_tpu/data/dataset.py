@@ -108,6 +108,9 @@ class BinnedDataset:
         self.metadata: Optional[Metadata] = None
         self.max_bin: int = 255
         self.reference: Optional["BinnedDataset"] = None
+        # where the value->bin push of X_binned ran: "device" = the
+        # packed bin table (ops/bucketize.py), "host" = the BinMapper loop
+        self.binned_on: str = "host"
         # EFB (Exclusive Feature Bundling, dataset.cpp:112 FindGroups /
         # :251 FastFeatureBundling): sparse features whose non-default
         # rows never (max_conflict_rate=0) or rarely overlap share one
@@ -539,6 +542,7 @@ def construct_from_matrix(
         raw = np.ascontiguousarray(data[:, ds.real_feature_index],
                                    np.float32)
         X[:, :] = bin_rows_device(raw, table).astype(X.dtype)
+        ds.binned_on = "device"
     else:
         for inner, (m, orig) in enumerate(zip(ds.mappers,
                                               ds.real_feature_index)):

@@ -10,7 +10,10 @@ with `num_machines=N, tree_learner="data"` joins the group automatically
 (parallel/distributed.py reads the launcher's environment).
 
 Single-machine multi-process (the DistributedMockup pattern,
-tests/distributed/_test_distributed.py:53):
+tests/distributed/_test_distributed.py:53) — a CPU mock: every rank is
+one CPU device of this host. A chip belongs to one process, so the
+launcher refuses any other ``JAX_PLATFORMS``; one process drives all the
+chips of a TPU host (``tree_learner=data`` with no launcher at all):
 
     python -m lightgbm_tpu.launch -n 4 -- python train_rank.py
 
@@ -51,19 +54,26 @@ def launch_local(num_machines: int, argv: Sequence[str],
                  coordinator_port: Optional[int] = None,
                  env_extra: Optional[dict] = None,
                  timeout: Optional[float] = None) -> List[int]:
-    """Spawn `num_machines` copies of `argv` as one JAX process group on
-    this machine (each with ONE virtual CPU device unless the caller's
-    env says otherwise). Returns the list of exit codes; raises
-    RuntimeError if any worker failed."""
+    """Spawn `num_machines` copies of `argv` as one JAX process group of
+    CPU ranks on this machine (each with ONE virtual CPU device unless
+    the caller's XLA_FLAGS say otherwise). Returns the list of exit
+    codes; raises RuntimeError if any worker failed, or if the
+    environment asks for an accelerator the ranks would have to share."""
+    base_env = dict(os.environ)
+    base_env.update(env_extra or {})
+    if base_env.setdefault("JAX_PLATFORMS", "cpu") != "cpu":
+        raise RuntimeError(
+            "launch_local is a CPU mock of a multi-host group: its ranks "
+            "cannot share a chip (JAX_PLATFORMS="
+            f"{base_env['JAX_PLATFORMS']!r}); run one process per TPU "
+            "host, or unset JAX_PLATFORMS")
     port = coordinator_port or _free_port()
     procs = []
     for rank in range(num_machines):
-        env = dict(os.environ)
-        env.update(env_extra or {})
+        env = dict(base_env)
         env["LIGHTGBM_TPU_RANK"] = str(rank)
         env["LIGHTGBM_TPU_NPROC"] = str(num_machines)
         env["LIGHTGBM_TPU_COORDINATOR"] = f"127.0.0.1:{port}"
-        env.setdefault("JAX_PLATFORMS", "cpu")
         env.setdefault("XLA_FLAGS",
                        "--xla_force_host_platform_device_count=1")
         procs.append(subprocess.Popen(list(argv), env=env))

@@ -66,9 +66,9 @@ _M_MISS_BIN = 4   # categorical: bin id for unseen/invalid values
 _M_NEG_INV = 5    # categorical: 1.0 = negative values are invalid (serve)
 _META_COLS = 8
 
-_ROW_TILE = 256           # rows per Pallas grid step (lane dim of out)
+_ROW_TILE = 256           # rows per Pallas grid step (sublane dim of x/out)
 _LANES = 128              # bin-table lane quantum
-_SUBLANES = 32            # feature-axis padding quantum (u8 tile sublanes)
+_SUBLANES = 32            # table feature-axis padding quantum
 
 # largest integer magnitude where every int is f32-exact
 _F32_EXACT_INT = 1 << 24
@@ -114,14 +114,12 @@ def resolve_binning_impl(knob: str = "auto") -> str:
         return "host"
     if knob in ("host", "device"):
         return knob
+    import jax
+
     from .histogram import pallas_interpret
-    if pallas_interpret():
+    if pallas_interpret() or jax.default_backend() == "tpu":
         return "device"
-    try:
-        import jax
-        return "device" if jax.default_backend() == "tpu" else "host"
-    except Exception:                                  # noqa: BLE001
-        return "host"
+    return "host"
 
 
 # ----------------------------------------------------------------------
@@ -257,12 +255,14 @@ def stack_bin_tables(tables: Sequence[DeviceBinTable]) -> DeviceBinTable:
 # device compute: XLA reference (kernel-true) + Pallas kernel
 # ----------------------------------------------------------------------
 def _bin_block(x, tab, cv, meta):
-    """The bucketize math for one block — shared verbatim by the XLA
-    reference and the Pallas kernel body, so the two cannot drift.
-    ``x`` [..., R] f32 values; ``tab``/``cv`` [..., B]; ``meta``
-    [..., 8]; broadcasting supplies the feature axis. Every op is an
-    exact predicate or a small-int f32 sum, so the result is
-    bit-identical across backends."""
+    """The bucketize math for one block — shared verbatim by the Pallas
+    kernel body and the stacked fleet variant, so the two cannot drift.
+    ``x`` [..., R, 1] f32 values; ``tab``/``cv`` [..., 1, B]; ``meta``
+    [..., 1, 8]; returns [..., R, 1] f32 bin ids. Every operand keeps
+    its two minor axes (rows on sublanes, bins on lanes), which is what
+    lets Mosaic tile the body; every op is an exact predicate or a
+    small-int f32 sum, so the result is bit-identical across
+    backends."""
     import jax.numpy as jnp
 
     is_cat = meta[..., _M_IS_CAT:_M_IS_CAT + 1]
@@ -272,11 +272,11 @@ def _bin_block(x, tab, cv, meta):
     miss_bin = meta[..., _M_MISS_BIN:_M_MISS_BIN + 1]
     neg_inv = meta[..., _M_NEG_INV:_M_NEG_INV + 1]
 
-    nanm = x != x                                         # [..., R]
+    nanm = x != x                                         # [..., R, 1]
     # numeric: count of floored bounds strictly below v == f64
     # searchsorted(side="left"), then the inclusive-bound clamp
-    lt = (tab[..., None, :] < x[..., :, None])            # [..., R, B]
-    cnt = jnp.sum(lt.astype(jnp.float32), axis=-1)
+    lt = tab < x                                          # [..., R, B]
+    cnt = jnp.sum(lt.astype(jnp.float32), axis=-1, keepdims=True)
     num_out = jnp.minimum(cnt, clamp)
     num_out = jnp.where(nanm, nan_bin, num_out)
     # categorical: trunc(v) == host astype(int64) for every f32 v;
@@ -284,33 +284,41 @@ def _bin_block(x, tab, cv, meta):
     vi = jnp.trunc(x)
     vi = jnp.where(nanm, nan_key, vi)
     vi = jnp.where((x < 0) & (neg_inv > 0), jnp.float32(-2.0), vi)
-    eq = tab[..., None, :] == vi[..., :, None]            # [..., R, B]
-    hit = jnp.sum(eq.astype(jnp.float32), axis=-1)
-    catv = jnp.sum(jnp.where(eq, cv[..., None, :], jnp.float32(0.0)),
-                   axis=-1)
+    eq = tab == vi                                        # [..., R, B]
+    hit = jnp.sum(eq.astype(jnp.float32), axis=-1, keepdims=True)
+    catv = jnp.sum(jnp.where(eq, cv, jnp.float32(0.0)), axis=-1,
+                   keepdims=True)
     cat_out = jnp.where(hit > 0, catv, miss_bin)
     return jnp.where(is_cat > 0, cat_out, num_out)
 
 
-def _bucketize_kernel(x_ref, tab_ref, cv_ref, meta_ref, out_ref):
-    """Pallas body: one [F_pad, R] row tile against the full bin table.
-    fori over features; per feature a [R, B] predicate block on the VPU
-    (B rides the 128-lane axis), reduced along bins."""
+def _bucketize_kernel(x_ref, tab_ref, cv_ref, meta_ref, out_ref, *, F):
+    """Pallas body: one [R, F] row tile (the caller's own row-major
+    layout) against the full bin table. fori over features; per feature
+    the column is lifted out by a masked lane reduction (a NaN/inf value
+    survives the sum: every other lane adds an exact 0), binned as an
+    [R, B] predicate block on the VPU (B rides the 128-lane axis,
+    reduced along bins), and dropped into its output lane by a select
+    against the same lane iota. The result leaves as int32 — Mosaic has
+    no f32 -> 8-bit narrowing — and the wrapper narrows to uint8."""
     import jax
     import jax.numpy as jnp
+    from jax.experimental import pallas as pl
 
-    F = x_ref.shape[0]
+    x = x_ref[...]                                        # [R, F] f32
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
 
-    def body(f, carry):
-        x = x_ref[f, :]                                   # [R]
-        tab = tab_ref[f, :]                               # [B]
-        cv = cv_ref[f, :]
-        meta = meta_ref[f, :]                             # [8]
-        res = _bin_block(x, tab, cv, meta)
-        out_ref[f, :] = res.astype(jnp.uint8)
-        return carry
+    def body(f, acc):
+        here = lane == f
+        col = jnp.sum(jnp.where(here, x, jnp.float32(0.0)), axis=1,
+                      keepdims=True)                      # [R, 1]
+        res = _bin_block(col, tab_ref[pl.ds(f, 1), :],
+                         cv_ref[pl.ds(f, 1), :],
+                         meta_ref[pl.ds(f, 1), :])        # [R, 1]
+        return jnp.where(here, res.astype(jnp.int32), acc)
 
-    jax.lax.fori_loop(0, F, body, 0)
+    out_ref[...] = jax.lax.fori_loop(0, F, body,
+                                     jnp.zeros(x.shape, jnp.int32))
 
 
 def _pallas_ok(B: int) -> bool:
@@ -324,18 +332,15 @@ def _pallas_ok(B: int) -> bool:
         return False
     if B > 4096:
         return False
-    if pallas_interpret():
-        return True
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    return pallas_interpret() or jax.default_backend() == "tpu"
 
 
 def _bucketize_pallas(X, t: DeviceBinTable):
     """X [n, F] f32 -> [n, F] u8 via the Pallas kernel (grid over row
     tiles; the bin table is one VMEM-resident block: F_pad*B*8 bytes,
     ~256 KiB at 256 features x 128 bins — docs/PERF.md §8)."""
+    import functools
+
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -346,23 +351,22 @@ def _bucketize_pallas(X, t: DeviceBinTable):
     F_pad, B = t.table.shape
     n = X.shape[0]
     n_pad = max(_round_up(n, _ROW_TILE), _ROW_TILE)
-    Xt = jnp.transpose(X.astype(jnp.float32))             # [F, n]
-    Xt = jnp.pad(Xt, ((0, F_pad - F), (0, n_pad - n)))
+    Xp = jnp.pad(X.astype(jnp.float32), ((0, n_pad - n), (0, 0)))
     out = pl.pallas_call(
-        _bucketize_kernel,
+        functools.partial(_bucketize_kernel, F=F),
         grid=(n_pad // _ROW_TILE,),
         in_specs=[
-            pl.BlockSpec((F_pad, _ROW_TILE), lambda i: (0, i)),
+            pl.BlockSpec((_ROW_TILE, F), lambda i: (i, 0)),
             pl.BlockSpec((F_pad, B), lambda i: (0, 0)),
             pl.BlockSpec((F_pad, B), lambda i: (0, 0)),
             pl.BlockSpec((F_pad, _META_COLS), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((F_pad, _ROW_TILE), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((F_pad, n_pad), jnp.uint8),
+        out_specs=pl.BlockSpec((_ROW_TILE, F), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_pad, F), jnp.int32),
         interpret=pallas_interpret(),
-    )(Xt, jnp.asarray(t.table), jnp.asarray(t.cat_val),
+    )(Xp, jnp.asarray(t.table), jnp.asarray(t.cat_val),
       jnp.asarray(t.meta))
-    return jnp.transpose(out[:F, :n])
+    return out[:n].astype(jnp.uint8)
 
 
 def _bucketize_xla(X, t: DeviceBinTable):
@@ -466,11 +470,10 @@ def bucketize_rows_stacked(X, t: DeviceBinTable, tid, *,
         cv_g = cv[:, f0:f1, :][tid]
         meta_g = meta[:, f0:f1, :][tid]                   # [n, Ft, 8]
         # each (row, feature) pair has its own table: R is a singleton
-        res = _bin_block(jnp.transpose(Xf[:, f0:f1])[..., None],
-                         jnp.transpose(tab_g, (1, 0, 2)),
-                         jnp.transpose(cv_g, (1, 0, 2)),
-                         jnp.transpose(meta_g, (1, 0, 2)))
-        outs.append(jnp.transpose(res[..., 0].astype(jnp.uint8)))
+        res = _bin_block(Xf[:, f0:f1, None, None],
+                         tab_g[:, :, None, :], cv_g[:, :, None, :],
+                         meta_g[:, :, None, :])           # [n, Ft, 1, 1]
+        outs.append(res[..., 0, 0].astype(jnp.uint8))
     return jnp.concatenate(outs, axis=1)
 
 

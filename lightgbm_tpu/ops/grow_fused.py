@@ -222,13 +222,11 @@ def _fused_scan(out_ref, parent_ref, scal_ref, meta_ref, rec_ref, *,
         # (row hb*C*K + c*K + k holds feature-major LO-wide lo-bins of
         # hi-block hb, channel c) — HB*C single-row loads, then the same
         # unflatten _unflatten_hist does outside, minus the K axis
-        rows = [pl.load(out_ref, (pl.ds(hb * C * K + c * K + k, 1),
-                                  slice(None)))
+        rows = [out_ref[pl.ds(hb * C * K + c * K + k, 1), :]
                 for hb in range(HB) for c in range(C)]      # [1, Fh*LO]
         sm = jnp.concatenate(rows, axis=0).reshape(HB, C, Fh, LO)
         sm = sm.transpose(1, 2, 0, 3).reshape(C, Fh, HB * LO)[:, :F, :B]
-        par = pl.load(parent_ref, (pl.ds(k, 1), slice(None))) \
-            .reshape(C, F, B)
+        par = parent_ref[pl.ds(k, 1), :].reshape(C, F, B)
         sil = scal_ref[4, col] != 0.0
         # the left child holds the small histogram iff smaller_is_left
         use_small = is_left == sil
@@ -424,13 +422,11 @@ def _fused_scan_tiled(out_ref, parent_ref, scal_ref, meta_ref, fm_ref,
         k = jnp.where(j < K, j, j - K)
         is_left = j < K
         col = jnp.where(is_left, k, KMAX + k)
-        rows = [pl.load(out_ref, (pl.ds(hb * C * K + c * K + k, 1),
-                                  slice(None)))
+        rows = [out_ref[pl.ds(hb * C * K + c * K + k, 1), :]
                 for hb in range(HB) for c in range(C)]      # [1, Th*LO]
         sm = jnp.concatenate(rows, axis=0).reshape(HB, C, Th, LO)
         sm = sm.transpose(1, 2, 0, 3).reshape(C, Th, HB * LO)[:, :T, :B]
-        par = pl.load(parent_ref, (pl.ds(k, 1), slice(None))) \
-            .reshape(C, T, B)
+        par = parent_ref[pl.ds(k, 1), :].reshape(C, T, B)
         sil = scal_ref[4, col] != 0.0
         use_small = is_left == sil
         ch = jnp.where(use_small, sm, par - sm)             # [C, T, B]
@@ -443,7 +439,7 @@ def _fused_scan_tiled(out_ref, parent_ref, scal_ref, meta_ref, fm_ref,
         pout = scal_ref[3, col]
         bmin = scal_ref[5, col]
         bmax = scal_ref[6, col]
-        fm = pl.load(fm_ref, (pl.ds(col, 1), slice(None)))[0, :T] != 0
+        fm = fm_ref[pl.ds(col, 1), :][0, :T] != 0
         hist3 = synth_count_channel(ch, cnt, sh)
         res, raw = find_best_split(hist3, sg, sh, cnt, pout, meta_k, hp,
                                    fm, leaf_min=bmin, leaf_max=bmax,

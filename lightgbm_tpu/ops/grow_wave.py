@@ -299,8 +299,21 @@ def grow_tree_wave(
     # XLA (the wave_apply dec layout).
     _vetoes = fused_veto_reasons(cfg, meta, dist is not None,
                                  _use_pallas(X_t, B))
+    from .histogram import pallas_interpret
+    if not _vetoes and not pallas_interpret():
+        # selected on a chip: fail here, not in an autotune probe. The
+        # in-kernel scan traces ops/split.py's search (cumsum, 3-D
+        # reshapes, flat argmax) and reads K-row parent blocks, none of
+        # which Mosaic lowers (CHANGES.md, PR 22)
+        raise NotImplementedError(
+            "histogram_impl=fused does not compile for the TPU: the fused "
+            "megakernels (ops/grow_fused.py) run under "
+            "LIGHTGBM_TPU_PALLAS_INTERPRET only; use histogram_impl=auto")
+    # (the narrow kernel sizes its parent slab from the 32-row padded
+    # block, so it is exact only at 32 storage columns; narrower data
+    # rides the tiled kernel as one partial tile)
     use_fused = (use_mega and not _vetoes
-                 and not quant
+                 and not quant and X_t.shape[0] == 32
                  and meta.monotone is None and meta.inter_sets is None
                  and not cfg.has_categorical)
     use_fused_tiled = not _vetoes and not use_fused

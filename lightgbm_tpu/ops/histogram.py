@@ -58,12 +58,7 @@ def _use_pallas(X_binned_t: jnp.ndarray, num_bins: int) -> bool:
         return False
     if num_bins > 256 or X_binned_t.dtype not in (jnp.uint8, jnp.int8):
         return False
-    if pallas_interpret():
-        return True
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    return pallas_interpret() or jax.default_backend() == "tpu"
 
 
 def _tier_route(tiers, F: int, num_bins: int, impl: str):
@@ -238,11 +233,8 @@ def take_leaf_values(values: jnp.ndarray,
     if os.environ.get("LIGHTGBM_TPU_DISABLE_PALLAS", "").lower() \
             in ("1", "true", "yes"):
         return values[leaf_of_row]
-    try:
-        on_tpu = jax.default_backend() == "tpu"
-    except RuntimeError:
-        on_tpu = False
-    if on_tpu and values.ndim == 1 and values.shape[0] <= 2048:
+    if jax.default_backend() == "tpu" and values.ndim == 1 \
+            and values.shape[0] <= 2048:
         from .histogram_pallas import take_leaf_values_pallas
         return take_leaf_values_pallas(values, leaf_of_row)
     return values[leaf_of_row]

@@ -61,6 +61,13 @@ from ..utils import round_up as _round_up
 F_BLK = 32          # int8 sublane tile
 N_BLK = 2048        # rows per grid step
 
+# Mosaic's default scoped-VMEM cap (16 MiB on a v5e, of 128 MiB physical)
+# is below the wide-bin (B = 256) float contraction's working set: the
+# hi/lo build keeps the [Fc*LO, R] bf16 lo one-hot AND one hi-masked copy
+# live (2 x 8 MB by the _feat_chunk budget) beside the output block. The
+# histogram kernels therefore state their own cap.
+HIST_VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+
 
 def _compute_dims(num_bins: int, wide_lo: int = 128):
     """B padded to a lane-friendly width; LO = one-hot compare width,
@@ -292,6 +299,8 @@ def build_histogram_slots_pallas(
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((rows, out_cols), out_dtype),
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=HIST_VMEM_LIMIT_BYTES),
         cost_estimate=pl.CostEstimate(
             flops=2 * K * C * (out_cols // LO) * Np * B,
             bytes_accessed=Fp * Np + (C * 4 + 4) * Np + rows * out_cols * 4,
@@ -584,6 +593,8 @@ def wave_pass_pallas(
             jax.ShapeDtypeStruct((rows, Fh * LO), out_dtype),
         ],
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=HIST_VMEM_LIMIT_BYTES),
         cost_estimate=pl.CostEstimate(
             flops=2 * K * C * Fh * Np * B,
             bytes_accessed=Fp * Np + (C * 4 + 8) * Np + rows * Fh * LO * 4,

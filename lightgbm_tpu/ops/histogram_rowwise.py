@@ -141,7 +141,9 @@ def _mv_accum(xx_all, W, out_ref, *, chunks, quantized):
         used = 0
         for (f0, m, w) in runs:
             iota3 = jax.lax.broadcasted_iota(jnp.int32, (m, w, R), 1)
-            parts.append((xx_all[f0:f0 + m, None, :] == iota3)
+            # slice, then add the bin axis: a combined [f0:f0+m, None, :]
+            # index traces to a gather, which Mosaic refuses
+            parts.append((xx_all[f0:f0 + m][:, None, :] == iota3)
                          .reshape(m * w, R).astype(w_dtype))
             used += m * w
         if used < cols:

@@ -21,11 +21,22 @@ COLLECTIVE_ERROR_MARKERS = ("collective", "all-reduce", "allreduce",
 
 def is_collective_error(exc: BaseException) -> bool:
     """True when `exc` looks like a failed cross-device collective
-    (injected CollectiveFault or a runtime error naming one)."""
+    (injected CollectiveFault or an XLA RUNTIME error naming one).
+
+    A trace or Pallas-lowering error is a plain Python exception and a
+    Mosaic compile error names a kernel, not a collective: neither may
+    degrade the exchange, whatever its text mentions — the program that
+    failed to build would fail the same way under any comm mode."""
+    import jax
+
     from ..runtime.faults import CollectiveFault
     if isinstance(exc, CollectiveFault):
         return True
+    if not isinstance(exc, jax.errors.JaxRuntimeError):
+        return False
     msg = str(exc).lower()
+    if "mosaic" in msg or "tpu_custom_call" in msg:
+        return False
     return any(m in msg for m in COLLECTIVE_ERROR_MARKERS)
 
 

@@ -33,30 +33,13 @@ from ..ops.split import FeatureMeta
 from .context import DATA_AXIS, DistContext
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, check_vma=False):
-    """`jax.shard_map` appeared (with `check_vma`) well after the
-    experimental API; older jax only has
-    `jax.experimental.shard_map.shard_map(check_rep=...)`. One call site
-    for both, so every mesh builder below works on either."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=bool(check_vma))
-
-
 def lane_multiple() -> int:
     """Device-derived row-pad granularity: TPU vector registers are
     (8, 128) tiles, so per-shard row counts that are multiples of 128
     avoid relayout padding inside every batched op; host/GPU backends
     tile fine at 8 (and 128 would waste real memory on tiny CPU-mesh
     tests)."""
-    try:
-        platform = jax.devices()[0].platform
-    except Exception:  # uninitialized backend: conservative default
-        return 8
-    return 128 if platform == "tpu" else 8
+    return 128 if jax.default_backend() == "tpu" else 8
 
 
 def pad_rows_to(n: int, num_shards: int, multiple: int = 0) -> int:
@@ -104,7 +87,7 @@ def build_data_parallel_train_fn(mesh: jax.sharding.Mesh,
     # works a feature slice inside the grower; outputs are replicated
     row = P() if replicate_rows else P(DATA_AXIS)
     rep = P()
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         step, mesh=mesh,
         in_specs=((P() if replicate_rows else P(None, DATA_AXIS)),
                   row, row, row, row, rep, rep, rep),
@@ -128,7 +111,7 @@ def build_sharded_score_fn(mesh: jax.sharding.Mesh, score_fn,
     `extra_row_args` extra PER-ROW 1-D operands (e.g. the fused scorer's
     tenant-id vector, export/fusion.py) shard along the same axis.
     """
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         score_fn, mesh=mesh,
         in_specs=(P(DATA_AXIS, None),) + (P(DATA_AXIS),) * extra_row_args,
         out_specs=P(None, DATA_AXIS),

@@ -81,11 +81,8 @@ def make_key(n_rows: int, n_features: int, max_bin: int, num_leaves: int,
     relabel-fusion, ``fused_variant_sig``) so a decision probed under
     one tiling never routes a differently-tiled run."""
     if not device_kind:
-        try:
-            import jax
-            device_kind = jax.local_devices()[0].device_kind
-        except Exception:
-            device_kind = "unknown"
+        import jax
+        device_kind = jax.local_devices()[0].device_kind
     dk = str(device_kind).replace(" ", "_")
     suffix = f"_{variant}" if variant else ""
     return f"r{int(n_rows)}_f{int(n_features)}_b{int(max_bin)}" \
@@ -154,6 +151,19 @@ def _block(out) -> None:
         if hasattr(x, "block_until_ready") else x, out)
 
 
+def _best_of_2(jitted, args, timer: Callable[[], float]) -> float:
+    """Compile + warm once, then the faster of two fenced runs."""
+    from .profiler import device_barrier
+    _block(jitted(*args))
+    best = float("inf")
+    for _ in range(2):
+        device_barrier()
+        t0 = timer()
+        _block(jitted(*args))
+        best = min(best, timer() - t0)
+    return best
+
+
 def probe_strategies(X_t, meta, cfg, candidates: Sequence[str],
                      probe_rows: int = DEFAULT_PROBE_ROWS, seed: int = 0,
                      timer: Callable[[], float] = time.perf_counter,
@@ -163,14 +173,13 @@ def probe_strategies(X_t, meta, cfg, candidates: Sequence[str],
 
     Gradients are synthetic (fixed ``seed``, binary-like: uniform grad in
     [-0.5, 0.5), constant hessian 0.25) so the probe exercises the real
-    split math without touching training state. A candidate that fails to
-    compile/run simply drops out of the timing table.
+    split math without touching training state. A candidate that fails
+    to compile or run raises: a probe returns timings, never a verdict on
+    what works.
     """
     import jax
     import jax.numpy as jnp
     import numpy as np
-
-    from .profiler import device_barrier
 
     n = int(X_t.shape[1])
     m = max(min(int(probe_rows), n), 1)
@@ -189,20 +198,7 @@ def probe_strategies(X_t, meta, cfg, candidates: Sequence[str],
             kw = {"rng_seed": jnp.int32(seed)} if _seed else {}
             return _fn(X, gg, hh, bb, meta, _cfg, **kw)
 
-        try:
-            jitted = jax.jit(run)
-            _block(jitted(Xs, g, h, bag))         # compile + warm
-            best = float("inf")
-            for _ in range(2):
-                device_barrier()
-                t0 = timer()
-                _block(jitted(Xs, g, h, bag))
-                best = min(best, timer() - t0)
-            timings[name] = best
-        except Exception as e:                    # noqa: BLE001
-            from ..utils.log import log_warning
-            log_warning(f"autotune: probe for grower '{name}' failed "
-                        f"({type(e).__name__}); dropping candidate")
+        timings[name] = _best_of_2(jax.jit(run), (Xs, g, h, bag), timer)
     return timings
 
 
@@ -220,7 +216,6 @@ def probe_rows_per_chunk(X_t, cfg, chunk_candidates: Sequence[int]
     import numpy as np
 
     from ..ops.histogram import build_histogram
-    from .profiler import device_barrier
 
     n = int(X_t.shape[1])
     m = max(min(int(probe_rows), n), 1)
@@ -237,18 +232,7 @@ def probe_rows_per_chunk(X_t, cfg, chunk_candidates: Sequence[int]
         def run(X, v, _rc=rc):
             return build_histogram(X, v, B, rows_per_chunk=_rc)
 
-        try:
-            jitted = jax.jit(run)
-            _block(jitted(Xs, vals))
-            best = float("inf")
-            for _ in range(2):
-                device_barrier()
-                t0 = timer()
-                _block(jitted(Xs, vals))
-                best = min(best, timer() - t0)
-            timings[rc] = best
-        except Exception:
-            pass
+        timings[rc] = _best_of_2(jax.jit(run), (Xs, vals), timer)
     return timings
 
 
@@ -279,7 +263,6 @@ def probe_hist_impls(X_t, cfg, impl_candidates: Sequence[str]
     import numpy as np
 
     from ..ops.histogram import _tier_route, build_histogram_slots
-    from .profiler import device_barrier
 
     n = int(X_t.shape[1])
     m = max(min(int(probe_rows), n), 1)
@@ -295,35 +278,19 @@ def probe_hist_impls(X_t, cfg, impl_candidates: Sequence[str]
     timings: Dict[str, float] = {}
     for impl in impl_candidates:
         if impl in ("rowwise", "rowwise_packed"):
-            try:
-                from ..ops.histogram_rowwise import rowwise_eligible
-                route = _tier_route(tiers, int(Xs.shape[0]), B, impl)
-                if route is None \
-                        or route[0] not in ("rowwise", "rowwise_packed") \
-                        or not rowwise_eligible(route[1], 2, K):
-                    continue      # dispatcher would fall back col-wise
-            except Exception:     # noqa: BLE001
-                continue
+            from ..ops.histogram_rowwise import rowwise_eligible
+            route = _tier_route(tiers, int(Xs.shape[0]), B, impl)
+            if route is None \
+                    or route[0] not in ("rowwise", "rowwise_packed") \
+                    or not rowwise_eligible(route[1], 2, K):
+                continue      # dispatcher would fall back col-wise
 
         def run(X, v, s, _impl=impl):
             return build_histogram_slots(X, v, s, K, B,
                                          rows_per_chunk=cfg.rows_per_chunk,
                                          tiers=tiers, impl=_impl)
 
-        try:
-            jitted = jax.jit(run)
-            _block(jitted(Xs, vals, slot))
-            best = float("inf")
-            for _ in range(2):
-                device_barrier()
-                t0 = timer()
-                _block(jitted(Xs, vals, slot))
-                best = min(best, timer() - t0)
-            timings[impl] = best
-        except Exception as e:                    # noqa: BLE001
-            from ..utils.log import log_warning
-            log_warning(f"autotune: probe for histogram impl '{impl}' "
-                        f"failed ({type(e).__name__}); dropping candidate")
+        timings[impl] = _best_of_2(jax.jit(run), (Xs, vals, slot), timer)
     return timings
 
 
@@ -341,9 +308,11 @@ def probe_fused_wave(X_t, cfg, probe_rows: int = DEFAULT_PROBE_ROWS,
     the kernels the grower would actually launch. ``histogram_impl=
     "fused"`` has no plain-histogram form, so it cannot ride
     ``probe_hist_impls`` — this is its timing probe, cached in the same
-    decision. Returns ``{"two_pass": s, "fused": s}``; either side
-    failing (non-TPU backend, wide bins) drops its key and the caller
-    keeps the unfused wave."""
+    decision. Returns ``{"two_pass": s, "fused": s}``, or ``{}`` outside
+    Pallas interpret mode: the fused kernels trace ops/split.py's search
+    inside the kernel body, which Mosaic cannot lower (grow_wave.py
+    raises when they are selected on a chip), so there is nothing to
+    time there."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -353,11 +322,11 @@ def probe_fused_wave(X_t, cfg, probe_rows: int = DEFAULT_PROBE_ROWS,
     from ..ops.histogram_pallas import T_ROWS, wave_pass_pallas
     from ..ops.split import (FeatureMeta, SplitHyperParams, find_best_split,
                              synth_count_channel)
-    from .profiler import device_barrier
 
+    from ..ops.histogram import pallas_interpret
     F_all, n = int(X_t.shape[0]), int(X_t.shape[1])
     B = int(cfg.num_bins_padded)
-    if B > 256:
+    if B > 256 or not pallas_interpret():
         return {}
     if F_all > 32:
         return _probe_fused_wave_tiled(X_t, cfg, probe_rows=probe_rows,
@@ -411,12 +380,9 @@ def probe_fused_wave(X_t, cfg, probe_rows: int = DEFAULT_PROBE_ROWS,
     meta_ops = pack_fused_meta(meta.num_bins, meta.missing_type,
                                meta.default_bin, meta.is_categorical)
 
-    from ..ops.histogram import pallas_interpret
-    _interp = pallas_interpret()
-
     def two_pass(X, v, l0):
         new_lor, hist = wave_pass_pallas(X, v, l0, tbl16, K, B,
-                                         interpret=_interp)
+                                         interpret=True)
         hist = jnp.pad(hist, ((0, KMAX - K), (0, 0), (0, 0), (0, 0)))
         hs = jnp.concatenate([hist, parent - hist], axis=0)  # [2*KMAX,...]
         h3 = jax.vmap(lambda hh, c, s: synth_count_channel(hh, c, s))(
@@ -431,25 +397,10 @@ def probe_fused_wave(X_t, cfg, probe_rows: int = DEFAULT_PROBE_ROWS,
         return wave_pass_fused_pallas(X, v, l0, tbl16,
                                       parent.reshape(KMAX, -1), scal,
                                       meta_ops, K, B, KMAX, hp,
-                                      interpret=_interp)
+                                      interpret=True)
 
-    timings: Dict[str, float] = {}
-    for name, fn in (("two_pass", two_pass), ("fused", fused)):
-        try:
-            jitted = jax.jit(fn)
-            _block(jitted(Xs, vals, lor))
-            best = float("inf")
-            for _ in range(2):
-                device_barrier()
-                t0 = timer()
-                _block(jitted(Xs, vals, lor))
-                best = min(best, timer() - t0)
-            timings[name] = best
-        except Exception as e:                    # noqa: BLE001
-            from ..utils.log import log_warning
-            log_warning(f"autotune: fused-wave probe '{name}' failed "
-                        f"({type(e).__name__}); dropping candidate")
-    return timings
+    return {name: _best_of_2(jax.jit(fn), (Xs, vals, lor), timer)
+            for name, fn in (("two_pass", two_pass), ("fused", fused))}
 
 
 def _probe_fused_wave_tiled(X_t, cfg, probe_rows: int = DEFAULT_PROBE_ROWS,
@@ -472,7 +423,6 @@ def _probe_fused_wave_tiled(X_t, cfg, probe_rows: int = DEFAULT_PROBE_ROWS,
     from ..ops.histogram_pallas import T_ROWS, wave_apply_pallas
     from ..ops.split import (FeatureMeta, SplitHyperParams, find_best_split,
                              synth_count_channel)
-    from .profiler import device_barrier
 
     F, n = int(X_t.shape[0]), int(X_t.shape[1])
     B = int(cfg.num_bins_padded)
@@ -529,12 +479,9 @@ def _probe_fused_wave_tiled(X_t, cfg, probe_rows: int = DEFAULT_PROBE_ROWS,
     pendl = jnp.full((128,), -1, jnp.int32)
     pnl0 = jnp.asarray(0, jnp.int32)
 
-    from ..ops.histogram import pallas_interpret
-    _interp = pallas_interpret()
-
     def two_pass(X, v, l0, d8):
         new_lor, slot_small = wave_apply_pallas(d8, l0, tbl16,
-                                                interpret=_interp)
+                                                interpret=True)
         hist = build_histogram_slots(X, v, slot_small, K, B,
                                      rows_per_chunk=cfg.rows_per_chunk,
                                      tiers=tiers, impl="auto")
@@ -552,25 +499,10 @@ def _probe_fused_wave_tiled(X_t, cfg, probe_rows: int = DEFAULT_PROBE_ROWS,
         return wave_pass_fused_tiled_pallas(
             X, v, d8, l0, tbl16, pendl, pnl0,
             parent.reshape(KMAX, -1), scal, meta_tiles, fm_tiles,
-            F, K, B, KMAX, hp, tile=tile, interpret=_interp)
+            F, K, B, KMAX, hp, tile=tile, interpret=True)
 
-    timings: Dict[str, float] = {}
-    for name, fn in (("two_pass", two_pass), ("fused", fused)):
-        try:
-            jitted = jax.jit(fn)
-            _block(jitted(Xs, vals, lor, dec8))
-            best = float("inf")
-            for _ in range(2):
-                device_barrier()
-                t0 = timer()
-                _block(jitted(Xs, vals, lor, dec8))
-                best = min(best, timer() - t0)
-            timings[name] = best
-        except Exception as e:                    # noqa: BLE001
-            from ..utils.log import log_warning
-            log_warning(f"autotune: fused-wave probe '{name}' failed "
-                        f"({type(e).__name__}); dropping candidate")
-    return timings
+    return {name: _best_of_2(jax.jit(fn), (Xs, vals, lor, dec8), timer)
+            for name, fn in (("two_pass", two_pass), ("fused", fused))}
 
 
 def probe_comm_modes(mesh, n_features: int, num_bins_padded: int,
@@ -590,8 +522,6 @@ def probe_comm_modes(mesh, n_features: int, num_bins_padded: int,
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.context import DATA_AXIS, DistContext
-    from ..parallel.data_parallel import shard_map_compat
-    from .profiler import device_barrier
 
     k = int(mesh.devices.size)
     dist = DistContext(DATA_AXIS)
@@ -608,22 +538,10 @@ def probe_comm_modes(mesh, n_features: int, num_bins_padded: int,
     }
     timings: Dict[str, float] = {}
     for name, (fn, out_spec) in candidates.items():
-        try:
-            jitted = jax.jit(shard_map_compat(
-                fn, mesh=mesh, in_specs=(P(),), out_specs=out_spec,
-                check_vma=False))
-            _block(jitted(buf))                   # compile + warm
-            best = float("inf")
-            for _ in range(2):
-                device_barrier()
-                t0 = timer()
-                _block(jitted(buf))
-                best = min(best, timer() - t0)
-            timings[name] = best
-        except Exception as e:                    # noqa: BLE001
-            from ..utils.log import log_warning
-            log_warning(f"autotune: comm probe for '{name}' failed "
-                        f"({type(e).__name__}); dropping candidate")
+        jitted = jax.jit(jax.shard_map(
+            fn, mesh=mesh, in_specs=(P(),), out_specs=out_spec,
+            check_vma=False))
+        timings[name] = _best_of_2(jitted, (buf,), timer)
     return timings
 
 
@@ -711,7 +629,6 @@ def probe_binning(mappers, *, probe_rows: int = 16384, seed: int = 0,
 
     from ..ops.bucketize import (BinningUnavailable, bucketize_rows,
                                  pack_bin_table)
-    from .profiler import device_barrier
 
     try:
         table = pack_bin_table(mappers, mode="train")
@@ -729,33 +646,16 @@ def probe_binning(mappers, *, probe_rows: int = 16384, seed: int = 0,
             if m is not None and not getattr(m, "is_trivial", False):
                 m.value_to_bin(np.asarray(X[:, f], np.float64))
 
-    try:
-        best = float("inf")
-        host_arm()                                 # warm numpy caches
-        for _ in range(2):
-            t0 = timer()
-            host_arm()
-            best = min(best, timer() - t0)
-        timings["host"] = best
-    except Exception as e:                         # noqa: BLE001
-        from ..utils.log import log_warning
-        log_warning(f"autotune: host binning probe failed "
-                    f"({type(e).__name__}); dropping candidate")
-    try:
-        import jax
-        jitted = jax.jit(lambda Xc: bucketize_rows(Xc, table))
-        _block(jitted(X))                          # compile + warm
-        best = float("inf")
-        for _ in range(2):
-            device_barrier()
-            t0 = timer()
-            _block(jitted(X))
-            best = min(best, timer() - t0)
-        timings["device"] = best
-    except Exception as e:                         # noqa: BLE001
-        from ..utils.log import log_warning
-        log_warning(f"autotune: device binning probe failed "
-                    f"({type(e).__name__}); dropping candidate")
+    best = float("inf")
+    host_arm()                                     # warm numpy caches
+    for _ in range(2):
+        t0 = timer()
+        host_arm()
+        best = min(best, timer() - t0)
+    timings["host"] = best
+    import jax
+    timings["device"] = _best_of_2(
+        jax.jit(lambda Xc: bucketize_rows(Xc, table)), (X,), timer)
     return timings
 
 

@@ -46,8 +46,7 @@ def device_barrier() -> None:
     every backend we run on (CPU/TPU, single- or multi-device)."""
     try:
         import jax
-        (jax.effects_barrier if hasattr(jax, "effects_barrier")
-         else lambda: None)()
+        jax.effects_barrier()
         for d in jax.live_arrays():
             d.block_until_ready()
     except Exception:

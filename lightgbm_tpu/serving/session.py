@@ -219,12 +219,8 @@ class ServingSession:
             return "host"
         if engine == "device":
             return "device"
-        try:
-            import jax
-            backend = jax.default_backend()
-        except Exception:
-            backend = "cpu"
-        return "device" if backend == "tpu" else "host"
+        import jax
+        return "device" if jax.default_backend() == "tpu" else "host"
 
     # ------------------------------------------------------------------
     @classmethod
@@ -299,11 +295,7 @@ class ServingSession:
             return self._device_scorer(bucket)
         if self.engine == "binned":
             return self._binned_scorer(bucket)
-        if self.engine == "compiled":
-            return self._compiled_scorer(bucket)
-        # host entries are trivially warm closures over the packed model;
-        # they ride the same cache so hit-rate accounting is uniform
-        return self._pm.predict_margin
+        return self._compiled_scorer(bucket)
 
     def _raw_scorer(self, bucket: int) -> Callable:
         """Raw-f32 fused scorer: bucketize + bin-domain walk in ONE
@@ -329,6 +321,28 @@ class ServingSession:
             self._raw_jit = jax.jit(score)
         return self._raw_jit
 
+    def _scorer(self, kind: str, b: int) -> Callable:
+        """Cached scorer for (``kind``, bucket) — ``kind`` is the engine
+        name, plus ``_raw`` for the fused raw-f32 variant. An accelerator
+        scorer is run once on zeros before it is cached, so a trace,
+        lowering or compile error of the scorer raises HERE, on the
+        caller's thread: score_margin's breaker guard covers scoring
+        faults of a scorer that exists, never the lack of one."""
+        def build():
+            if kind == "host":
+                # trivially warm closure over the packed model; rides
+                # the same cache so hit-rate accounting is uniform
+                return self._pm.predict_margin
+            import jax
+            raw = kind.endswith("_raw")
+            fn = self._raw_scorer(b) if raw else self._build_scorer(b)
+            zeros = (np.zeros((b, self.num_features), np.float32)
+                     if raw or kind == "device" else
+                     np.zeros((b, self._bm.num_features), np.uint8))
+            jax.block_until_ready(fn(zeros))
+            return fn
+        return self._cache.get((self.version, kind, b), build)
+
     def warmup(self) -> List[int]:
         """Pre-compile the whole bucket ladder (min_bucket..max_batch,
         powers of two) before traffic lands, so no live request pays a
@@ -338,27 +352,12 @@ class ServingSession:
         while b <= self.max_batch:
             ladder.append(b)
             b *= 2
-        F = self.num_features
         for b in ladder:
-            fn = self._cache.get((self.version, self.engine, b),
-                                 lambda b=b: self._build_scorer(b))
-            if self.engine == "device":
-                import jax
-                out = fn(np.zeros((b, F), np.float32))
-                jax.block_until_ready(out)
-            elif self.engine in ("binned", "compiled"):
-                import jax
-                out = fn(np.zeros((b, self._bm.num_features), np.uint8))
-                jax.block_until_ready(out)
-                if self._bin_table is not None:
-                    # warm the raw-f32 fused ladder alongside the
-                    # uint8 one: live traffic may arrive either way
-                    rfn = self._cache.get(
-                        (self.version, self.engine + "_raw", b),
-                        lambda b=b: self._raw_scorer(b))
-                    out = rfn(np.zeros((b, self.num_features),
-                                       np.float32))
-                    jax.block_until_ready(out)
+            self._scorer(self.engine, b)
+            if self._bin_table is not None:
+                # warm the raw-f32 fused ladder alongside the uint8
+                # one: live traffic may arrive either way
+                self._scorer(self.engine + "_raw", b)
         log_info(f"serving warmup: engine={self.engine} "
                  f"buckets={ladder} shards={self.num_shards or 1}")
         return ladder
@@ -366,28 +365,20 @@ class ServingSession:
     # ------------------------------------------------------------------
     # scoring
     # ------------------------------------------------------------------
-    def _host_fn(self, b: int):
-        return self._cache.get((self.version, "host", b),
-                               lambda b=b: self._pm.predict_margin)
-
-    def _score_device(self, X: np.ndarray, c0: int, c1: int,
-                      b: int) -> np.ndarray:
+    def _score_device(self, fn: Callable, X: np.ndarray, c0: int,
+                      c1: int, b: int) -> np.ndarray:
         import jax
-        fn = self._cache.get((self.version, "device", b),
-                             lambda b=b: self._build_scorer(b))
         m = c1 - c0
         Xp = np.zeros((b, X.shape[1]), np.float32)
         Xp[:m] = X[c0:c1]
         return np.asarray(jax.device_get(fn(Xp)))[:, :m].astype(np.float64)
 
-    def _score_binned(self, X: np.ndarray, c0: int, c1: int,
-                      b: int) -> np.ndarray:
+    def _score_binned(self, fn: Callable, X: np.ndarray, c0: int,
+                      c1: int, b: int) -> np.ndarray:
         """Bin the chunk once through the frozen mappers (host-side
         searchsorted), then score uint8 bins on device — an 8x smaller
         transfer than the f32 path, bit-identical output."""
         import jax
-        fn = self._cache.get((self.version, self.engine, b),
-                             lambda b=b: self._build_scorer(b))
         m = c1 - c0
         Xp = np.zeros((b, self._bm.num_features), np.uint8)
         if self.profiler is not None:
@@ -401,14 +392,12 @@ class ServingSession:
             Xp[:m] = self._bm.bin_rows(X[c0:c1])
         return np.asarray(jax.device_get(fn(Xp)))[:, :m].astype(np.float64)
 
-    def _score_binned_raw(self, X: np.ndarray, c0: int, c1: int,
-                          b: int) -> np.ndarray:
+    def _score_binned_raw(self, fn: Callable, X: np.ndarray, c0: int,
+                          c1: int, b: int) -> np.ndarray:
         """Raw-f32 fused path: the chunk ships as f32 and the bucketize
         runs INSIDE the scoring launch (one program raw features ->
         margins; no host bin_rows stage, no separate binning launch)."""
         import jax
-        fn = self._cache.get((self.version, self.engine + "_raw", b),
-                             lambda b=b: self._raw_scorer(b))
         m = c1 - c0
         Xp = np.zeros((b, self.num_features), np.float32)
         Xp[:m] = X[c0:c1, :self.num_features]
@@ -461,25 +450,29 @@ class ServingSession:
                 # up in batch latency (latency-SLO shed / breaker trip)
                 self.fault_plan.slow_score(seq)
             if use_accel:
+                # built + compiled OUTSIDE the guard (_scorer): a scorer
+                # that cannot be built is an error, not a host chunk
+                fn = self._scorer(
+                    self.engine + ("_raw" if raw_f32 else ""), b)
                 try:
                     if self.fault_plan is not None:
                         self.fault_plan.fail_score(seq)
-                    if self.engine in ("binned", "compiled"):
-                        r = (self._score_binned_raw(X, c0, c1, b)
-                             if raw_f32
-                             else self._score_binned(X, c0, c1, b))
+                    if raw_f32:
+                        r = self._score_binned_raw(fn, X, c0, c1, b)
+                    elif self.engine == "device":
+                        r = self._score_device(fn, X, c0, c1, b)
                     else:
-                        r = self._score_device(X, c0, c1, b)
+                        r = self._score_binned(fn, X, c0, c1, b)
                     if self.breaker is not None:
                         self.breaker.record_success(
                             time.perf_counter() - t0)
-                except BaseException as e:
+                except Exception as e:
                     if self.breaker is not None:
                         self.breaker.record_failure(e)
                     self.metrics.inc("host_fallbacks")
                     log_warning(f"serving: {self.engine} scoring failed "
                                 f"({e!r}); chunk re-scored on host")
-                    r = self._host_fn(b)(
+                    r = self._scorer("host", b)(
                         np.asarray(X[c0:c1], np.float64))
             else:
                 if self.fault_plan is not None:
@@ -488,7 +481,8 @@ class ServingSession:
                 # without a shaped trace) — bit-identical to
                 # Booster.predict by construction; f32 raw chunks
                 # upcast so the host walk always sees f64
-                r = self._host_fn(b)(np.asarray(X[c0:c1], np.float64))
+                r = self._scorer("host", b)(
+                    np.asarray(X[c0:c1], np.float64))
             self.metrics.record_batch(time.perf_counter() - t0, m)
             if self.profiler is not None:
                 self.profiler.sample_hbm("serve_score")

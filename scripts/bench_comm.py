@@ -15,6 +15,13 @@ payload [C, F_pad, B]:
     full-buffer ``psum``, ``psum_scatter`` over the padded feature
     axis, and ``psum_scatter`` of the packed int32 lane.
 
+This is a CPU MOCK: it times XLA:CPU's collectives between virtual
+devices of one host, which says nothing about ICI — the byte accounting
+is exact, the step times are not device numbers (the result is stamped
+``"device": "cpu-virtual"``). The parent never touches JAX; every child
+is held to ``JAX_PLATFORMS=cpu``, so no chip is involved even on a TPU
+host, and a failed child fails the bench.
+
 A CPU host has one device, and the XLA device-count flag must be set
 before the backend initializes — so the driver re-execs itself as one
 child interpreter per mesh size with
@@ -46,7 +53,6 @@ def _child_main() -> None:
 
     from lightgbm_tpu.parallel.context import (DATA_AXIS, DistContext,
                                                make_data_mesh)
-    from lightgbm_tpu.parallel.data_parallel import shard_map_compat
     from lightgbm_tpu.parallel.packed import pack_gh, unpack_gh
     from lightgbm_tpu.runtime.profiler import device_barrier
     from lightgbm_tpu.utils import round_up
@@ -91,7 +97,7 @@ def _child_main() -> None:
     out = {"mesh_size": k, "features": F, "features_padded": Fp,
            "num_bins": B, "channels": C, "payload_bytes": payload}
     for name, (fn, buf, out_spec, recv, wire) in arms.items():
-        jitted = jax.jit(shard_map_compat(
+        jitted = jax.jit(jax.shard_map(
             fn, mesh=mesh, in_specs=(P(),), out_specs=out_spec,
             check_vma=False))
         jax.block_until_ready(jitted(buf))            # compile + warm
@@ -133,9 +139,8 @@ def main() -> None:
             [sys.executable, os.path.abspath(__file__)], env=env,
             capture_output=True, text=True, timeout=600)
         if proc.returncode != 0:
-            print(f"bench_comm: mesh size {k} failed:\n"
-                  + proc.stderr[-2000:], file=sys.stderr)
-            continue
+            raise SystemExit(f"bench_comm: mesh size {k} failed:\n"
+                             + proc.stderr[-2000:])
         meshes.append(json.loads(proc.stdout.strip().splitlines()[-1]))
 
     result = {"metric": "hist_exchange_allreduce_vs_reduce_scatter",

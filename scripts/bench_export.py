@@ -40,6 +40,12 @@ full bucket-ladder warmup -> first score (export/runtime.py, standalone)
 against live-model ServingSession(engine="binned", warmup=True) -> first
 score over the same ladder.
 
+This is a CPU MOCK: it times the scheduler against an injected sleep
+(``slow_score``), and its cold-start children are separate processes —
+on a TPU host they could not share the parent's chip. It refuses to run
+unless ``JAX_PLATFORMS=cpu`` and stamps ``"device": "cpu"``; none of its
+numbers is a device number.
+
 Writes ``BENCH_EXPORT.json`` at the repo root (consumed by
 scripts/check_stale_claims.py) and prints it. Env knobs: EXPORT_TENANTS,
 EXPORT_QPS, EXPORT_CROWD_QPS, EXPORT_SERVICE_MS, EXPORT_PHASE_S,
@@ -343,6 +349,11 @@ def _cold_start(models):
 
 
 def main() -> None:
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        raise SystemExit(
+            "bench_export.py is a CPU mock (scheduler policy against an "
+            "injected sleep, cold starts in child processes); run it with "
+            "JAX_PLATFORMS=cpu")
     n_tenants = max(int(os.environ.get("EXPORT_TENANTS", "8")), 2)
     total_qps = float(os.environ.get("EXPORT_QPS", "900"))
     crowd_qps = float(os.environ.get("EXPORT_CROWD_QPS", "1200"))
@@ -390,6 +401,7 @@ def main() -> None:
 
     results = {
         "bench": "export",
+        "device": "cpu",
         "tenants": n_tenants,
         "users": USERS,
         "engine": "binned",

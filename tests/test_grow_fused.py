@@ -541,3 +541,18 @@ def test_fused_variant_sig_keys_decision_cache():
     assert at.make_key(1000, 10, 255, 31, variant="") == k0
     k1 = at.make_key(1000, 10, 255, 31, variant=sig)
     assert k1 != k0 and k1.endswith("_" + sig)
+
+
+def test_fused_selected_on_chip_raises(monkeypatch):
+    """histogram_impl=fused without interpret mode on a TPU backend fails
+    at trace time instead of dropping out of a probe."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rng = np.random.RandomState(0)
+    X = rng.normal(size=(400, 8)).astype(np.float32)
+    p = dict(objective="binary", num_leaves=4, max_bin=15, verbose=-1,
+             histogram_impl="fused", binning_impl="host")
+    bst = lgb.Booster(params=p, train_set=lgb.Dataset(
+        X, label=(X[:, 0] > 0).astype(np.float32), params=p))
+    with pytest.raises(NotImplementedError, match="histogram_impl=fused"):
+        bst.update()

@@ -3,12 +3,15 @@ CPU test mesh — the analog of the reference's GPU/CPU dual test,
 tests/python_package_test/test_dual.py:19).
 
 These exercise the device-only code paths that CPU CI cannot reach: the
-fused wave megakernel, the wide/categorical/EFB wave-apply path
-(grow_wave.py dec_go_left + wave_apply_pallas), and the device batch
-predictor. Ground truth is the SAME training run on the portable XLA
-path (LIGHTGBM_TPU_DISABLE_PALLAS subprocess would be cleaner still, but
-models are deterministic given the grower order, so CPU-recorded AUC
-levels serve as the recorded gates where noted)."""
+wave megakernel, the wide/categorical/EFB wave-apply path (grow_wave.py
+dec_go_left + wave_apply_pallas), and the device batch predictor. Ground
+truth is the SAME training run on the portable XLA path, in the SAME
+process: a chip belongs to one process, and the
+LIGHTGBM_TPU_DISABLE_PALLAS kill switch is read at trace time, which
+every new Booster's jitted closures go through again.
+
+On the chip:  LIGHTGBM_TPU_TEST_ON_TPU=1 python -m pytest tests/test_tpu_parity.py
+"""
 
 import os
 
@@ -17,65 +20,35 @@ import pytest
 
 import lightgbm_tpu as lgb
 
-
-def _on_tpu() -> bool:
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        return False
-    try:
-        import jax
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+# the env switch, not a backend probe: collecting this file on the CPU
+# mesh must not initialize a backend (tests/conftest.py reads the same)
+pytestmark = pytest.mark.skipif(
+    os.environ.get("LIGHTGBM_TPU_TEST_ON_TPU", "") != "1",
+    reason="needs a real TPU backend (LIGHTGBM_TPU_TEST_ON_TPU=1)")
 
 
-pytestmark = pytest.mark.skipif(not _on_tpu(),
-                                reason="needs a real TPU backend")
+@pytest.fixture(scope="module", autouse=True)
+def _tpu():
+    from lightgbm_tpu.runtime.device import require_tpu
+    return require_tpu()
 
 
-def _auc(pred, lab):
-    order = np.argsort(pred)
-    ranks = np.empty(order.size)
-    ranks[order] = np.arange(1, order.size + 1)
-    npos = lab.sum()
-    return float((ranks[lab > 0].sum() - npos * (npos + 1) / 2)
-                 / max(npos * (lab.size - npos), 1))
+def _pallas_vs_portable(monkeypatch, params, X, y, rounds=10, **dskw):
+    """Train twice on the SAME backend, in this process: once on the
+    portable XLA lowering (kill switch set while the first Booster
+    traces), once with the Pallas kernels; return both predictions."""
+    def fit():
+        b = lgb.train(params, lgb.Dataset(X, label=y, **dskw),
+                      num_boost_round=rounds)
+        return b.predict(X[:20000])
+
+    monkeypatch.setenv("LIGHTGBM_TPU_DISABLE_PALLAS", "1")
+    ref = fit()
+    monkeypatch.delenv("LIGHTGBM_TPU_DISABLE_PALLAS")
+    return fit(), ref
 
 
-def _pallas_vs_portable(params, X, y, rounds=10, **dskw):
-    """Train twice on the SAME backend: once with Pallas kernels, once
-    with the portable XLA lowering (the kill switch is read at trace
-    time in a fresh subprocess), and compare predictions."""
-    import json
-    import subprocess
-    import sys
-    import tempfile
-
-    with tempfile.TemporaryDirectory() as td:
-        np.save(f"{td}/X.npy", X)
-        np.save(f"{td}/y.npy", y)
-        code = f"""
-import json, sys
-import numpy as np
-sys.path.insert(0, {json.dumps(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))})
-import lightgbm_tpu as lgb
-X = np.load({json.dumps(td)} + "/X.npy")
-y = np.load({json.dumps(td)} + "/y.npy")
-b = lgb.train({params!r}, lgb.Dataset(X, label=y, **{dskw!r}),
-              num_boost_round={rounds})
-np.save({json.dumps(td)} + "/pred.npy", b.predict(X[:20000]))
-"""
-        env = dict(os.environ)
-        env["LIGHTGBM_TPU_DISABLE_PALLAS"] = "1"
-        subprocess.run([sys.executable, "-c", code], env=env, check=True,
-                       timeout=1500)
-        ref = np.load(f"{td}/pred.npy")
-    b = lgb.train(params, lgb.Dataset(X, label=y, **dskw),
-                  num_boost_round=rounds)
-    got = b.predict(X[:20000])
-    return got, ref
-
-
-def test_wide_feature_parity():
+def test_wide_feature_parity(monkeypatch):
     """F=64 > 32 exercises wave_apply_pallas + the F-gridded slots
     kernel against the portable select-chain path."""
     rng = np.random.RandomState(0)
@@ -85,11 +58,11 @@ def test_wide_feature_parity():
     y = (X @ w + rng.normal(scale=0.5, size=N) > 0).astype(np.float32)
     params = dict(objective="binary", num_leaves=63, max_bin=63,
                   verbose=-1)
-    got, ref = _pallas_vs_portable(params, X, y)
+    got, ref = _pallas_vs_portable(monkeypatch, params, X, y)
     np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-5)
 
 
-def test_categorical_parity():
+def test_categorical_parity(monkeypatch):
     rng = np.random.RandomState(1)
     N = 100_000
     Xc = rng.randint(0, 24, size=(N, 2)).astype(np.float32)
@@ -99,12 +72,12 @@ def test_categorical_parity():
          ^ (Xn[:, 0] > 0)).astype(np.float32)
     params = dict(objective="binary", num_leaves=31, max_bin=63,
                   verbose=-1, min_data_in_leaf=20)
-    got, ref = _pallas_vs_portable(params, X, y,
+    got, ref = _pallas_vs_portable(monkeypatch, params, X, y,
                                    categorical_feature=[0, 1])
     np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-5)
 
 
-def test_efb_parity():
+def test_efb_parity(monkeypatch):
     """Sparse one-hot-ish features trigger EFB bundling; the bundled
     storage drives dec_go_left's unpack path on TPU."""
     rng = np.random.RandomState(2)
@@ -116,7 +89,7 @@ def test_efb_parity():
     y = ((hot % 3 == 0) ^ (X[:, F // 2] > 0)).astype(np.float32)
     params = dict(objective="binary", num_leaves=31, max_bin=63,
                   verbose=-1, enable_bundle=True)
-    got, ref = _pallas_vs_portable(params, X, y)
+    got, ref = _pallas_vs_portable(monkeypatch, params, X, y)
     np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-5)
 
 
