@@ -21,6 +21,8 @@ from .data.dataset import (BinnedDataset, construct_from_matrix,
 from .metrics import Metric, create_metric, default_metric_for_objective
 from .models.gbdt import GBDT
 from .objectives import create_objective
+from .runtime.profiler import (compiles_outside_spans,
+                               count as span_count, span, spans)
 from .utils.log import log_fatal, log_info, log_warning
 
 # streaming device bin table "not yet resolved" marker (None is the
@@ -729,10 +731,15 @@ class Booster:
 
     def get_profile(self) -> Optional[Dict[str, Any]]:
         """Device-profile export (runtime/profiler.py to_dict): per-stage
-        seconds, per-iteration ring buffer, row-iters/s, HBM watermark.
+        seconds, per-iteration ring buffer, row-iters/s, HBM watermark,
+        and under "spans" the process's unfenced span ring (beside it
+        the backend compilations that fired under no span).
         None unless trained with device_profile=true."""
         prof = getattr(self._gbdt, "profiler", None)
-        return prof.to_dict() if prof is not None else None
+        if prof is None:
+            return None
+        return dict(prof.to_dict(), spans=spans(),
+                    compiles_outside_spans=compiles_outside_spans())
 
     def num_model_per_iteration(self) -> int:
         return self._gbdt.num_tree_per_iteration
@@ -796,24 +803,30 @@ class Booster:
                 num_iteration: Optional[int] = None,
                 raw_score: bool = False, pred_leaf: bool = False,
                 pred_contrib: bool = False, **kwargs) -> np.ndarray:
-        data = _to_2d_numpy(data)
-        ni = num_iteration if num_iteration is not None else (
-            self.best_iteration if self.best_iteration > 0 else -1)
-        if pred_leaf:
-            return self._gbdt.predict_leaf_index(data, start_iteration, ni)
-        if pred_contrib:
-            from .models.shap import predict_contrib
-            return predict_contrib(self._gbdt, data, start_iteration, ni)
-        es_kwargs = {}
-        for p in ("pred_early_stop", "pred_early_stop_freq",
-                  "pred_early_stop_margin"):
-            if p in kwargs:
-                es_kwargs[p] = kwargs[p]
-            elif p in self.params:
-                es_kwargs[p] = self.params[p]
-        return self._gbdt.predict(data, raw_score=raw_score,
-                                  start_iteration=start_iteration,
-                                  num_iteration=ni, **es_kwargs)
+        with span("predict"):
+            with span("predict/to_numpy"):
+                data = _to_2d_numpy(data)
+            span_count(rows=data.shape[0], features=data.shape[1],
+                       bytes_in=data.nbytes)
+            ni = num_iteration if num_iteration is not None else (
+                self.best_iteration if self.best_iteration > 0 else -1)
+            if pred_leaf:
+                return self._gbdt.predict_leaf_index(data, start_iteration,
+                                                     ni)
+            if pred_contrib:
+                from .models.shap import predict_contrib
+                return predict_contrib(self._gbdt, data, start_iteration,
+                                       ni)
+            es_kwargs = {}
+            for p in ("pred_early_stop", "pred_early_stop_freq",
+                      "pred_early_stop_margin"):
+                if p in kwargs:
+                    es_kwargs[p] = kwargs[p]
+                elif p in self.params:
+                    es_kwargs[p] = self.params[p]
+            return self._gbdt.predict(data, raw_score=raw_score,
+                                      start_iteration=start_iteration,
+                                      num_iteration=ni, **es_kwargs)
 
     def serve(self, **kwargs) -> "Any":
         """Production inference session over this model: pinned packed

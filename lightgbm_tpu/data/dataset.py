@@ -21,6 +21,7 @@ from typing import Any, Dict, List, Optional, Sequence, Union
 import numpy as np
 
 from ..config import Config
+from ..runtime.profiler import count as span_count, span
 from ..utils.log import log_fatal, log_info, log_warning
 from .binning import (BIN_TYPE_CATEGORICAL, BIN_TYPE_NUMERICAL, BinMapper)
 
@@ -514,45 +515,52 @@ def construct_from_matrix(
     if data.ndim != 2:
         log_fatal("Training data must be 2-dimensional")
     num_data, num_cols = data.shape
-    ds = _init_ds(num_data, num_cols, config, feature_names)
+    with span("dataset/construct", rows=num_data, features=num_cols):
+        ds = _init_ds(num_data, num_cols, config, feature_names)
 
-    # sample rows for binning (bin_construct_sample_cnt rows,
-    # dataset_loader.cpp:1162)
-    sample_cnt = min(config.bin_construct_sample_cnt, num_data)
-    rng = np.random.RandomState(config.data_random_seed)
-    if sample_cnt < num_data:
-        sample_idx = np.sort(rng.choice(num_data, sample_cnt,
-                                        replace=False))
-        sample = data[sample_idx]
-    else:
-        sample = data
-    sample = np.asarray(sample, dtype=np.float64)
-    _fit_or_adopt_mappers(ds, config, reference,
-                          lambda j: sample[:, j], len(sample),
-                          categorical_feature)
+        # sample rows for binning (bin_construct_sample_cnt rows,
+        # dataset_loader.cpp:1162)
+        with span("dataset/sample"):
+            sample_cnt = min(config.bin_construct_sample_cnt, num_data)
+            rng = np.random.RandomState(config.data_random_seed)
+            if sample_cnt < num_data:
+                sample_idx = np.sort(rng.choice(num_data, sample_cnt,
+                                                replace=False))
+                sample = data[sample_idx]
+            else:
+                sample = data
+            sample = np.asarray(sample, dtype=np.float64)
+        with span("dataset/find_bins"):
+            _fit_or_adopt_mappers(ds, config, reference,
+                                  lambda j: sample[:, j], len(sample),
+                                  categorical_feature)
 
-    # push rows: device bucketize when the raw matrix is f32 and the
-    # mapper set packs (bit-identical to the host loop — docs/PERF.md
-    # §8); per-feature vectorized value->bin on host otherwise
-    X = _alloc_binned(ds)
-    table = ingest_bin_table(ds, config, num_data) \
-        if data.dtype == np.float32 else None
-    if table is not None:
-        from ..ops.bucketize import bin_rows_device
-        raw = np.ascontiguousarray(data[:, ds.real_feature_index],
-                                   np.float32)
-        X[:, :] = bin_rows_device(raw, table).astype(X.dtype)
-        ds.binned_on = "device"
-    else:
-        for inner, (m, orig) in enumerate(zip(ds.mappers,
-                                              ds.real_feature_index)):
-            col = np.asarray(data[:, orig], dtype=np.float64)
-            X[:, inner] = m.value_to_bin(col).astype(X.dtype)
-    ds.X_binned = X
-    if config.linear_tree:
-        ds.raw_data = np.ascontiguousarray(data, dtype=np.float32)
-    return _finalize(ds, config, label, weight, group, init_score,
-                     reference)
+        # push rows: device bucketize when the raw matrix is f32 and the
+        # mapper set packs (bit-identical to the host loop — docs/PERF.md
+        # §8); per-feature vectorized value->bin on host otherwise
+        X = _alloc_binned(ds)
+        table = ingest_bin_table(ds, config, num_data) \
+            if data.dtype == np.float32 else None
+        span_count(binned_on_device=int(table is not None))
+        if table is not None:
+            from ..ops.bucketize import bin_rows_device
+            with span("dataset/bucketize"):
+                raw = np.ascontiguousarray(data[:, ds.real_feature_index],
+                                           np.float32)
+                X[:, :] = bin_rows_device(raw, table).astype(X.dtype)
+            ds.binned_on = "device"
+        else:
+            with span("dataset/host_bin"):
+                for inner, (m, orig) in enumerate(
+                        zip(ds.mappers, ds.real_feature_index)):
+                    col = np.asarray(data[:, orig], dtype=np.float64)
+                    X[:, inner] = m.value_to_bin(col).astype(X.dtype)
+        ds.X_binned = X
+        if config.linear_tree:
+            ds.raw_data = np.ascontiguousarray(data, dtype=np.float32)
+        with span("dataset/finalize"):
+            return _finalize(ds, config, label, weight, group, init_score,
+                             reference)
 
 
 def construct_from_sequences(
@@ -576,54 +584,57 @@ def construct_from_sequences(
     num_data = int(sum(lens))
     if num_data == 0:
         log_fatal("Sequence sources are empty")
-    probe = np.asarray(seqs[0][0:1], dtype=np.float64)
-    ds = _init_ds(num_data, probe.shape[1], config, feature_names)
-    starts = np.concatenate([[0], np.cumsum(lens)])
-    b = getattr(seqs[0], "batch_size", None) or 65536
+    with span("dataset/construct", rows=num_data):
+        probe = np.asarray(seqs[0][0:1], dtype=np.float64)
+        ds = _init_ds(num_data, probe.shape[1], config, feature_names)
+        starts = np.concatenate([[0], np.cumsum(lens)])
+        b = getattr(seqs[0], "batch_size", None) or 65536
 
-    def fetch(global_lo, global_hi):
-        """Rows [global_lo, global_hi) across the concatenated sources."""
-        parts = []
-        for si, s in enumerate(seqs):
-            lo = max(global_lo, starts[si])
-            hi = min(global_hi, starts[si + 1])
-            if lo < hi:
-                parts.append(np.asarray(
-                    s[int(lo - starts[si]):int(hi - starts[si])],
-                    dtype=np.float64))
-        return parts[0] if len(parts) == 1 else np.concatenate(parts)
+        def fetch(global_lo, global_hi):
+            """Rows [global_lo, global_hi) across the concatenated sources."""
+            parts = []
+            for si, s in enumerate(seqs):
+                lo = max(global_lo, starts[si])
+                hi = min(global_hi, starts[si + 1])
+                if lo < hi:
+                    parts.append(np.asarray(
+                        s[int(lo - starts[si]):int(hi - starts[si])],
+                        dtype=np.float64))
+            return parts[0] if len(parts) == 1 else np.concatenate(parts)
 
-    if reference is None:
-        # round 1: sample rows (contiguous batched fetches of a random
-        # global index set, dataset_loader.cpp:1162)
-        sample_cnt = min(config.bin_construct_sample_cnt, num_data)
-        rng = np.random.RandomState(config.data_random_seed)
-        idx = np.sort(rng.choice(num_data, sample_cnt, replace=False)) \
-            if sample_cnt < num_data else np.arange(num_data)
-        chunks = []
+        if reference is None:
+            # round 1: sample rows (contiguous batched fetches of a random
+            # global index set, dataset_loader.cpp:1162)
+            sample_cnt = min(config.bin_construct_sample_cnt, num_data)
+            rng = np.random.RandomState(config.data_random_seed)
+            idx = np.sort(rng.choice(num_data, sample_cnt, replace=False)) \
+                if sample_cnt < num_data else np.arange(num_data)
+            chunks = []
+            for lo in range(0, num_data, b):
+                sel = idx[(idx >= lo) & (idx < lo + b)]
+                if sel.size:
+                    batch = fetch(lo, min(lo + b, num_data))
+                    chunks.append(batch[sel - lo])
+            sample = np.concatenate(chunks)
+        else:
+            sample = probe
+        with span("dataset/find_bins"):
+            _fit_or_adopt_mappers(ds, config, reference,
+                                  lambda j: sample[:, j], len(sample),
+                                  categorical_feature)
+
+        # round 2: stream batches through the mappers
+        X = _alloc_binned(ds)
         for lo in range(0, num_data, b):
-            sel = idx[(idx >= lo) & (idx < lo + b)]
-            if sel.size:
-                batch = fetch(lo, min(lo + b, num_data))
-                chunks.append(batch[sel - lo])
-        sample = np.concatenate(chunks)
-    else:
-        sample = probe
-    _fit_or_adopt_mappers(ds, config, reference,
-                          lambda j: sample[:, j], len(sample),
-                          categorical_feature)
-
-    # round 2: stream batches through the mappers
-    X = _alloc_binned(ds)
-    for lo in range(0, num_data, b):
-        hi = min(lo + b, num_data)
-        batch = fetch(lo, hi)
-        for inner, (m, orig) in enumerate(
-                zip(ds.mappers, ds.real_feature_index)):
-            X[lo:hi, inner] = m.value_to_bin(batch[:, orig]).astype(X.dtype)
-    ds.X_binned = X
-    return _finalize(ds, config, label, weight, group, init_score,
-                     reference)
+            hi = min(lo + b, num_data)
+            batch = fetch(lo, hi)
+            for inner, (m, orig) in enumerate(
+                    zip(ds.mappers, ds.real_feature_index)):
+                X[lo:hi, inner] = m.value_to_bin(
+                    batch[:, orig]).astype(X.dtype)
+        ds.X_binned = X
+        return _finalize(ds, config, label, weight, group, init_score,
+                         reference)
 
 
 def construct_from_sparse(
@@ -642,31 +653,34 @@ def construct_from_sparse(
     reference's sparse semantics, sparse_bin.hpp; storage compression of
     the BINNED matrix comes from EFB bundling, dataset.cpp:251)."""
     num_data, num_cols = data.shape
-    ds = _init_ds(num_data, num_cols, config, feature_names)
-    csc = data.tocsc()
+    with span("dataset/construct", rows=num_data, features=num_cols):
+        ds = _init_ds(num_data, num_cols, config, feature_names)
+        csc = data.tocsc()
 
-    if reference is None:
-        sample_cnt = min(config.bin_construct_sample_cnt, num_data)
-        rng = np.random.RandomState(config.data_random_seed)
-        idx = np.sort(rng.choice(num_data, sample_cnt, replace=False)) \
-            if sample_cnt < num_data else np.arange(num_data)
-        sample = data.tocsr()[idx].tocsc()
-        n_sample = len(idx)
-    else:
-        sample, n_sample = None, 0
-    _fit_or_adopt_mappers(
-        ds, config, reference,
-        lambda j: np.asarray(sample[:, j].todense(), np.float64).ravel(),
-        n_sample, categorical_feature)
+        if reference is None:
+            sample_cnt = min(config.bin_construct_sample_cnt, num_data)
+            rng = np.random.RandomState(config.data_random_seed)
+            idx = np.sort(rng.choice(num_data, sample_cnt, replace=False)) \
+                if sample_cnt < num_data else np.arange(num_data)
+            sample = data.tocsr()[idx].tocsc()
+            n_sample = len(idx)
+        else:
+            sample, n_sample = None, 0
+        with span("dataset/find_bins"):
+            _fit_or_adopt_mappers(
+                ds, config, reference,
+                lambda j: np.asarray(sample[:, j].todense(),
+                                     np.float64).ravel(),
+                n_sample, categorical_feature)
 
-    X = _alloc_binned(ds)
-    for inner, (m, orig) in enumerate(zip(ds.mappers,
-                                          ds.real_feature_index)):
-        col = np.asarray(csc[:, orig].todense(), np.float64).ravel()
-        X[:, inner] = m.value_to_bin(col).astype(X.dtype)
-    ds.X_binned = X
-    return _finalize(ds, config, label, weight, group, init_score,
-                     reference)
+        X = _alloc_binned(ds)
+        for inner, (m, orig) in enumerate(zip(ds.mappers,
+                                              ds.real_feature_index)):
+            col = np.asarray(csc[:, orig].todense(), np.float64).ravel()
+            X[:, inner] = m.value_to_bin(col).astype(X.dtype)
+        ds.X_binned = X
+        return _finalize(ds, config, label, weight, group, init_score,
+                         reference)
 
 
 def load_binary_file(path: str, config: Config) -> BinnedDataset:

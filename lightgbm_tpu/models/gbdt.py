@@ -38,7 +38,7 @@ from ..ops.grow import DeviceTree, GrowConfig, grow_tree
 from ..ops.predict import predict_leaf_binned
 from ..ops.split import FeatureMeta
 from ..utils.log import log_fatal, log_info, log_warning
-from ..utils.timer import global_timer
+from ..runtime.profiler import count as span_count, span
 from .tree import Tree, make_decision_type
 
 _KEPS = 1e-15
@@ -198,7 +198,8 @@ class GBDT:
         self.autotune_decision: Optional[Dict[str, Any]] = None
 
         if train_set is not None:
-            self._init_train(train_set)
+            with span("booster/init", rows=train_set.num_data):
+                self._init_train(train_set)
 
     # ------------------------------------------------------------------
     def _init_train(self, ds: BinnedDataset) -> None:
@@ -301,42 +302,16 @@ class GBDT:
         self.num_bins_padded = max(_round_up(max_bin, 8), 8)
         self._max_bin = max_bin   # autotune cache key component (degrade
         #                           path re-pins under the same key)
-        Xt_np = np.ascontiguousarray(X.T)                   # [F(b), N]
-        if self._host_pad != N_real:
-            Xt_np = np.pad(Xt_np, ((0, 0), (0, self._host_pad - N_real)))
-        with self._prof_span("bin"):
-            self.X_t = self._put_rows(jnp.asarray(Xt_np), row_axis=1)
-        self.meta = build_feature_meta(ds, cfg.monotone_constraints,
-                                       cfg.interaction_constraints)
-        if cfg.forcedsplits_filename:
-            forced_tbl = parse_forced_splits(cfg.forcedsplits_filename, ds)
-            if forced_tbl is not None:
-                self.meta = self.meta._replace(
-                    forced=jnp.asarray(forced_tbl))
-        if self._use_bundles:
-            F = len(ds.mappers)
-            B = self.num_bins_padded
-            expand = np.full((F, B), len(ds.bundles) * B, np.int32)  # fill
-            mfb = np.zeros((F, B), np.float32)
-            for f, m in enumerate(ds.mappers):
-                ci, off = ds.bundle_col[f], ds.bundle_off[f]
-                dbf, nbf = m.default_bin, m.num_bin
-                mfb[f, dbf] = 1.0
-                for b in range(nbf):
-                    if off < 0:
-                        expand[f, b] = ci * B + b
-                    elif b != dbf:
-                        expand[f, b] = ci * B + off + b - (1 if b > dbf
-                                                           else 0)
-            self.meta = self.meta._replace(
-                bundle_expand=jnp.asarray(expand.reshape(-1)),
-                bundle_mfb=jnp.asarray(mfb))
-        if self.meta.monotone is not None \
-                and cfg.monotone_constraints_method not in (
-                    "basic", "intermediate"):
-            log_fatal("monotone_constraints_method="
-                      f"{cfg.monotone_constraints_method} is not "
-                      "implemented (use 'basic' or 'intermediate')")
+        with span("booster/init/transpose"):
+            Xt_np = np.ascontiguousarray(X.T)               # [F(b), N]
+            if self._host_pad != N_real:
+                Xt_np = np.pad(Xt_np,
+                               ((0, 0), (0, self._host_pad - N_real)))
+        with span("booster/init/upload", bytes_up=Xt_np.nbytes):
+            with self._prof_span("bin"):
+                self.X_t = self._put_rows(jnp.asarray(Xt_np), row_axis=1)
+        with span("booster/init/meta"):
+            self._init_feature_meta(ds, cfg)
         # per-STORAGE-COLUMN bin counts for the bin-width-tiered histogram
         # path (ops/histogram_tiered.py, docs/PERF.md): bundled storage
         # counts each bundle column's packed width, raw storage the mapper
@@ -826,6 +801,41 @@ class GBDT:
             "comm_bytes_per_tree": int(elems * 4 * factor),
         }
 
+    def _init_feature_meta(self, ds: BinnedDataset, cfg: Config) -> None:
+        """Per-feature device metadata: bins, missing types, constraints,
+        forced splits and the EFB expansion tables."""
+        self.meta = build_feature_meta(ds, cfg.monotone_constraints,
+                                       cfg.interaction_constraints)
+        if cfg.forcedsplits_filename:
+            forced_tbl = parse_forced_splits(cfg.forcedsplits_filename, ds)
+            if forced_tbl is not None:
+                self.meta = self.meta._replace(
+                    forced=jnp.asarray(forced_tbl))
+        if self._use_bundles:
+            F = len(ds.mappers)
+            B = self.num_bins_padded
+            expand = np.full((F, B), len(ds.bundles) * B, np.int32)  # fill
+            mfb = np.zeros((F, B), np.float32)
+            for f, m in enumerate(ds.mappers):
+                ci, off = ds.bundle_col[f], ds.bundle_off[f]
+                dbf, nbf = m.default_bin, m.num_bin
+                mfb[f, dbf] = 1.0
+                for b in range(nbf):
+                    if off < 0:
+                        expand[f, b] = ci * B + b
+                    elif b != dbf:
+                        expand[f, b] = ci * B + off + b - (1 if b > dbf
+                                                           else 0)
+            self.meta = self.meta._replace(
+                bundle_expand=jnp.asarray(expand.reshape(-1)),
+                bundle_mfb=jnp.asarray(mfb))
+        if self.meta.monotone is not None \
+                and cfg.monotone_constraints_method not in (
+                    "basic", "intermediate"):
+            log_fatal("monotone_constraints_method="
+                      f"{cfg.monotone_constraints_method} is not "
+                      "implemented (use 'basic' or 'intermediate')")
+
     def _prof_span(self, name: str):
         """The active profiler's span, or a no-op context."""
         return (self.profiler.span(name) if self.profiler is not None
@@ -907,8 +917,9 @@ class GBDT:
                 tree, leaf_of_row = grow_fn(
                     X_t, grad, hess, in_bag, meta, cfg_static, **kw)
                 from ..ops.histogram import take_leaf_values
-                new_scores = scores_k + take_leaf_values(
-                    tree.leaf_value * lr, leaf_of_row)
+                with jax.named_scope("train/score_update"):
+                    new_scores = scores_k + take_leaf_values(
+                        tree.leaf_value * lr, leaf_of_row)
                 # CEGB coupled-penalty state: features used by this tree
                 # (UpdateLeafBestSplits flips is_feature_used_in_split_,
                 # cost_effective_gradient_boosting.hpp:110)
@@ -1008,18 +1019,29 @@ class GBDT:
         return self._models
 
     def _materialize_models(self) -> None:
-        if self._drain is not None:
-            self._drain.flush()
-        if not self._pending:
+        if not self._pending and (self._drain is None
+                                  or self._drain.idle()):
             return
-        pending, self._pending = self._pending, []
-        # one batched transfer for all pending trees (one host sync).
-        # Records are either a single DeviceTree (bias: float) or a chunk
-        # of trees stacked [n, K, ...] (bias: list, iteration-major).
-        with global_timer.section("GBDT::MaterializeModels"):
-            hosts = jax.device_get([t for t, _ in pending])
-            for host, (_, bias) in zip(hosts, pending):
-                self._models.extend(self._host_record_to_trees(host, bias))
+        with span("train/drain"):
+            n0 = len(self._models)
+            if self._drain is not None:
+                with span("train/drain/flush_worker"):
+                    self._drain.flush()
+            pending, self._pending = self._pending, []
+            # one batched transfer for all pending trees (one host sync).
+            # Records are either a single DeviceTree (bias: float) or a
+            # chunk of trees stacked [n, K, ...] (bias: list,
+            # iteration-major).
+            if pending:
+                with span("train/drain/device_get"):
+                    hosts = jax.device_get([t for t, _ in pending])
+                    span_count(bytes_down=sum(
+                        a.nbytes for a in jax.tree.leaves(hosts)))
+                with span("train/drain/to_trees"):
+                    for host, (_, bias) in zip(hosts, pending):
+                        self._models.extend(
+                            self._host_record_to_trees(host, bias))
+            span_count(trees=len(self._models) - n0)
 
     def _host_record_to_trees(self, host, bias) -> List[Tree]:
         """Convert one device_get'd pending record (single tree or a
@@ -1062,7 +1084,8 @@ class GBDT:
                 need -= int(np.prod(np.shape(trees.num_leaves)) or 1)
                 if need <= 0:
                     break
-            got = jax.device_get(take)
+            with span("train/stop_check"):   # waits for the device
+                got = jax.device_get(take)
             counts = [c for g in reversed(got)
                       for c in np.asarray(g).reshape(-1)][-K:]
         elif self._models:
@@ -1218,6 +1241,13 @@ class GBDT:
         batched_eval_layout()), or None when no valid metrics ride
         along."""
         n_pad = max(n, int(n_pad or n))
+        d0 = self.dispatch_count
+        with span("train/chunk", trees=n, trees_padded=n_pad):
+            mvals = self._train_chunk(n, n_pad)
+            span_count(dispatches=self.dispatch_count - d0)
+        return mvals
+
+    def _train_chunk(self, n: int, n_pad: int) -> Optional[jnp.ndarray]:
         K = self.num_tree_per_iteration
         prof = self.profiler
         t0 = None
@@ -1225,40 +1255,43 @@ class GBDT:
             from ..runtime.profiler import device_barrier
             device_barrier()
             t0 = time.perf_counter()
-        init_scores = np.zeros(K)
-        if self.iter == 0:
-            init_scores = self._boost_from_average()
-        mode = self._batched_sampling_mode()
-        if mode == "host":
-            if self._in_bag_dev is None \
-                    or self.sample_strategy.resamples_at(self.iter):
-                in_bag = self.sample_strategy.sample(self.iter, None, None)
-                if self._host_pad != self.num_data:
-                    in_bag = jnp.pad(in_bag,
-                                     (0, self._host_pad - self.num_data))
-                self._in_bag_dev = self._put_rows(in_bag, row_axis=0)
-            in_bag0 = self._in_bag_dev
-        else:
-            # drawn in-scan; a constant placeholder keeps the compiled
-            # fn's arg pytree identical across chunks
-            in_bag0 = getattr(self, "_in_bag_ones", None)
-            if in_bag0 is None or in_bag0.shape[0] != self._host_pad:
-                in_bag0 = self._in_bag_ones = jnp.ones(
-                    (self._host_pad,), jnp.float32)
+        with span("train/chunk/prepare"):
+            init_scores = np.zeros(K)
+            if self.iter == 0:
+                init_scores = self._boost_from_average()
+            mode = self._batched_sampling_mode()
+            if mode == "host":
+                if self._in_bag_dev is None \
+                        or self.sample_strategy.resamples_at(self.iter):
+                    in_bag = self.sample_strategy.sample(
+                        self.iter, None, None)
+                    if self._host_pad != self.num_data:
+                        in_bag = jnp.pad(
+                            in_bag, (0, self._host_pad - self.num_data))
+                    self._in_bag_dev = self._put_rows(in_bag, row_axis=0)
+                in_bag0 = self._in_bag_dev
+            else:
+                # drawn in-scan; a constant placeholder keeps the
+                # compiled fn's arg pytree identical across chunks
+                in_bag0 = getattr(self, "_in_bag_ones", None)
+                if in_bag0 is None or in_bag0.shape[0] != self._host_pad:
+                    in_bag0 = self._in_bag_ones = jnp.ones(
+                        (self._host_pad,), jnp.float32)
 
-        # per-iteration feature masks, precomputed host-side (same RNG
-        # stream as the per-iteration path); padded steps reuse an
-        # all-ones mask (their trees are discarded)
-        F = len(self.mappers)
-        masks_dev = jnp.stack(
-            [m if m is not None else jnp.ones((F,), bool)
-             for m in (self._feature_mask_for_iter(self.iter + i)
-                       for i in range(n))]
-            + [jnp.ones((F,), bool)] * (n_pad - n))
+            # per-iteration feature masks, precomputed host-side (same
+            # RNG stream as the per-iteration path); padded steps reuse
+            # an all-ones mask (their trees are discarded)
+            F = len(self.mappers)
+            masks_dev = jnp.stack(
+                [m if m is not None else jnp.ones((F,), bool)
+                 for m in (self._feature_mask_for_iter(self.iter + i)
+                           for i in range(n))]
+                + [jnp.ones((F,), bool)] * (n_pad - n))
 
-        scan_fn = self._get_scan_fn(n_pad, mode)
+        with span("train/chunk/scan_fn"):
+            scan_fn = self._get_scan_fn(n_pad, mode)
         self._count_dispatch()
-        with global_timer.section("GBDT::TrainItersBatched/scan"):
+        with span("train/chunk/dispatch"):
             new_scores, new_vscores, tree_stack, mvals = scan_fn(
                 self.X_t, self.scores, self.label_dev, self.weight_dev,
                 in_bag0, jnp.float32(self.shrinkage_rate),
@@ -1269,29 +1302,31 @@ class GBDT:
                 tuple(self._valid_label_dev),
                 tuple(self._valid_weight_dev),
                 tuple(jnp.float32(s) for s in self._valid_sumw))
-        self.scores = new_scores
-        for vi, vs in enumerate(new_vscores):
-            self._valid_scores[vi] = vs
-        if n < n_pad:
-            # tail chunk: drop the inert steps' trees/metrics on device so
-            # pending stacks and stop checks never see padding rows
-            tree_stack = jax.tree.map(lambda a: a[:n], tree_stack)
-            mvals = mvals[:n]
-            self._count_dispatch()
-        # ONE stacked pending record for the whole chunk (slicing happens
-        # host-side at materialization — per-tree device slices would
-        # reintroduce hundreds of dispatches); iteration-0 bias folds into
-        # the first tree. With the async drain active, the record goes to
-        # the worker so host conversion overlaps the NEXT chunk's device
-        # compute.
-        biases = [
-            float(init_scores[k]) if (self.iter + i) == 0 else 0.0
-            for i in range(n) for k in range(K)]
-        record = (tree_stack, biases)
-        if self._drain is not None:
-            self._drain.submit(record)
-        else:
-            self._pending.append(record)
+        with span("train/chunk/submit"):
+            self.scores = new_scores
+            for vi, vs in enumerate(new_vscores):
+                self._valid_scores[vi] = vs
+            if n < n_pad:
+                # tail chunk: drop the inert steps' trees/metrics on
+                # device so pending stacks and stop checks never see
+                # padding rows
+                tree_stack = jax.tree.map(lambda a: a[:n], tree_stack)
+                mvals = mvals[:n]
+                self._count_dispatch()
+            # ONE stacked pending record for the whole chunk (slicing
+            # happens host-side at materialization — per-tree device
+            # slices would reintroduce hundreds of dispatches);
+            # iteration-0 bias folds into the first tree. With the async
+            # drain active, the record goes to the worker so host
+            # conversion overlaps the NEXT chunk's device compute.
+            biases = [
+                float(init_scores[k]) if (self.iter + i) == 0 else 0.0
+                for i in range(n) for k in range(K)]
+            record = (tree_stack, biases)
+            if self._drain is not None:
+                self._drain.submit(record)
+            else:
+                self._pending.append(record)
         self.iter += n
         if prof is not None:
             from ..runtime.profiler import device_barrier
@@ -1334,11 +1369,12 @@ class GBDT:
                 mask, i = xs
                 it = start_iter + i
                 active = i < n_active
-                if K == 1:
-                    g, h = obj.get_gradients(scores[0], label, weight)
-                    g, h = g[None, :], h[None, :]
-                else:
-                    g, h = obj.get_gradients(scores, label, weight)
+                with jax.named_scope("train/gradients"):
+                    if K == 1:
+                        g, h = obj.get_gradients(scores[0], label, weight)
+                        g, h = g[None, :], h[None, :]
+                    else:
+                        g, h = obj.get_gradients(scores, label, weight)
                 if mode == "scan":
                     # device-side bagging/GOSS: pure function of `it`
                     # (+ this step's gradients for GOSS), bit-identical
@@ -1486,7 +1522,6 @@ class GBDT:
         t_grow0 = (time.perf_counter()
                    if (prof is not None and self._pre_part) else None)
         for k in range(K):
-          with global_timer.section("GBDT::TrainOneIter/grow"):
             with self._prof_span("grow"):
                 tree_dev, leaf_of_row, new_scores = self._grow_step(
                     self.X_t, g_dev[k], h_dev[k],
@@ -2035,8 +2070,17 @@ class GBDT:
                     pred_early_stop_margin: float = 10.0) -> np.ndarray:
         # f32 inputs may route to the device predictor below — capture
         # the original dtype before the host paths' f64 upcast
+        with span("predict/raw"):
+            return self._predict_raw(X, start_iteration, num_iteration,
+                                     pred_early_stop, pred_early_stop_freq,
+                                     pred_early_stop_margin)
+
+    def _predict_raw(self, X, start_iteration, num_iteration,
+                     pred_early_stop, pred_early_stop_freq,
+                     pred_early_stop_margin) -> np.ndarray:
         x_was_f32 = getattr(X, "dtype", None) == np.float32
-        X = np.asarray(X, dtype=np.float64)
+        with span("predict/cast_f64"):
+            X = np.asarray(X, dtype=np.float64)
         K = self.num_tree_per_iteration
         total_iters = len(self.models) // K
         end = total_iters if num_iteration <= 0 else min(
@@ -2062,27 +2106,39 @@ class GBDT:
                 if device_tables_bytes(trees, X.shape[1]) > 300_000_000:
                     trees = None
             if on_tpu and trees is not None:
+                span_count(trees=len(trees), device_route=1)
                 key = (start_iteration, end, len(self.models))
                 cache = getattr(self, "_device_tables_cache", None)
                 if cache is None or cache[0] != key:
-                    cache = (key, build_device_tables(trees, K, X.shape[1]))
+                    with span("predict/tables"):
+                        cache = (key, build_device_tables(trees, K,
+                                                          X.shape[1]))
                     self._device_tables_cache = cache
-                out = predict_margin_device(trees, K,
-                                            X.astype(np.float32),
+                with span("predict/cast_f32"):
+                    X32 = X.astype(np.float32)
+                out = predict_margin_device(trees, K, X32,
                                             tables=cache[1])
+                # the two host copies are given back here, under a name,
+                # and not at the return (0.2 s for 3.5 GB at 10.5M x 28)
+                with span("predict/release"):
+                    del X, X32
                 if self.average_output and end > start_iteration:
                     out /= (end - start_iteration)
                 return out
-        pm = self._packed_model(start_iteration, end)
-        # early stop is margin-based and meaningless for averaged (RF)
-        # output (prediction_early_stop.cpp operates on boosted margins)
-        margin = (pred_early_stop_margin
-                  if pred_early_stop and not self.average_output else None)
-        # freq counts ITERATIONS (each covering all K class trees), as in
-        # the reference's per-iteration early-stop counter
-        out = pm.predict_margin(X, early_stop_margin=margin,
-                                early_stop_freq=max(
-                                    1, int(pred_early_stop_freq)))
+        span_count(trees=(end - start_iteration) * K, device_route=0)
+        with span("predict/host_walk"):
+            pm = self._packed_model(start_iteration, end)
+            # early stop is margin-based and meaningless for averaged
+            # (RF) output (prediction_early_stop.cpp operates on boosted
+            # margins)
+            margin = (pred_early_stop_margin
+                      if pred_early_stop and not self.average_output
+                      else None)
+            # freq counts ITERATIONS (each covering all K class trees),
+            # as in the reference's per-iteration early-stop counter
+            out = pm.predict_margin(X, early_stop_margin=margin,
+                                    early_stop_freq=max(
+                                        1, int(pred_early_stop_freq)))
         if self.average_output and end > start_iteration:
             out /= (end - start_iteration)
         return out
@@ -2108,10 +2164,11 @@ class GBDT:
                 **pred_kwargs) -> np.ndarray:
         raw = self.predict_raw(X, start_iteration, num_iteration,
                                **pred_kwargs)
-        if not raw_score and self.objective is not None \
-                and self.objective.need_convert_output:
-            raw = self.objective.convert_output(raw)
-        return raw[0] if raw.shape[0] == 1 else raw.T
+        with span("predict/convert_output"):
+            if not raw_score and self.objective is not None \
+                    and self.objective.need_convert_output:
+                raw = self.objective.convert_output(raw)
+            return raw[0] if raw.shape[0] == 1 else raw.T
 
     def predict_leaf_index(self, X: np.ndarray, start_iteration: int = 0,
                            num_iteration: int = -1) -> np.ndarray:
@@ -2264,6 +2321,11 @@ class _AsyncTreeDrain:
 
     def submit(self, record) -> None:
         self._q.put(record)
+
+    def idle(self) -> bool:
+        """Nothing queued, converting, or converted and not yet folded."""
+        return (self._q.unfinished_tasks == 0 and not self._done
+                and self._error is None)
 
     def _run(self) -> None:
         while True:

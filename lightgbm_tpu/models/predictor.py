@@ -29,6 +29,7 @@ from typing import List, Optional
 
 import numpy as np
 
+from ..runtime.profiler import span
 from .tree import (Tree, MISSING_NAN, MISSING_ZERO, _CATEGORICAL_MASK,
                    _DEFAULT_LEFT_MASK, _KZERO_THRESHOLD)
 
@@ -420,18 +421,29 @@ def predict_margin_device(trees, num_class_models: int, X,
     import jax.numpy as jnp
 
     if tables is None:
-        tables = build_device_tables(trees, num_class_models, X.shape[1])
+        with span("predict/tables"):
+            tables = build_device_tables(trees, num_class_models,
+                                         X.shape[1])
     ohf, thr, dt, bits, P, c, lv, K = tables
     F = X.shape[1]
     N = X.shape[0]
-    Xd = jnp.asarray(np.asarray(X, np.float32)) \
-        if not isinstance(X, jnp.ndarray) else X.astype(jnp.float32)
-    Np = int(np.ceil(N / chunk) * chunk)
-    Xt = jnp.pad(Xd, ((0, Np - N), (0, 0))).T.reshape(F, Np // chunk,
-                                                      chunk)
-    out = np.asarray(jax.device_get(_get_device_margin()(
-        Xt, ohf, thr, dt, bits, P, c, lv, K=K)))[:, :N]
-    return out.astype(np.float64)
+    with span("predict/upload", bytes_up=N * F * 4):
+        Xd = jnp.asarray(np.asarray(X, np.float32)) \
+            if not isinstance(X, jnp.ndarray) else X.astype(jnp.float32)
+    with span("predict/layout"):
+        Np = int(np.ceil(N / chunk) * chunk)
+        Xt = jnp.pad(Xd, ((0, Np - N), (0, 0))).T.reshape(
+            F, Np // chunk, chunk)
+    with span("predict/dispatch"):
+        out_dev = _get_device_margin()(Xt, ohf, thr, dt, bits, P, c, lv,
+                                       K=K)
+    # the wait device_get would make anyway, timed apart from the copy
+    with span("predict/wait_device"):
+        out_dev.block_until_ready()
+    with span("predict/download", bytes_down=out_dev.nbytes):
+        out = np.asarray(jax.device_get(out_dev))
+    with span("predict/cast_out"):
+        return out[:, :N].astype(np.float64)
 
 
 _DEVICE_MARGIN_JIT = None
@@ -465,42 +477,45 @@ def _device_margin(Xt, ohf, thr, dt, bits, P, c, lv, *, K):
 
         def per_tree(carry, tab):
             ohf_t, thr_t, dt_t, bits_t, P_t, c_t, lv_t = tab
-            fval = jax.lax.dot_general(
-                ohf_t, Xclean, (((1,), (0,)), ((), ())),
-                precision=hp)                              # [M, n]
-            nan_mask = jax.lax.dot_general(
-                ohf_t, nan_f32, (((1,), (0,)), ((), ())),
-                precision=hp) > 0.5
-            mt = (dt_t[:, None] >> 2) & 3
-            fval_n = jnp.where(nan_mask, 0.0, fval)
-            is_missing = ((mt == MISSING_ZERO)
-                          & (jnp.abs(fval_n) <= _KZERO_THRESHOLD)) | \
-                         ((mt == MISSING_NAN) & nan_mask)
-            default_left = (dt_t[:, None] & _DEFAULT_LEFT_MASK) != 0
-            go_left = jnp.where(is_missing, default_left,
-                                fval_n <= thr_t[:, None])
-            is_cat = (dt_t[:, None] & _CATEGORICAL_MASK) != 0
-            if W > 0:
-                valid = ~nan_mask & (fval >= 0)
-                iv = jnp.where(valid, fval, 0).astype(jnp.int32)
-                widx = jnp.clip(iv >> 5, 0, W - 1)
-                wsel = jnp.zeros(iv.shape, jnp.uint32)
-                for w in range(W):
-                    wsel = jnp.where(widx == w, bits_t[:, w:w + 1], wsel)
-                in_range = valid & (iv < W * 32)
-                gl_cat = in_range & (
-                    ((wsel >> (iv & 31).astype(jnp.uint32)) & 1) == 1)
-                go_left = jnp.where(is_cat, gl_cat, go_left)
-            # mismatch count per (leaf, row): ONE matmul. Products are
-            # 0/+-1 -> exact in bf16 with f32 accumulation.
-            counts = jax.lax.dot_general(
-                P_t, go_left.astype(jnp.float32),
-                (((1,), (0,)), ((), ())), precision=hp) + c_t[:, None]
-            hit = (counts == 0).astype(jnp.float32)        # [L, n]
-            out = jax.lax.dot_general(
-                lv_t[None, :], hit, (((1,), (0,)), ((), ())),
-                precision=hp)[0]                           # [n]
-            return carry + out.astype(jnp.float32), None
+            with jax.named_scope("predict/feature_select"):
+                fval = jax.lax.dot_general(
+                    ohf_t, Xclean, (((1,), (0,)), ((), ())),
+                    precision=hp)                          # [M, n]
+                nan_mask = jax.lax.dot_general(
+                    ohf_t, nan_f32, (((1,), (0,)), ((), ())),
+                    precision=hp) > 0.5
+            with jax.named_scope("predict/path_match"):
+                mt = (dt_t[:, None] >> 2) & 3
+                fval_n = jnp.where(nan_mask, 0.0, fval)
+                is_missing = ((mt == MISSING_ZERO)
+                              & (jnp.abs(fval_n) <= _KZERO_THRESHOLD)) | \
+                             ((mt == MISSING_NAN) & nan_mask)
+                default_left = (dt_t[:, None] & _DEFAULT_LEFT_MASK) != 0
+                go_left = jnp.where(is_missing, default_left,
+                                    fval_n <= thr_t[:, None])
+                is_cat = (dt_t[:, None] & _CATEGORICAL_MASK) != 0
+                if W > 0:
+                    valid = ~nan_mask & (fval >= 0)
+                    iv = jnp.where(valid, fval, 0).astype(jnp.int32)
+                    widx = jnp.clip(iv >> 5, 0, W - 1)
+                    wsel = jnp.zeros(iv.shape, jnp.uint32)
+                    for w in range(W):
+                        wsel = jnp.where(widx == w, bits_t[:, w:w + 1], wsel)
+                    in_range = valid & (iv < W * 32)
+                    gl_cat = in_range & (
+                        ((wsel >> (iv & 31).astype(jnp.uint32)) & 1) == 1)
+                    go_left = jnp.where(is_cat, gl_cat, go_left)
+                # mismatch count per (leaf, row): ONE matmul. Products are
+                # 0/+-1 -> exact in bf16 with f32 accumulation.
+                counts = jax.lax.dot_general(
+                    P_t, go_left.astype(jnp.float32),
+                    (((1,), (0,)), ((), ())), precision=hp) + c_t[:, None]
+                hit = (counts == 0).astype(jnp.float32)        # [L, n]
+            with jax.named_scope("predict/leaf_sum"):
+                out = jax.lax.dot_general(
+                    lv_t[None, :], hit, (((1,), (0,)), ((), ())),
+                    precision=hp)[0]                       # [n]
+                return carry + out.astype(jnp.float32), None
 
         n = Xc_t.shape[1]
         outs = []

@@ -55,7 +55,7 @@ from typing import List, NamedTuple, Optional, Sequence
 import numpy as np
 
 from ..models.tree import MISSING_NAN
-from ..utils import round_up as _round_up
+from ..utils import kernel_name, round_up as _round_up
 
 # meta row layout ([F, 8] f32, one row per feature)
 _M_IS_CAT = 0     # 1.0 = categorical feature
@@ -363,6 +363,7 @@ def _bucketize_pallas(X, t: DeviceBinTable):
         ],
         out_specs=pl.BlockSpec((_ROW_TILE, F), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, F), jnp.int32),
+        name=kernel_name("bucketize", f=F),
         interpret=pallas_interpret(),
     )(Xp, jnp.asarray(t.table), jnp.asarray(t.cat_val),
       jnp.asarray(t.meta))

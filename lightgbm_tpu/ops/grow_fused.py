@@ -79,7 +79,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..utils import round_up as _round_up
+from ..utils import kernel_name, round_up as _round_up
 from .histogram_pallas import (N_BLK, _compute_dims, _feat_chunk,
                                _hist_chunks, _make_W, _pack_wave_table,
                                _T_NL0, _unflatten_hist, _wave_logic)
@@ -383,6 +383,7 @@ def wave_pass_fused_pallas(
             jax.ShapeDtypeStruct((rows, Fh * LO), jnp.float32),
             jax.ShapeDtypeStruct((REC_ROWS, RECW), jnp.float32),
         ],
+        name=kernel_name("fused_wave", k=K, b=num_bins),
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
             # streamed contraction + one scan's cumsums over 2K children
@@ -685,6 +686,8 @@ def wave_pass_fused_tiled_pallas(
             jax.ShapeDtypeStruct((FT * rows_t, Th * LO), acc),
             jax.ShapeDtypeStruct((FT * REC_ROWS, RECW), jnp.float32),
         ],
+        name=kernel_name("fused_tiled", k=K, t=tile, b=num_bins,
+                         q=quantized),
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
             flops=2 * K * C * FT * Th * Np * B_lane

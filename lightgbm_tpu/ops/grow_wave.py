@@ -656,9 +656,11 @@ def grow_tree_wave(
                 use_f)
       return search
 
-    search = make_search(meta, feature_mask)
-    search_sh = make_search(meta_sh, fmask_sh, foff) if (fo or fp) \
-        else search
+    # every call of a search, vmapped or not, traces under one scope name
+    in_search_scope = jax.named_scope("train/split_search")
+    search = in_search_scope(make_search(meta, feature_mask))
+    search_sh = in_search_scope(make_search(meta_sh, fmask_sh, foff)) \
+        if (fo or fp) else search
 
     if fp:
         # each shard histograms ONLY its feature slice (over all rows)
@@ -695,6 +697,7 @@ def grow_tree_wave(
         u = jax.random.uniform(key, (n, F))
         return jnp.minimum((u * hi[None, :]).astype(jnp.int32), hi - 1)
 
+    @in_search_scope
     def search_voted(hist2, sum_g, sum_h, count, out, bmin, bmax,
                      sets_row, mv_nb, mv_mt, mv_db, mv_mono, mv_inter,
                      mv_fmask):
@@ -776,11 +779,12 @@ def grow_tree_wave(
 
     # feature-parallel builds the root on its feature slice only (the
     # whole point of the learner: 1/n of the histogram work per shard)
-    hist_root_local = build_histogram(X_hist if fp else X_t, vals0, B,
-                                      cfg.rows_per_chunk,
-                                      tiers=cfg.hist_tiers,
-                                      impl=cfg.hist_impl)
-    hist_root = exchange_hist(hist_root_local, psum, 0)
+    with jax.named_scope("train/root_histogram"):
+        hist_root_local = build_histogram(X_hist if fp else X_t, vals0, B,
+                                          cfg.rows_per_chunk,
+                                          tiers=cfg.hist_tiers,
+                                          impl=cfg.hist_impl)
+        hist_root = exchange_hist(hist_root_local, psum, 0)
     root_fid = jnp.asarray(0 if has_forced else -1, jnp.int32)
     used0 = (cegb_used if has_cegb else jnp.zeros((F,), bool))
     root_kwargs = dict(
@@ -1293,162 +1297,170 @@ def grow_tree_wave(
         drop_r = jnp.where(appv, r_j, L)
         drop_s = jnp.where(appv, s_j, M)
 
-        t = st.tree
-        bs2 = SplitResult(*[x[p_j] for x in st.best])
-        iscat2 = st.best_is_cat[p_j]
-        bits2 = st.best_bitset[p_j]
+        # ---- APPLY: write the selected splits into the tree and the
+        # per-leaf state
+        with jax.named_scope("train/apply"):
+            t = st.tree
+            bs2 = SplitResult(*[x[p_j] for x in st.best])
+            iscat2 = st.best_is_cat[p_j]
+            bits2 = st.best_bitset[p_j]
 
-        def rec(arr, v):
-            return arr.at[drop_s].set(v, mode="drop")
+            def rec(arr, v):
+                return arr.at[drop_s].set(v, mode="drop")
 
-        t = t._replace(
-            split_feature=rec(t.split_feature, bs2.feature),
-            threshold_bin=rec(t.threshold_bin, bs2.threshold),
-            default_left=rec(t.default_left, bs2.default_left),
-            split_gain=rec(t.split_gain, bs2.gain),
-            left_child=rec(t.left_child, ~p_j),
-            right_child=rec(t.right_child, ~r_j),
-            internal_value=rec(t.internal_value, st.leaf_output[p_j]),
-            internal_weight=rec(t.internal_weight, st.leaf_sum_h[p_j]),
-            internal_count=rec(t.internal_count, t.leaf_count[p_j]),
-            split_parent_leaf=rec(t.split_parent_leaf, p_j),
-            split_is_cat=rec(t.split_is_cat, iscat2),
-            split_cat_bitset=t.split_cat_bitset.at[drop_s].set(
-                bits2, mode="drop"),
-            num_leaves=nl0 + napp,
-        )
-        # rewire parent node child pointers (~p_j -> s_j). Sibling leaves
-        # may be applied in the SAME wave (same parent node), so the
-        # non-writing side must be dropped via out-of-range indices.
-        prev = st.leaf_parent_node[p_j]
-        fix = appv & (prev >= 0)
-        was_left = st.leaf_is_left[p_j]
-        t = t._replace(
-            left_child=t.left_child.at[
-                jnp.where(fix & was_left, prev, M)].set(s_j, mode="drop"),
-            right_child=t.right_child.at[
-                jnp.where(fix & ~was_left, prev, M)].set(s_j, mode="drop"))
+            t = t._replace(
+                split_feature=rec(t.split_feature, bs2.feature),
+                threshold_bin=rec(t.threshold_bin, bs2.threshold),
+                default_left=rec(t.default_left, bs2.default_left),
+                split_gain=rec(t.split_gain, bs2.gain),
+                left_child=rec(t.left_child, ~p_j),
+                right_child=rec(t.right_child, ~r_j),
+                internal_value=rec(t.internal_value, st.leaf_output[p_j]),
+                internal_weight=rec(t.internal_weight, st.leaf_sum_h[p_j]),
+                internal_count=rec(t.internal_count, t.leaf_count[p_j]),
+                split_parent_leaf=rec(t.split_parent_leaf, p_j),
+                split_is_cat=rec(t.split_is_cat, iscat2),
+                split_cat_bitset=t.split_cat_bitset.at[drop_s].set(
+                    bits2, mode="drop"),
+                num_leaves=nl0 + napp,
+            )
+            # rewire parent node child pointers (~p_j -> s_j). Sibling leaves
+            # may be applied in the SAME wave (same parent node), so the
+            # non-writing side must be dropped via out-of-range indices.
+            prev = st.leaf_parent_node[p_j]
+            fix = appv & (prev >= 0)
+            was_left = st.leaf_is_left[p_j]
+            t = t._replace(
+                left_child=t.left_child.at[
+                    jnp.where(fix & was_left, prev, M)].set(s_j, mode="drop"),
+                right_child=t.right_child.at[
+                    jnp.where(fix & ~was_left, prev, M)].set(s_j, mode="drop"))
 
-        def upd2(arr, lv, rv, cast=None):
-            if cast is not None:
-                lv, rv = lv.astype(cast), rv.astype(cast)
-            arr = arr.at[drop_p].set(lv, mode="drop")
-            return arr.at[drop_r].set(rv, mode="drop")
+            def upd2(arr, lv, rv, cast=None):
+                if cast is not None:
+                    lv, rv = lv.astype(cast), rv.astype(cast)
+                arr = arr.at[drop_p].set(lv, mode="drop")
+                return arr.at[drop_r].set(rv, mode="drop")
 
-        t = t._replace(
-            leaf_value=upd2(t.leaf_value, bs2.left_output, bs2.right_output),
-            leaf_weight=upd2(t.leaf_weight, bs2.left_sum_h, bs2.right_sum_h),
-            leaf_count=upd2(t.leaf_count, bs2.left_count, bs2.right_count,
-                            jnp.int32),
-        )
-        depth_child = st.leaf_depth[p_j] + 1
+            t = t._replace(
+                leaf_value=upd2(t.leaf_value, bs2.left_output,
+                                bs2.right_output),
+                leaf_weight=upd2(t.leaf_weight, bs2.left_sum_h,
+                                 bs2.right_sum_h),
+                leaf_count=upd2(t.leaf_count, bs2.left_count, bs2.right_count,
+                                jnp.int32),
+            )
+            depth_child = st.leaf_depth[p_j] + 1
 
-        # children own-histograms from the speculative pass + subtraction.
-        # One-hot matmul gathers/scatters: XLA's dynamic gather runs ~2GB/s
-        # here, while these read/write the 22MB caches at HBM speed.
-        # Caches are flat [L, C*F*B] (see hist_cache0).
-        hsm = _onehot_gather(st.small_hist, drop_p)          # [K, C*F*B]
-        hlg = _onehot_gather(st.hist_cache, drop_p) - hsm
-        sil = st.small_is_left[p_j][:, None]
-        hcl = jnp.where(sil, hsm, hlg)
-        hcr = jnp.where(sil, hlg, hsm)
-        hist_cache = _onehot_scatter(
-            st.hist_cache,
-            jnp.concatenate([drop_p, drop_r]),
-            jnp.concatenate([hcl, hcr], axis=0))
+            # children own-histograms from the speculative pass + subtraction.
+            # One-hot matmul gathers/scatters: XLA's dynamic gather runs ~2GB/s
+            # here, while these read/write the 22MB caches at HBM speed.
+            # Caches are flat [L, C*F*B] (see hist_cache0).
+            hsm = _onehot_gather(st.small_hist, drop_p)          # [K, C*F*B]
+            hlg = _onehot_gather(st.hist_cache, drop_p) - hsm
+            sil = st.small_is_left[p_j][:, None]
+            hcl = jnp.where(sil, hsm, hlg)
+            hcr = jnp.where(sil, hlg, hsm)
+            hist_cache = _onehot_scatter(
+                st.hist_cache,
+                jnp.concatenate([drop_p, drop_r]),
+                jnp.concatenate([hcl, hcr], axis=0))
 
-        # install the children's pre-searched best splits
-        best = SplitResult(*[
-            a.at[drop_p].set(lv[p_j], mode="drop")
-             .at[drop_r].set(rv[p_j], mode="drop")
-            for a, lv, rv in zip(st.best, st.bestl, st.bestr)])
-        best_is_cat = upd2(st.best_is_cat, st.catl[p_j], st.catr[p_j])
-        best_bitset = st.best_bitset.at[drop_p].set(
-            st.bitsl[p_j], mode="drop")
-        best_bitset = best_bitset.at[drop_r].set(
-            st.bitsr[p_j], mode="drop")
-        ready = upd2(st.ready, False, False)
-        almin, almax, armin, armax = child_bounds(
-            bs2, st.leaf_min[p_j], st.leaf_max[p_j])
-        leaf_min2 = upd2(st.leaf_min, almin, armin)
-        leaf_max2 = upd2(st.leaf_max, almax, armax)
-        asets = child_sets(bs2, st.leaf_sets[p_j])
-        leaf_sets2 = upd2(st.leaf_sets, asets, asets)
-        leaf_forced2 = upd2(st.leaf_forced, st.fidl[p_j], st.fidr[p_j],
-                            jnp.int32)
-        best_forced2 = upd2(st.best_forced, st.bfl[p_j], st.bfr[p_j])
-        feat_used2 = st.feat_used.at[
-            jnp.where(appv, bs2.feature, F)].set(True, mode="drop")
-        # subtree membership for monotone-intermediate bound refreshes:
-        # children inherit the parent leaf's mask and add the new node
-        if has_mono and mono_inter:
-            pu = st.under[p_j]                               # [K, M]
-            setcol = (jnp.arange(M, dtype=jnp.int32)[None, :]
-                      == drop_s[:, None])
-            under2 = st.under.at[drop_p].set(
-                jnp.where(setcol, jnp.int8(1), pu), mode="drop")
-            under2 = under2.at[drop_r].set(
-                jnp.where(setcol, jnp.int8(2), pu), mode="drop")
-        else:
-            under2 = st.under
+            # install the children's pre-searched best splits
+            best = SplitResult(*[
+                a.at[drop_p].set(lv[p_j], mode="drop")
+                 .at[drop_r].set(rv[p_j], mode="drop")
+                for a, lv, rv in zip(st.best, st.bestl, st.bestr)])
+            best_is_cat = upd2(st.best_is_cat, st.catl[p_j], st.catr[p_j])
+            best_bitset = st.best_bitset.at[drop_p].set(
+                st.bitsl[p_j], mode="drop")
+            best_bitset = best_bitset.at[drop_r].set(
+                st.bitsr[p_j], mode="drop")
+            ready = upd2(st.ready, False, False)
+            almin, almax, armin, armax = child_bounds(
+                bs2, st.leaf_min[p_j], st.leaf_max[p_j])
+            leaf_min2 = upd2(st.leaf_min, almin, armin)
+            leaf_max2 = upd2(st.leaf_max, almax, armax)
+            asets = child_sets(bs2, st.leaf_sets[p_j])
+            leaf_sets2 = upd2(st.leaf_sets, asets, asets)
+            leaf_forced2 = upd2(st.leaf_forced, st.fidl[p_j], st.fidr[p_j],
+                                jnp.int32)
+            best_forced2 = upd2(st.best_forced, st.bfl[p_j], st.bfr[p_j])
+            feat_used2 = st.feat_used.at[
+                jnp.where(appv, bs2.feature, F)].set(True, mode="drop")
+            # subtree membership for monotone-intermediate bound refreshes:
+            # children inherit the parent leaf's mask and add the new node
+            if has_mono and mono_inter:
+                pu = st.under[p_j]                               # [K, M]
+                setcol = (jnp.arange(M, dtype=jnp.int32)[None, :]
+                          == drop_s[:, None])
+                under2 = st.under.at[drop_p].set(
+                    jnp.where(setcol, jnp.int8(1), pu), mode="drop")
+                under2 = under2.at[drop_r].set(
+                    jnp.where(setcol, jnp.int8(2), pu), mode="drop")
+            else:
+                under2 = st.under
 
-        st = st._replace(
-            under=under2,
-            tree=t,
-            leaf_parent_node=upd2(st.leaf_parent_node, s_j, s_j, jnp.int32),
-            leaf_is_left=upd2(st.leaf_is_left,
-                              jnp.ones((KMAX,), bool),
-                              jnp.zeros((KMAX,), bool)),
-            leaf_depth=upd2(st.leaf_depth, depth_child, depth_child,
-                            jnp.int32),
-            leaf_output=upd2(st.leaf_output, bs2.left_output,
-                             bs2.right_output),
-            leaf_sum_g=upd2(st.leaf_sum_g, bs2.left_sum_g, bs2.right_sum_g),
-            leaf_sum_h=upd2(st.leaf_sum_h, bs2.left_sum_h, bs2.right_sum_h),
-            hist_cache=hist_cache, ready=ready,
-            leaf_min=leaf_min2, leaf_max=leaf_max2,
-            leaf_sets=leaf_sets2,
-            best=best, best_is_cat=best_is_cat, best_bitset=best_bitset,
-            leaf_forced=leaf_forced2, best_forced=best_forced2,
-            feat_used=feat_used2,
-        )
+            st = st._replace(
+                under=under2,
+                tree=t,
+                leaf_parent_node=upd2(st.leaf_parent_node, s_j, s_j,
+                                      jnp.int32),
+                leaf_is_left=upd2(st.leaf_is_left,
+                                  jnp.ones((KMAX,), bool),
+                                  jnp.zeros((KMAX,), bool)),
+                leaf_depth=upd2(st.leaf_depth, depth_child, depth_child,
+                                jnp.int32),
+                leaf_output=upd2(st.leaf_output, bs2.left_output,
+                                 bs2.right_output),
+                leaf_sum_g=upd2(st.leaf_sum_g, bs2.left_sum_g,
+                                bs2.right_sum_g),
+                leaf_sum_h=upd2(st.leaf_sum_h, bs2.left_sum_h,
+                                bs2.right_sum_h),
+                hist_cache=hist_cache, ready=ready,
+                leaf_min=leaf_min2, leaf_max=leaf_max2,
+                leaf_sets=leaf_sets2,
+                best=best, best_is_cat=best_is_cat, best_bitset=best_bitset,
+                leaf_forced=leaf_forced2, best_forced=best_forced2,
+                feat_used=feat_used2,
+            )
 
-        if has_mono and mono_inter:
-            # ---- refresh intermediate bounds against CURRENT subtree
-            # output extrema (the batched fixpoint of the reference's
-            # leaves_to_update propagation, GoUpToFindLeavesToUpdate,
-            # monotone_constraints.hpp:625): for an increasing split at
-            # node n, every leaf in left(n) is capped above by
-            # min(outputs over right(n)) and vice versa. Leaves whose
-            # bounds MOVED are re-searched (ready cleared).
-            act = jnp.arange(L) < st.tree.num_leaves
-            o_min = jnp.where(act, st.leaf_output, jnp.inf)[:, None]
-            o_max = jnp.where(act, st.leaf_output, -jnp.inf)[:, None]
-            uL = st.under == 1                               # [L, M]
-            uR = st.under == 2
-            lmax_n = jnp.max(jnp.where(uL, o_max, -jnp.inf), axis=0)
-            rmin_n = jnp.min(jnp.where(uR, o_min, jnp.inf), axis=0)
-            lmin_n = jnp.min(jnp.where(uL, o_min, jnp.inf), axis=0)
-            rmax_n = jnp.max(jnp.where(uR, o_max, -jnp.inf), axis=0)
-            node_act = jnp.arange(M) < st.tree.num_leaves - 1
-            mono_n = jnp.where(node_act,
-                               meta.monotone[st.tree.split_feature]
-                               .astype(jnp.int32), 0)        # [M]
-            capmax = jnp.where(
-                (mono_n > 0)[None, :] & uL, rmin_n[None, :],
-                jnp.where((mono_n < 0)[None, :] & uR, lmin_n[None, :],
-                          jnp.inf))
-            capmin = jnp.where(
-                (mono_n > 0)[None, :] & uR, lmax_n[None, :],
-                jnp.where((mono_n < 0)[None, :] & uL, rmax_n[None, :],
-                          -jnp.inf))
-            new_max = jnp.min(capmax, axis=1)                # [L]
-            new_min = jnp.max(capmin, axis=1)
-            moved = act & ((jnp.abs(new_min - st.leaf_min) > 1e-12)
-                           | (jnp.abs(new_max - st.leaf_max) > 1e-12))
-            st = st._replace(leaf_min=new_min, leaf_max=new_max,
-                             ready=st.ready & ~moved,
-                             stale=st.stale | moved)
+            if has_mono and mono_inter:
+                # ---- refresh intermediate bounds against CURRENT subtree
+                # output extrema (the batched fixpoint of the reference's
+                # leaves_to_update propagation, GoUpToFindLeavesToUpdate,
+                # monotone_constraints.hpp:625): for an increasing split at
+                # node n, every leaf in left(n) is capped above by
+                # min(outputs over right(n)) and vice versa. Leaves whose
+                # bounds MOVED are re-searched (ready cleared).
+                act = jnp.arange(L) < st.tree.num_leaves
+                o_min = jnp.where(act, st.leaf_output, jnp.inf)[:, None]
+                o_max = jnp.where(act, st.leaf_output, -jnp.inf)[:, None]
+                uL = st.under == 1                               # [L, M]
+                uR = st.under == 2
+                lmax_n = jnp.max(jnp.where(uL, o_max, -jnp.inf), axis=0)
+                rmin_n = jnp.min(jnp.where(uR, o_min, jnp.inf), axis=0)
+                lmin_n = jnp.min(jnp.where(uL, o_min, jnp.inf), axis=0)
+                rmax_n = jnp.max(jnp.where(uR, o_max, -jnp.inf), axis=0)
+                node_act = jnp.arange(M) < st.tree.num_leaves - 1
+                mono_n = jnp.where(node_act,
+                                   meta.monotone[st.tree.split_feature]
+                                   .astype(jnp.int32), 0)        # [M]
+                capmax = jnp.where(
+                    (mono_n > 0)[None, :] & uL, rmin_n[None, :],
+                    jnp.where((mono_n < 0)[None, :] & uR, lmin_n[None, :],
+                              jnp.inf))
+                capmin = jnp.where(
+                    (mono_n > 0)[None, :] & uR, lmax_n[None, :],
+                    jnp.where((mono_n < 0)[None, :] & uL, rmax_n[None, :],
+                              -jnp.inf))
+                new_max = jnp.min(capmax, axis=1)                # [L]
+                new_min = jnp.max(capmin, axis=1)
+                moved = act & ((jnp.abs(new_min - st.leaf_min) > 1e-12)
+                               | (jnp.abs(new_max - st.leaf_max) > 1e-12))
+                st = st._replace(leaf_min=new_min, leaf_max=new_max,
+                                 ready=st.ready & ~moved,
+                                 stale=st.stale | moved)
 
         # ---- SPECULATE selection: top-K unready frontier leaves by gain
         # (post-apply state: fresh children compete immediately)
@@ -1530,12 +1542,14 @@ def grow_tree_wave(
                         st.hist_cache, jnp.where(valid, cand, L)),
                     lambda: jnp.zeros((KMAX, st.hist_cache.shape[1]),
                                       st.hist_cache.dtype))
-                leaf_of_row, hist_wave, rec_wave = jax.lax.switch(
-                    kidx_m, fused_branches,
-                    (st.leaf_of_row, tbl16, scal_f, parent_flat))
+                with jax.named_scope("train/wave_pass"):
+                    leaf_of_row, hist_wave, rec_wave = jax.lax.switch(
+                        kidx_m, fused_branches,
+                        (st.leaf_of_row, tbl16, scal_f, parent_flat))
             else:
-                leaf_of_row, hist_wave = jax.lax.switch(
-                    kidx_m, mega_branches, (st.leaf_of_row, tbl16))
+                with jax.named_scope("train/wave_pass"):
+                    leaf_of_row, hist_wave = jax.lax.switch(
+                        kidx_m, mega_branches, (st.leaf_of_row, tbl16))
             st = st._replace(leaf_of_row=leaf_of_row)
             slot_small = None
         elif use_fused_tiled:
@@ -1646,10 +1660,11 @@ def grow_tree_wave(
                     _flush_pend, lambda lor: lor, st.leaf_of_row)
             else:
                 lor_in = st.leaf_of_row
-            leaf_of_row, hist_wave, rec_wave = jax.lax.switch(
-                kidx_t, tiled_branches,
-                (lor_in, dec, tbl16, pendl, pnl0, scal_f, parent_flat,
-                 fm_tiles))
+            with jax.named_scope("train/wave_pass"):
+                leaf_of_row, hist_wave, rec_wave = jax.lax.switch(
+                    kidx_t, tiled_branches,
+                    (lor_in, dec, tbl16, pendl, pnl0, scal_f, parent_flat,
+                     fm_tiles))
             # applies-only wave with fusion on: the relabel was DEFERRED
             # (branch 0 returned lor unchanged) — record it so the next
             # wave's kernel runs it as its pending pass
@@ -1735,7 +1750,9 @@ def grow_tree_wave(
                 kidx = jnp.searchsorted(bucket_bounds,
                                         n_cand).astype(jnp.int32)
                 kidx = jnp.minimum(kidx, len(buckets) - 1)
-                hist_local = jax.lax.switch(kidx, hist_branches, slot_small)
+                with jax.named_scope("train/wave_pass"):
+                    hist_local = jax.lax.switch(kidx, hist_branches,
+                                                slot_small)
             if fo:
                 if cfg.parallel_hist_mode == "allreduce":
                     # full-histogram psum baseline: every rank receives

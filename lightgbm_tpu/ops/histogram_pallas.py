@@ -56,7 +56,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..utils import round_up as _round_up
+from ..utils import kernel_name, round_up as _round_up
 
 F_BLK = 32          # int8 sublane tile
 N_BLK = 2048        # rows per grid step
@@ -298,6 +298,8 @@ def build_histogram_slots_pallas(
         out_specs=pl.BlockSpec((rows, Fh * LO), lambda f, n: (0, f),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((rows, out_cols), out_dtype),
+        name=kernel_name("hist_slots", k=K, b=num_bins, lo=LO,
+                         q=quantized),
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=HIST_VMEM_LIMIT_BYTES),
@@ -358,6 +360,7 @@ def take_leaf_values_pallas(
         out_specs=pl.BlockSpec((1, n_blk), lambda n: (0, n),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((1, Np), jnp.float32),
+        name=kernel_name("take_leaf_values", l=Lp),
         interpret=interpret,
     )(lor[None, :], v[None, :])
     return out[0, :N]
@@ -592,6 +595,7 @@ def wave_pass_pallas(
             jax.ShapeDtypeStruct((1, Np), jnp.int32),
             jax.ShapeDtypeStruct((rows, Fh * LO), out_dtype),
         ],
+        name=kernel_name("wave_pass", k=K, b=num_bins, lo=LO, q=quantized),
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=HIST_VMEM_LIMIT_BYTES),
@@ -688,6 +692,7 @@ def wave_apply_pallas(
             jax.ShapeDtypeStruct((1, Np), jnp.int32),
             jax.ShapeDtypeStruct((1, Np), jnp.int32),
         ],
+        name=kernel_name("wave_apply"),
         interpret=interpret,
     )(d, lor[None, :], tblp, nl0)
     return newlor[0, :N], slot[0, :N]
@@ -743,6 +748,7 @@ def wave_relabel_pallas(
         out_specs=pl.BlockSpec((1, n_blk), lambda n: (0, n),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((1, Np), jnp.int32),
+        name=kernel_name("wave_relabel", b=num_bins, q=quantized),
         interpret=interpret,
     )(X, v, lor[None, :], tblp, nl0)
     return newlor[0, :N]

@@ -49,7 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..utils import round_up as _round_up
+from ..utils import kernel_name, round_up as _round_up
 from .histogram_pallas import N_BLK, _make_W
 
 # one MXU contraction per column chunk: the [chunk_cols, R] one-hot
@@ -226,6 +226,7 @@ def build_histogram_slots_rowwise_flat(
         out_specs=pl.BlockSpec((rows, plan.total), lambda n: (0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((rows, plan.total), out_dtype),
+        name=kernel_name("hist_rowwise", k=K, q=quantized),
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
             flops=2 * rows * plan.total * Np,
@@ -446,6 +447,7 @@ def build_histogram_slots_rowwise_packed_flat(
         out_specs=pl.BlockSpec((rows, plan.total), lambda n: (0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((rows, plan.total), out_dtype),
+        name=kernel_name("hist_rowwise_packed", k=K, q=quantized),
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
             flops=2 * rows * plan.total * Np,

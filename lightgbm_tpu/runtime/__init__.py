@@ -8,8 +8,8 @@ This package is that idea generalized for the TPU build:
  * `profiler`  — per-iteration stage spans with proper device fencing
    (block_until_ready around jitted segments), throughput counters,
    an HBM watermark, a ring buffer, and JSON export consumed by
-   bench.py / BENCH_*.json. Absorbs the old `utils/timer.py`
-   global-timer machinery (which now re-exports from here).
+   bench.py / BENCH_*.json; and the unfenced `span` / `count`
+   primitive whose records also land in a profiler trace as `lgbm:*`.
  * `autotune`  — at train init, short timed probes of the candidate
    grower strategies (ops/grow.py / grow_fast.py / grow_wave.py) and
    histogram chunk layouts on a subsample of the real binned matrix;
@@ -32,7 +32,8 @@ XLA backend is initialized (multi-host bring-up orders
 jax.distributed.initialize before the first backend touch).
 """
 
-from .profiler import StageProfiler, Timer, global_timer, trace  # noqa: F401
+from .profiler import (StageProfiler, count, set_spans,  # noqa: F401
+                       span, spans)
 from .autotune import (AUTOTUNE_PREFERENCE, autotune_decision,  # noqa: F401
                        load_disk_cache, make_key, pin_comm_decision,
                        save_disk_cache)

@@ -1,20 +1,33 @@
-"""Device-fenced stage profiling (absorbs the old ``utils/timer.py``).
+"""Host spans on the profiler's clock, and device-fenced stage profiling.
 
 Two layers live here:
 
- * ``Timer`` — the process-global named-phase accumulator, the analog of
-   the reference's ``Common::Timer global_timer`` with RAII
-   ``FunctionTimer`` sections (utils/common.h:980,1044; printed at exit
-   when built with USE_TIMETAG). Unchanged API; ``utils/timer.py`` now
-   re-exports it for back-compat.
- * ``StageProfiler`` — per-iteration stage spans with proper device
-   synchronization. JAX dispatches asynchronously, so every span is
-   fenced with a device barrier (``jax.effects_barrier`` + blocking the
-   live arrays) before and after; the host clock then brackets real
-   device wall time. Each iteration records named spans plus an
+ * ``span`` / ``count`` — the program's one span-and-counter primitive.
+   A span times what the HOST did between two points and never fences
+   the device: no ``block_until_ready``, no barrier. Each span also
+   enters ``jax.profiler.TraceAnnotation("lgbm:" + name)``, so under
+   ``jax.profiler.start_trace`` it lands in the trace's ``/host:CPU``
+   plane beside the device planes, on the same clock, over the idle gap
+   it explains; with no profiler session the annotation is inert.
+   Finished spans go to one bounded ring (oldest dropped); ``spans()``
+   snapshots it (all spans of one call share their ``root``). Backend
+   compilations are counted where they happen: one ``jax.monitoring``
+   listener adds ``compiles`` and ``compile_s`` to the innermost open
+   span of the compiling thread.
+   The recorder is on by default at call / chunk / ingest-phase
+   granularity; ``set_spans(False)`` turns recording and annotation off.
+ * ``StageProfiler`` — the operator's fenced per-iteration profile
+   (``device_profile=true``). JAX dispatches asynchronously, so every
+   span is fenced with a device barrier (``jax.effects_barrier`` +
+   blocking the live arrays) before and after; the host clock then
+   brackets real device wall time. Outside an iteration (``bin``,
+   ``autotune``) it opens the primitive's span between its fences, so
+   there is one recorder and one name space; the spans of an iteration
+   stay in its own per-iteration ring, since the span ring is never
+   written per tree. Each iteration records named spans plus an
    ``other`` catch-all (iteration wall minus the sum of explicit spans)
-   so the per-stage breakdown always sums to the measured wall time.
-   A bounded ring buffer keeps the most recent iterations; totals,
+   so the per-stage breakdown always sums to the measured wall time. A
+   bounded ring buffer keeps the most recent iterations; totals,
    throughput counters (row-iters/s) and an HBM watermark
    (``jax.local_devices()[0].memory_stats()``) accumulate for the whole
    run. ``to_dict``/``export_json`` emit the JSON shape consumed by
@@ -29,13 +42,17 @@ opaque ``grow`` span.
 
 from __future__ import annotations
 
-import atexit
 import collections
 import contextlib
+import itertools
 import json
-import os
+import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
+
+SPAN_PREFIX = "lgbm:"           # a span's name in a profiler trace
+SPAN_RING_SIZE = 4096
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 def device_barrier() -> None:
@@ -53,65 +70,145 @@ def device_barrier() -> None:
         pass
 
 
-class Timer:
-    """reference: Common::Timer (utils/common.h:980)."""
+# ---------------------------------------------------------------------------
+# spans and counts
+# ---------------------------------------------------------------------------
 
-    def __init__(self) -> None:
-        self.acc: Dict[str, float] = {}
-        self.counts: Dict[str, int] = {}
-        self._printed = False
+def _trace_annotation(name: str):
+    """The profiler's own host annotation for ``name`` (jax imported
+    lazily: this module stays importable host-only)."""
+    import jax.profiler
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name)
 
-    @contextlib.contextmanager
-    def section(self, name: str, block: bool = False):
-        """Time a named section (FunctionTimer, common.h:1044). With
-        block=True, waits for all dispatched device work first and after
-        (so the section reflects device wall time)."""
-        if block:
-            self._barrier()
-        t0 = time.perf_counter()
+
+class _Span:
+    """One open (then finished) span; ``to_dict`` is its record."""
+
+    __slots__ = ("_rec", "name", "id", "parent", "root", "start_ns",
+                 "end_ns", "thread", "counts", "_ann")
+
+    def __init__(self, rec: "SpanRecorder", name: str,
+                 counts: Dict[str, float]) -> None:
+        self._rec = rec
+        self.name = name
+        self.counts = counts
+
+    def __enter__(self) -> "_Span":
+        stack = self._rec._stack()
+        self.id = next(self._rec._ids)
+        if stack:
+            self.parent, self.root = stack[-1].id, stack[-1].root
+        else:
+            self.parent, self.root = None, self.id
+        self.thread = threading.current_thread().name
+        stack.append(self)
+        self._ann = _trace_annotation(self.name)
+        self._ann.__enter__()
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        self.end_ns = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
+        self._rec._stack().pop()
+        self._rec.ring.append(self)
+
+    def to_dict(self) -> Dict[str, Any]:
+        return {"name": self.name, "id": self.id, "parent": self.parent,
+                "root": self.root, "start_ns": self.start_ns,
+                "end_ns": self.end_ns, "thread": self.thread,
+                "counts": dict(self.counts)}
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+class SpanRecorder:
+    """Finished spans in one bounded ring, open spans on a stack per
+    thread (a span's parent is the innermost open span of its own
+    thread; its root is the outermost, whose id every span of one
+    ``predict`` call, one chunk, one ``construct`` shares)."""
+
+    def __init__(self, maxlen: int = SPAN_RING_SIZE) -> None:
+        self.ring: collections.deque = collections.deque(maxlen=maxlen)
+        self.enabled = True
+        self.compiles_outside_spans = 0
+        self._ids = itertools.count(1)
+        self._local = threading.local()
+
+    def _stack(self) -> List[_Span]:
         try:
-            yield
-        finally:
-            if block:
-                self._barrier()
-            dt = time.perf_counter() - t0
-            self.acc[name] = self.acc.get(name, 0.0) + dt
-            self.counts[name] = self.counts.get(name, 0) + 1
+            return self._local.stack
+        except AttributeError:
+            self._local.stack = []
+            return self._local.stack
 
-    _barrier = staticmethod(device_barrier)
+    def span(self, name: str, **counts: float):
+        """Context manager: time the block on the host clock (no device
+        fence) and record it; ``counts`` start the span's counts."""
+        if not self.enabled:
+            return _NO_SPAN
+        return _Span(self, name, counts)
 
-    def summary(self) -> str:
-        lines = ["[LightGBM-TPU] [Info] Time summary:"]
-        for name in sorted(self.acc, key=lambda n: -self.acc[n]):
-            lines.append(f"  {name}: {self.acc[name]:.3f}s "
-                         f"({self.counts[name]} calls)")
-        return "\n".join(lines)
+    def count(self, **counts: float) -> None:
+        """Add to the counts of the innermost open span of this thread
+        (nothing open: nothing counted)."""
+        stack = self._stack()
+        if stack:
+            c = stack[-1].counts
+            for k, v in counts.items():
+                c[k] = c.get(k, 0) + v
 
-    def reset(self) -> None:
-        self.acc.clear()
-        self.counts.clear()
+    def on_compile(self, secs: float) -> None:
+        stack = self._stack()
+        if stack:
+            self.count(compiles=1, compile_s=secs)
+        elif self.enabled:
+            self.compiles_outside_spans += 1
 
-    def print_summary(self) -> None:
-        from ..utils.log import log_info
-        for line in self.summary().split("\n"):
-            log_info(line)
-
-
-global_timer = Timer()
-
-if os.environ.get("LIGHTGBM_TPU_TIMETAG", "") not in ("", "0", "false"):
-    atexit.register(lambda: global_timer.acc
-                    and global_timer.print_summary())
+    def spans(self) -> List[Dict[str, Any]]:
+        """Snapshot of the ring, oldest first, as plain dicts."""
+        return [s.to_dict() for s in list(self.ring)]
 
 
-@contextlib.contextmanager
-def trace(log_dir: str):
-    """Capture an XLA device profile for the enclosed region (the TPU
-    analog of the reference's USE_TIMETAG device phases; view with
-    tensorboard or xprof)."""
-    import jax
-    with jax.profiler.trace(log_dir):
-        yield
+_RECORDER = SpanRecorder()
+_compile_listener_on = False
+
+
+def _on_duration_event(event: str, secs: float, **_: Any) -> None:
+    if event == COMPILE_EVENT:
+        _RECORDER.on_compile(secs)
+
+
+def span(name: str, **counts: float):
+    """``with span("predict/upload", bytes_up=n): ...`` on the process's
+    recorder. Names are the contract the benchmark's readers match
+    (PERF.md section 3 lists each with the metric it feeds)."""
+    global _compile_listener_on
+    if not _compile_listener_on and _RECORDER.enabled:
+        import jax.monitoring
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_duration_event)
+        _compile_listener_on = True
+    return _RECORDER.span(name, **counts)
+
+
+def count(**counts: float) -> None:
+    _RECORDER.count(**counts)
+
+
+def spans() -> List[Dict[str, Any]]:
+    return _RECORDER.spans()
+
+
+def compiles_outside_spans() -> int:
+    return _RECORDER.compiles_outside_spans
+
+
+def set_spans(on: bool) -> None:
+    """Turn recording and annotation on (the default) or off. Spans
+    already open finish and are kept."""
+    _RECORDER.enabled = bool(on)
 
 
 def _hbm_peak_bytes() -> Optional[int]:
@@ -152,9 +249,13 @@ class StageProfiler:
 
     def __init__(self, ring_size: int = RING_SIZE,
                  clock: Callable[[], float] = time.perf_counter,
-                 barrier: Callable[[], None] = device_barrier) -> None:
+                 barrier: Callable[[], None] = device_barrier,
+                 record_spans: bool = True) -> None:
         self._clock = clock
         self._barrier = barrier
+        # serving's per-batch accounting passes False: the span ring is
+        # for calls, chunks and ingest phases, never per request
+        self._record_spans = record_spans
         self.ring: collections.deque = collections.deque(maxlen=ring_size)
         self.totals: Dict[str, float] = {}
         self.counts: Dict[str, int] = {}
@@ -184,13 +285,16 @@ class StageProfiler:
     def span(self, name: str, tenant: Optional[str] = None):
         """Fence the device, time the block, fence again. Inside an
         iteration the span lands in that iteration's record; outside it
-        accumulates into totals only (init-scope work such as "bin").
-        With ``tenant`` set, the span also lands in that tenant's row of
-        the per-tenant table (fleet serving)."""
+        accumulates into totals only (init-scope work such as "bin") and
+        the block also runs under the recorder's ``span(name)``, between
+        the fences. With ``tenant`` set, the span also lands in that
+        tenant's row of the per-tenant table (fleet serving)."""
         self._barrier()
         t0 = self._clock()
+        recorded = self._record_spans and self._iter_spans is None
         try:
-            yield
+            with span(name) if recorded else _NO_SPAN:
+                yield
         finally:
             self._barrier()
             dt = self._clock() - t0
@@ -488,17 +592,15 @@ def probe_stage_breakdown(X_t, grad, hess, meta, cfg,
     return out
 
 
-def count_pallas_launch_sites(fn: Callable, *args: Any,
-                              **kwargs: Any) -> int:
-    """Static count of Pallas kernel launch sites in ``fn``'s jaxpr.
+def pallas_kernel_names(fn: Callable, *args: Any,
+                        **kwargs: Any) -> List[str]:
+    """The kernel name of every Pallas launch site in ``fn``'s jaxpr.
 
     Traces ``fn`` on the given args (abstract — nothing executes) and
     walks every equation, recursing into sub-jaxprs (cond branches,
-    while bodies, pjit/scan calls), counting ``pallas_call`` primitives.
-    Sites inside a while body dispatch once per trip, so for the wave
-    grower this is exactly the launches-per-wave figure the relabel
-    fusion halves (docs/PERF.md §6) — the dispatch-count analog that
-    regression tests pin (tests/test_grow_fused.py)."""
+    while bodies, pjit/scan calls), collecting each ``pallas_call``'s
+    name: what the site passed as ``name=`` (utils.kernel_name), else
+    the kernel function's own name."""
     import jax
 
     def sub_jaxprs(params: Dict[str, Any]):
@@ -509,13 +611,24 @@ def count_pallas_launch_sites(fn: Callable, *args: Any,
                 elif hasattr(x, "jaxpr"):           # ClosedJaxpr
                     yield x.jaxpr
 
-    def walk(jaxpr) -> int:
-        n = 0
+    def walk(jaxpr) -> List[str]:
+        names: List[str] = []
         for eqn in jaxpr.eqns:
             if "pallas_call" in eqn.primitive.name:
-                n += 1
+                names.append(str(eqn.params["name"]))
             for sj in sub_jaxprs(eqn.params):
-                n += walk(sj)
-        return n
+                names += walk(sj)
+        return names
 
     return walk(jax.make_jaxpr(fn, **kwargs)(*args).jaxpr)
+
+
+def count_pallas_launch_sites(fn: Callable, *args: Any,
+                              **kwargs: Any) -> int:
+    """Static count of Pallas kernel launch sites in ``fn``'s jaxpr
+    (``pallas_kernel_names``' walk). Sites inside a while body dispatch
+    once per trip, so for the wave grower this is exactly the
+    launches-per-wave figure the relabel fusion halves (docs/PERF.md §6)
+    — the dispatch-count analog that regression tests pin
+    (tests/test_grow_fused.py)."""
+    return len(pallas_kernel_names(fn, *args, **kwargs))

@@ -198,7 +198,7 @@ class ModelFleet:
         self.fault_plan = fault_plan
         # no device fencing: fleet spans time live traffic
         self.profiler = profiler if profiler is not None else \
-            StageProfiler(barrier=lambda: None)
+            StageProfiler(barrier=lambda: None, record_spans=False)
         self._session_opts = dict(session_opts or {})
         self._admission_opts = dict(admission_opts or {})
         self._breaker_opts = dict(breaker_opts or {})

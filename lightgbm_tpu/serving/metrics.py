@@ -36,7 +36,8 @@ class ServingMetrics:
         # profiler WITHOUT device fencing: serving spans time enqueued
         # host work per batch; a live-traffic barrier per batch would
         # serialize the very pipeline being measured
-        self.profiler = StageProfiler(barrier=lambda: None)
+        self.profiler = StageProfiler(barrier=lambda: None,
+                                      record_spans=False)
         self.request_latency = LatencyStats()
         self.batch_latency = LatencyStats()
         self.max_batch = max_batch

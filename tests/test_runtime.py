@@ -171,15 +171,6 @@ def test_no_profiler_without_flag(binary_data):
     assert bst.get_profile() is None
 
 
-def test_timer_shim_still_importable():
-    from lightgbm_tpu.utils.timer import Timer, global_timer, trace  # noqa
-    from lightgbm_tpu.runtime.profiler import Timer as T2
-    assert Timer is T2
-    with global_timer.section("runtime-shim-test"):
-        pass
-    assert global_timer.counts["runtime-shim-test"] >= 1
-
-
 # ---------------------------------------------------------------------------
 # autotune
 
