@@ -2078,15 +2078,13 @@ class GBDT:
     def _predict_raw(self, X, start_iteration, num_iteration,
                      pred_early_stop, pred_early_stop_freq,
                      pred_early_stop_margin) -> np.ndarray:
-        x_was_f32 = getattr(X, "dtype", None) == np.float32
-        with span("predict/cast_f64"):
-            X = np.asarray(X, dtype=np.float64)
         K = self.num_tree_per_iteration
         total_iters = len(self.models) // K
         end = total_iters if num_iteration <= 0 else min(
             total_iters, start_iteration + num_iteration)
+        rows = np.shape(X)[0]
         if end <= start_iteration:
-            return np.zeros((K, X.shape[0]), dtype=np.float64)
+            return np.zeros((K, rows), dtype=np.float64)
         # large FLOAT32 batches score on the accelerator (the matmul
         # predictor, models/predictor.py predict_margin_device — the
         # reference's parallel Predictor analog, application/predictor.hpp).
@@ -2094,16 +2092,24 @@ class GBDT:
         # which routes f32 values exactly like the host's f64 walk; f64
         # inputs with sub-f32 precision stay on the host. Small batches
         # and early-stop stay on the host walk too.
-        if (x_was_f32 and X.shape[0] >= 100_000 and not pred_early_stop
+        # Float32 rows go to the device as they are: the float64 copy is
+        # made below, for the host walk alone.
+        if (getattr(X, "dtype", None) == np.float32 and rows >= 100_000
+                and not pred_early_stop
                 and not any(getattr(t, "is_linear", False)
                             for t in self.models)):
             on_tpu = jax.default_backend() == "tpu"
             if on_tpu:
                 from .predictor import (build_device_tables,
+                                        device_tables_budget,
                                         device_tables_bytes,
                                         predict_margin_device)
                 trees = self.models[start_iteration * K:end * K]
-                if device_tables_bytes(trees, X.shape[1]) > 300_000_000:
+                if device_tables_bytes(trees, X.shape[1]) \
+                        > device_tables_budget(*X.shape):
+                    # rows and tables do not fit the device together:
+                    # the host walk below answers, and says so
+                    span_count(tables_over_budget=1)
                     trees = None
             if on_tpu and trees is not None:
                 span_count(trees=len(trees), device_route=1)
@@ -2114,17 +2120,12 @@ class GBDT:
                         cache = (key, build_device_tables(trees, K,
                                                           X.shape[1]))
                     self._device_tables_cache = cache
-                with span("predict/cast_f32"):
-                    X32 = X.astype(np.float32)
-                out = predict_margin_device(trees, K, X32,
-                                            tables=cache[1])
-                # the two host copies are given back here, under a name,
-                # and not at the return (0.2 s for 3.5 GB at 10.5M x 28)
-                with span("predict/release"):
-                    del X, X32
+                out = predict_margin_device(trees, K, X, tables=cache[1])
                 if self.average_output and end > start_iteration:
                     out /= (end - start_iteration)
                 return out
+        with span("predict/cast_f64"):
+            X = np.asarray(X, dtype=np.float64)
         span_count(trees=(end - start_iteration) * K, device_route=0)
         with span("predict/host_walk"):
             pm = self._packed_model(start_iteration, end)
@@ -2255,6 +2256,14 @@ class GBDT:
     def load_model_from_string(cls, model_str: str,
                                config: Optional[Config] = None) -> "GBDT":
         """reference: GBDT::LoadModelFromString (gbdt_model_text.cpp:590)."""
+        with span("booster/load", bytes=len(model_str)):
+            gbdt = cls._load_model_from_string(model_str, config)
+            span_count(trees=len(gbdt.models))
+        return gbdt
+
+    @classmethod
+    def _load_model_from_string(cls, model_str: str,
+                                config: Optional[Config]) -> "GBDT":
         from ..config import resolve_params
         config = config or Config()
         gbdt = cls(config, None, None)

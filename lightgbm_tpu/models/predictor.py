@@ -25,6 +25,7 @@ Python loop per tree. The same packed arrays drive:
 
 from __future__ import annotations
 
+import functools
 from typing import Any, List, NamedTuple, Optional
 
 import numpy as np
@@ -392,6 +393,10 @@ class DeviceTables(NamedTuple):
         return _row_tile(self.ohf.shape[1], self.P.shape[1], self.F_pad,
                          self.bits.shape[2], self.has_nan)
 
+    @property
+    def nbytes(self) -> int:
+        return sum(a.nbytes for a in self.arrays)
+
 
 def _padded_shape(trees, F: int):
     """(M_pad, L_pad, F_pad, n_bias): node slots hold the widest tree's
@@ -448,6 +453,32 @@ def device_tables_bytes(trees, num_features: int) -> int:
     NEXT to the builder so routing budgets track the layout."""
     Mp, Lp, Fp, _ = _padded_shape(trees, num_features)
     return len(trees) * (Mp * 3 * Fp * 2 + Lp * Mp)
+
+
+@functools.lru_cache(maxsize=None)
+def _device_memory_bytes() -> Optional[int]:
+    """Memory of the first local device as its allocator states it, or
+    None where the backend keeps no allocator statistics (the CPU). Read
+    once a process: the limit does not change, and on a TPU the call
+    waits on the runtime (0.1 s between two 10.5M-row passes)."""
+    import jax
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit", 0)) or None
+
+
+def device_tables_budget(rows: int, num_features: int) -> int:
+    """Bytes a forest's tables may take of the device: what its memory
+    leaves beside the rows as `predict_margin_device` holds them, all at
+    once: X float32, x3 and, read or not, nanf (12 bytes a padded cell),
+    and a tenth more for `_layout`'s temporaries. The tables stay in HBM
+    and stream to the kernel a tree a grid step, so room is all they
+    cost. Negative where the rows alone do not fit: the host walk
+    answers then too. 300 MB where the backend states no memory."""
+    memory = _device_memory_bytes()
+    if memory is None:
+        return 300_000_000
+    held = rows * (4 * num_features + 8 * _round_up(num_features, 16))
+    return memory - held - held // 10
 
 
 def _go_left(fval, nan_mask, thr, dt, bits, *, has_zero):
@@ -737,10 +768,14 @@ def predict_margin_device(trees, num_class_models: int, X,
     with span("predict/upload", bytes_up=N * F * 4):
         Xd = jnp.asarray(np.asarray(X, np.float32)) \
             if not isinstance(X, jnp.ndarray) else X.astype(jnp.float32)
-    with span("predict/layout"):
+    # x3 holds 6 bytes of a padded cell, nanf (where read) 2 more
+    with span("predict/layout", layout_bytes=max(_round_up(N, n), n)
+              * tables.F_pad * (8 if tables.has_nan else 6)):
         x3, nanf = _get_layout()(Xd, F_pad=tables.F_pad, n=n,
                                  has_nan=tables.has_nan)
-    with span("predict/dispatch", fused=int(fused), row_tile=n):
+    with span("predict/dispatch", fused=int(fused), row_tile=n,
+              has_nan=int(tables.has_nan), f_pad=tables.F_pad,
+              m_pad=tables.ohf.shape[1], table_bytes=tables.nbytes):
         out_dev = _get_device_margin()(
             x3, nanf, *tables.arrays, K=tables.K, has_zero=tables.has_zero,
             n=n, fused=fused, interpret=interpret)
