@@ -2098,32 +2098,29 @@ class GBDT:
                 and not pred_early_stop
                 and not any(getattr(t, "is_linear", False)
                             for t in self.models)):
-            on_tpu = jax.default_backend() == "tpu"
-            if on_tpu:
+            if jax.default_backend() == "tpu":
                 from .predictor import (build_device_tables,
-                                        device_tables_budget,
-                                        device_tables_bytes,
                                         predict_margin_device)
                 trees = self.models[start_iteration * K:end * K]
-                if device_tables_bytes(trees, X.shape[1]) \
-                        > device_tables_budget(*X.shape):
-                    # rows and tables do not fit the device together:
-                    # the host walk below answers, and says so
-                    span_count(tables_over_budget=1)
-                    trees = None
-            if on_tpu and trees is not None:
-                span_count(trees=len(trees), device_route=1)
                 key = (start_iteration, end, len(self.models))
                 cache = getattr(self, "_device_tables_cache", None)
                 if cache is None or cache[0] != key:
+                    # None where the tables do not fit beside these rows
                     with span("predict/tables"):
-                        cache = (key, build_device_tables(trees, K,
-                                                          X.shape[1]))
+                        cache = (key, build_device_tables(
+                            trees, K, X.shape[1], rows=rows))
+                tables = cache[1]
+                if tables is None or tables.over_budget(rows):
+                    # rows and tables do not fit the device together:
+                    # the host walk below answers, and says so
+                    span_count(tables_over_budget=1)
+                else:
                     self._device_tables_cache = cache
-                out = predict_margin_device(trees, K, X, tables=cache[1])
-                if self.average_output and end > start_iteration:
-                    out /= (end - start_iteration)
-                return out
+                    span_count(trees=len(trees), device_route=1)
+                    out = predict_margin_device(trees, K, X, tables=tables)
+                    if self.average_output and end > start_iteration:
+                        out /= (end - start_iteration)
+                    return out
         with span("predict/cast_f64"):
             X = np.asarray(X, dtype=np.float64)
         span_count(trees=(end - start_iteration) * K, device_route=0)

@@ -229,10 +229,14 @@ def random_tree(rng, leaves, X, nan=False, cat_feature=None):
     return t
 
 
-def _predict_forest(leaves, F, K=1, nan=False, cat=False):
-    """The device predictor's kernel (models/predictor.py) against the
-    HOST's float32 walk of the same random forest: per tree the leaf by
-    ``Tree.get_leaf_index``, its float32 value added in tree order."""
+def _predict_forest(leaves, F, K=1, nan=False, cat=False, terms=1):
+    """The device predictor (models/predictor.py: the rows coded as
+    threshold ranks by ``_layout``, then the kernel) against the HOST's
+    float32 walk of the same random forest: per tree the leaf by
+    ``Tree.get_leaf_index``, its float32 value added in tree order.
+    ``terms`` is the int8 digits a code must take: every threshold is a
+    row's own value, so 6 trees of ``leaves`` leaves over few features
+    hold hundreds of distinct ones a feature."""
     from lightgbm_tpu.models import predictor as Pm
     rng = np.random.RandomState(leaves + F)
     X = rng.normal(size=(N, F)).astype(np.float32)
@@ -249,9 +253,12 @@ def _predict_forest(leaves, F, K=1, nan=False, cat=False):
             t.get_leaf_index(X.astype(np.float64))]
     n = tb.row_tile
 
+    assert tb.terms == terms, (tb.terms, tb.thresholds_max)
+
     def kernel(X):
-        x3, nanf = Pm._layout(X, F_pad=tb.F_pad, n=n, has_nan=tb.has_nan)
-        return Pm._forest_pallas(x3, nanf, *tb.arrays, K=K,
+        codes = Pm._layout(X, tb.tkeys, tb.ncat, n=n, terms=tb.terms,
+                           has_nan=tb.has_nan, dual=tb.dual)
+        return Pm._forest_pallas(codes, *tb.arrays, K=K, has_nan=tb.has_nan,
                                  has_zero=tb.has_zero, n=n)[:, :N]
     return (lambda _: (X,)), lambda interp: (
         (lambda X: jnp.asarray(ref)) if interp else kernel)
@@ -321,7 +328,9 @@ def cases():
             ("predict_forest L15 F130 K3 nan",
              lambda: _predict_forest(15, 130, K=3, nan=True)),
             ("predict_forest L31 F28 cat",
-             lambda: _predict_forest(31, 28, cat=True))]
+             lambda: _predict_forest(31, 28, cat=True)),
+            ("predict_forest L255 F4 nan c2",
+             lambda: _predict_forest(255, 4, nan=True, terms=2))]
     out = [(n, b, False) for n, b in out]
     out += [("fused narrow F32 B64", lambda: _fused(False), True),
             ("fused tiled F64 B64", lambda: _fused(True), True)]

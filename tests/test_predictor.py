@@ -162,25 +162,34 @@ def _walk_f32(trees, K, X):
     return out
 
 
-def _removed_xla_body(tables, X):
-    """The three-stage XLA body this kernel replaced, kept as a reference:
-    float32 one-hot feature select, float32 path matmul and the leaf
-    value through a third contraction, all at precision highest."""
+def _removed_xla_body(trees, K, X):
+    """The three-stage XLA body the kernel replaced, kept as a reference:
+    float32 one-hot feature select, float32 compare against the floored
+    thresholds, float32 path matmul and the leaf value through a third
+    contraction, all at precision highest. (No reference for infinite
+    values: its select multiplies them by zero.)"""
     import jax
     import jax.numpy as jnp
+    from lightgbm_tpu.models import predictor
     from lightgbm_tpu.models.tree import (MISSING_NAN, MISSING_ZERO,
                                           _CATEGORICAL_MASK,
                                           _DEFAULT_LEFT_MASK,
                                           _KZERO_THRESHOLD)
     hp = jax.lax.Precision.HIGHEST
-    F, K, W = tables.F, tables.K, tables.bits.shape[2]
+    F = X.shape[1]
+    M_pad, L_pad, n_bias = predictor._padded_shape(trees)
+    W = max([int(np.diff(t.cat_boundaries).max()) for t in trees
+             if t.num_cat > 0], default=0)
     Xt = jnp.asarray(X).T
     nan_f = jnp.isnan(Xt)
     Xclean = jnp.where(nan_f, 0.0, Xt)
     outs = [jnp.zeros(X.shape[0], jnp.float32) for _ in range(K)]
-    for i in range(tables.ohf.shape[0]):
-        ohf = tables.ohf[i, :, :F].astype(jnp.float32)
-        thr, dt = tables.thr[i], tables.dt[i]
+    for i, tree in enumerate(trees):
+        P, feat, thr, dt, bits, lv = predictor._tree_path_tables(
+            tree, M_pad, L_pad, W, n_bias)
+        ohf = jnp.asarray(feat[:, None] == np.arange(F), jnp.float32)
+        thr, dt = jnp.asarray(thr)[:, None], jnp.asarray(dt)[:, None]
+        bits = jnp.asarray(bits.view(np.int32))
         fval = jnp.dot(ohf, Xclean, precision=hp)
         nan_mask = jnp.dot(ohf, nan_f.astype(jnp.float32),
                            precision=hp) > 0.5
@@ -197,20 +206,20 @@ def _removed_xla_body(tables, X):
             widx = jnp.clip(iv >> 5, 0, W - 1)
             wsel = jnp.zeros(iv.shape, jnp.int32)
             for w in range(W):
-                wsel = jnp.where(widx == w, tables.bits[i][:, w:w + 1], wsel)
+                wsel = jnp.where(widx == w, bits[:, w:w + 1], wsel)
             gl_cat = valid & (iv < W * 32) & (((wsel >> (iv & 31)) & 1) == 1)
             go_left = jnp.where((dt & _CATEGORICAL_MASK) != 0, gl_cat,
                                 go_left)
-        counts = jnp.dot(tables.P[i].astype(jnp.float32),
+        counts = jnp.dot(jnp.asarray(P, jnp.float32),
                          go_left.astype(jnp.float32), precision=hp)
         hit = (counts == 0).astype(jnp.float32)
-        outs[i % K] = outs[i % K] + jnp.dot(tables.lv[i][:, 0], hit,
+        outs[i % K] = outs[i % K] + jnp.dot(jnp.asarray(lv), hit,
                                             precision=hp)
     return np.asarray(jnp.stack(outs))
 
 
-def _forest_case(name):
-    """(trees, K, rows to score) of one case."""
+def _trained_case(name):
+    """(trees, K, rows to score) of one trained case."""
     rng = np.random.RandomState(len(name))
     N, F, rounds = 3000, 10, 4
     params = {"objective": "binary", "num_leaves": 15, "verbose": -1,
@@ -255,23 +264,161 @@ def _forest_case(name):
     return g.models, g.num_tree_per_iteration, Xs
 
 
-@pytest.mark.parametrize("name", [
-    "leaves255", "leaves15", "multiclass3", "missing_nan", "missing_zero",
-    "categorical", "ragged_rows", "features6", "features130"])
+def _hand_tree(rng, nodes, leaf_values):
+    """A tree of len(nodes) + 1 leaves grown as LightGBM numbers one (node
+    i splits a seeded leaf of the i + 1 there are; the right child is
+    leaf i + 1). A node is (feature, threshold, decision_type), its
+    threshold a list of bitset words where it is categorical."""
+    from lightgbm_tpu.models.tree import Tree
+    t = Tree(len(nodes) + 1)
+    link = {0: None}                     # leaf -> (parent node, is_left)
+    words, bounds = [], [0]
+    for i, (f, thr, dtype) in enumerate(nodes):
+        leaf = rng.randint(0, i + 1)
+        if link[leaf] is not None:
+            node, is_left = link[leaf]
+            (t.left_child if is_left else t.right_child)[node] = i
+        t.left_child[i], t.right_child[i] = ~leaf, ~(i + 1)
+        link[leaf], link[i + 1] = (i, True), (i, False)
+        t.split_feature[i], t.decision_type[i] = f, dtype
+        if dtype & 1:
+            t.threshold[i] = t.threshold_in_bin[i] = len(bounds) - 1
+            words += list(thr)
+            bounds.append(len(words))
+        else:
+            t.threshold[i] = thr
+    t.leaf_value[:] = leaf_values
+    if len(bounds) > 1:
+        t.num_cat = len(bounds) - 1
+        t.cat_boundaries = np.asarray(bounds, np.int32)
+        t.cat_threshold = np.asarray(words, np.uint32)
+    return t
+
+
+# a threshold's neighbours, the ends of the range and of the zero band
+_F32 = np.finfo(np.float32)
+_EDGE_THRESHOLDS = [-1.5, 0.0, -0.0, 0.1, 1e-40, -1e-40, 1e-35, -1e-35,
+                    1e-36, float(_F32.max), -float(_F32.max), np.inf]
+
+
+def _edge_values():
+    """Float32 values at, one ulp under and one ulp over every edge
+    threshold as the device sees it (floored), plus the infinities, both
+    zeros, the smallest denormals and NaN."""
+    from lightgbm_tpu.models.predictor import floor_threshold_f32
+    at = floor_threshold_f32(np.asarray(_EDGE_THRESHOLDS))
+    with np.errstate(over="ignore"):
+        vals = np.concatenate([
+            at, np.nextafter(at, np.float32(-np.inf)),
+            np.nextafter(at, np.float32(np.inf)),
+            np.asarray([np.nan, np.inf, -np.inf, 0.0, -0.0,
+                        _F32.smallest_subnormal, -_F32.smallest_subnormal,
+                        _F32.tiny, -_F32.tiny], np.float32)])
+    return vals.astype(np.float32)
+
+
+def _hand_case(name):
+    """(trees, K, rows) of a forest built node by node. Leaf values are
+    whole numbers whose sums float32 holds, so the device's float32
+    margins must equal the host walk's float64 ones."""
+    rng = np.random.RandomState(len(name))
+    if name == "edge_values":
+        # one node a tree, so every row meets every node: each of 3
+        # features x every edge threshold x the 3 missing types x both
+        # default directions; a class's margin is the bit mask of its 24
+        # trees' decisions
+        F, vals = 3, _edge_values()
+        nodes = [(f, thr, missing << 2 | default_left << 1)
+                 for f in range(F) for thr in _EDGE_THRESHOLDS
+                 for missing in range(3) for default_left in range(2)]
+        K = len(nodes) // 24
+        trees = [_hand_tree(rng, [nd], [2.0 ** (i // K), 0.0])
+                 for i, nd in enumerate(nodes)]
+        return trees, K, np.concatenate([
+            np.stack([vals[rng.permutation(len(vals))] for _ in range(F)], 1)
+            for _ in range(8)])
+    F, K = 5, 1
+    X = rng.normal(size=(2500, F)).astype(np.float32)
+    X[rng.rand(*X.shape) < 0.05] = np.nan
+    X[rng.rand(*X.shape) < 0.05] = 0.0
+    if name in ("thresholds300", "terms3_stub"):
+        # 20 trees x 15 nodes, feature 0 at every node: 300 thresholds,
+        # each one a row's own value, so a rank off by one moves a row
+        def node():
+            return (0 if rng.rand() < 0.9 else rng.randint(1, F),
+                    float(np.nan_to_num(X[rng.randint(len(X)),
+                                          rng.randint(F)])),
+                    rng.randint(0, 3) << 2 | rng.randint(0, 2) << 1)
+        trees = [_hand_tree(rng, [node() for _ in range(15)],
+                            rng.randint(0, 1 << 16, 16)) for _ in range(20)]
+        for t in trees:
+            own = t.split_feature == 0
+            t.threshold[own] = np.nan_to_num(
+                X[rng.randint(0, len(X), own.sum()), 0])
+        return trees, K, X
+    assert name == "numeric_and_categorical"
+    # feature 0 holds categories and is also compared as a number;
+    # feature 1 is categorical alone, with two-word sets
+    X[:, 0] = rng.randint(-3, 40, len(X))
+    X[:, 1] = rng.randint(0, 70, len(X))
+    X[::17, :2] = np.nan
+    X[5::17, :2] += 0.5
+
+    def node():
+        f = rng.randint(0, 4)
+        if f == 1 or (f == 0 and rng.rand() < 0.5):
+            return (f, [rng.randint(0, 1 << 32, dtype=np.uint64)
+                        for _ in range(1 + f)], 1)
+        return (f, float(np.nan_to_num(X[rng.randint(len(X)), f])),
+                rng.randint(0, 3) << 2 | rng.randint(0, 2) << 1)
+    trees = []
+    for _ in range(12):
+        nodes = [node() for _ in range(rng.randint(3, 31))]
+        trees.append(_hand_tree(rng, nodes,
+                                rng.randint(0, 1 << 16, len(nodes) + 1)))
+    return trees, 2, X
+
+
+TRAINED = ["leaves255", "leaves15", "multiclass3", "missing_nan",
+           "missing_zero", "categorical", "ragged_rows", "features6",
+           "features130"]
+HAND = ["edge_values", "thresholds300", "terms3_stub",
+        "numeric_and_categorical"]
+
+
+@pytest.mark.parametrize("name", TRAINED + HAND)
 def test_fused_kernel_is_bit_equal(monkeypatch, name):
-    """The one-kernel predictor under the Pallas interpreter, the XLA
-    scans built from the same per-tree function, the float32 reference
-    walk and the removed three-stage body all give the same bits."""
+    """The one-kernel predictor under the Pallas interpreter and the XLA
+    scans built from the same per-tree function give the bits of the
+    host walk: the float32 reference walk, the removed three-stage body
+    and, where the leaf values are whole numbers, PackedModel's own
+    float64 margins."""
     from lightgbm_tpu.models import predictor
     from lightgbm_tpu.models.tree import MISSING_NAN, MISSING_ZERO
     from lightgbm_tpu.runtime import profiler
-    trees, K, X = _forest_case(name)
+    trees, K, X = (_hand_case if name in HAND else _trained_case)(name)
+    has_cat = any(t.num_cat > 0 for t in trees)
+    if name == "terms3_stub":
+        # three digits a code, and the thresholds counted 64 at a time
+        monkeypatch.setattr(predictor, "_code_terms", lambda codes_max: 3)
+        monkeypatch.setattr(predictor, "_COUNT_CHUNK", 64)
     tables = predictor.build_device_tables(trees, K, X.shape[1])
+    assert tables.tkeys.shape[1] % (64 if name == "terms3_stub" else 8) == 0
     kinds = {(int(d) >> 2) & 3 for t in trees for d in t.decision_type}
     assert tables.has_zero == (MISSING_ZERO in kinds)
-    assert tables.has_nan == (MISSING_NAN in kinds
-                              or any(t.num_cat > 0 for t in trees))
-    assert (tables.bits.shape[2] > 0) == (name == "categorical")
+    assert tables.has_nan == (MISSING_NAN in kinds or has_cat)
+    assert (tables.ncat is not None) == has_cat == (
+        name in ("categorical", "numeric_and_categorical"))
+    assert tables.ohf.dtype == np.int8
+    assert tables.ohf.shape[2] == tables.F_pad == tables.tkeys.shape[0]
+    # the term count is read from the forest: one int8 digit up to 126
+    # thresholds or categories a code row, two up to 2 ** 14 - 2
+    assert tables.terms == {"thresholds300": 2, "terms3_stub": 3}.get(
+        name, 1)
+    assert (126 < tables.thresholds_max <= 300) == (
+        name in ("thresholds300", "terms3_stub"))
+    assert tables.dual == ((0,) if name == "numeric_and_categorical"
+                           else ())
 
     def margins(interpret):
         monkeypatch.setenv("LIGHTGBM_TPU_PALLAS_INTERPRET",
@@ -283,10 +430,17 @@ def test_fused_kernel_is_bit_equal(monkeypatch, name):
                   if r["name"] == "predict/dispatch"][-1]["counts"]
         assert counts["fused"] == int(interpret)
         assert X.shape[0] % counts["row_tile"] != 0
-        return out.astype(np.float32)
+        assert counts["code_terms"] == tables.terms
+        assert counts["thresholds_max"] == tables.thresholds_max
+        return out
 
     kernel = margins(True)
-    ref = _walk_f32(trees, K, X)
-    assert np.array_equal(kernel, ref)
     assert np.array_equal(kernel, margins(False))
-    assert np.array_equal(kernel, _removed_xla_body(tables, X))
+    assert np.array_equal(kernel.astype(np.float32), _walk_f32(trees, K, X))
+    if name in HAND:
+        walk = predictor.PackedModel(trees, K).predict_margin(
+            X.astype(np.float64))
+        assert np.array_equal(kernel, walk)
+    else:
+        assert np.array_equal(kernel.astype(np.float32),
+                              _removed_xla_body(trees, K, X))

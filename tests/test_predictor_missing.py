@@ -7,6 +7,7 @@ walks the arrays the generator made, never the program's parse)."""
 
 import os
 import sys
+import types
 
 import numpy as np
 import pytest
@@ -66,8 +67,7 @@ def _device(monkeypatch, trees, X, body):
         out = predictor.predict_margin_device(trees, 1, X)
     counts = {}
     for r in profiler.spans()[n0:]:
-        for k, v in r["counts"].items():
-            counts[k] = counts.get(k, 0) + v
+        counts.update(r["counts"])
     return out[0].astype(np.float32), counts
 
 
@@ -81,11 +81,13 @@ def test_loaded_forest_is_bit_equal_to_the_reference(monkeypatch, body,
     got, counts = _device(monkeypatch, booster._gbdt.models, X, body)
     assert np.array_equal(got, ref)
     assert counts["fused"] == int(body == "pallas_interpreter")
-    assert counts["has_nan"] == 1 and counts["f_pad"] == 976
+    assert counts["has_nan"] == 1 and counts["f_pad"] == 992
     assert counts["m_pad"] == (32 if leaves == 15 else 64)
-    # x3 and nanf: 8 bytes a padded column, rows padded to the row tile
+    # a few thresholds a column: one int8 digit a code, so the codes are
+    # a byte a padded column, rows padded to the row tile
+    assert counts["code_terms"] == 1 and counts["thresholds_max"] <= 126
     n = counts["row_tile"]
-    assert counts["layout_bytes"] == -(-len(X) // n) * n * 976 * 8
+    assert counts["layout_bytes"] == -(-len(X) // n) * n * 992
     assert counts["table_bytes"] == predictor.build_device_tables(
         booster._gbdt.models, 1, F).nbytes
 
@@ -161,7 +163,7 @@ def test_forest_over_the_table_budget_is_counted(monkeypatch):
     big = np.concatenate([X] * 50)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(predictor, "device_tables_budget",
-                        lambda rows, cols: 1000)
+                        lambda rows, cols, layout_row_bytes: 1000)
     got = booster.predict(big, raw_score=True)
     raw = [r for r in profiler.spans() if r["name"] == "predict/raw"][-1]
     assert raw["counts"]["tables_over_budget"] == 1
@@ -188,8 +190,9 @@ def test_device_route_takes_float32_rows_as_they_are(monkeypatch):
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(predictor, "predict_margin_device", fake)
+    fits = types.SimpleNamespace(over_budget=lambda rows: False)
     monkeypatch.setattr(predictor, "build_device_tables",
-                        lambda trees, K, F: None)
+                        lambda trees, K, F, rows: fits)
     n0 = len(profiler.spans())
     booster.predict(X, raw_score=True)
     names = [r["name"] for r in profiler.spans()[n0:]]
@@ -204,30 +207,47 @@ def test_device_route_takes_float32_rows_as_they_are(monkeypatch):
                          "predict/host_walk"]
 
 
-@pytest.mark.parametrize("rows,cols,memory,budget", [
-    # a v5e's 16.9 GB beside the Bosch table and the Higgs table
-    (1_000_000, 968, 16_909_336_576, 16_909_336_576 - 12_848_000_000),
-    (10_500_000, 28, 16_909_336_576, 16_909_336_576 - 4_250_400_000),
+@pytest.mark.parametrize("rows,cols,layout_row_bytes,memory,budget", [
+    # a v5e's 16.9 GB beside the Bosch table and the Higgs table: X, its
+    # transposed copy and a byte of code a padded cell
+    (1_000_000, 968, 992 * 5, 16_909_336_576,
+     16_909_336_576 - 8_832_000_000),
+    (10_500_000, 28, 32 * 5, 16_909_336_576, 16_909_336_576 - 2_856_000_000),
+    # two digits a code (a 255-bin forest): the ranks are held as int32
+    (10_500_000, 28, 32 * 14, 16_909_336_576,
+     16_909_336_576 - 5_880_000_000),
     # rows that do not fit alone leave less than nothing
-    (2_000_000, 968, 16_909_336_576, 16_909_336_576 - 25_696_000_000),
+    (2_000_000, 968, 992 * 5, 16_909_336_576,
+     16_909_336_576 - 17_664_000_000),
     # a backend that states no memory: the old line
-    (1_000_000, 968, None, 300_000_000),
+    (1_000_000, 968, 992 * 5, None, 300_000_000),
 ])
 def test_table_budget_is_what_the_rows_leave(monkeypatch, rows, cols,
-                                             memory, budget):
+                                             layout_row_bytes, memory,
+                                             budget):
     monkeypatch.setattr(predictor, "_device_memory_bytes", lambda: memory)
-    assert predictor.device_tables_budget(rows, cols) == budget
+    assert predictor.device_tables_budget(rows, cols,
+                                          layout_row_bytes) == budget
 
 
 def test_the_source_settings_at_the_leaf_cap_pass_a_v5e_budget(monkeypatch):
-    """500 trees at the 255-leaf cap over 968 columns: 782 MB of tables,
-    over the old 300 MB line and under what a v5e has beside the rows."""
+    """500 trees at the 255-leaf cap over 968 columns: 163 MB of tables
+    with the int8 selector (782 MB as three bfloat16 terms a column),
+    under what a v5e has beside the rows; a device with no room for them
+    gets no upload."""
     forest, booster = _forest(255, trees=1)
-    one = predictor.device_tables_bytes(booster._gbdt.models, F)
-    assert one == 1_564_672 and 500 * one > 300_000_000
+    tables = predictor.build_device_tables(booster._gbdt.models, 1, F)
+    one = tables.nbytes - tables.tkeys.nbytes           # the per-tree part
+    assert one == 256 * (992 + 256 + 5 * 4) + 256 * 4
+    assert 150_000_000 < 500 * one < 170_000_000
     monkeypatch.setattr(predictor, "_device_memory_bytes",
                         lambda: 16_909_336_576)
-    assert 500 * one < predictor.device_tables_budget(1_000_000, F)
+    assert not tables.over_budget(1_000_000)
+    assert tables.layout_row_bytes == 992 * 5
+    assert 500 * one < predictor.device_tables_budget(1_000_000, F, 992 * 5)
+    assert tables.over_budget(2_000_000)
+    assert predictor.build_device_tables(booster._gbdt.models, 1, F,
+                                         rows=2_000_000) is None
 
 
 def test_load_span_and_new_counts_reach_get_profile(monkeypatch):
@@ -243,9 +263,16 @@ def test_load_span_and_new_counts_reach_get_profile(monkeypatch):
     assert load["parent"] is None
     assert load["counts"] == {"trees": 4, "bytes": len(
         forestgen.model_text(forest, F))}
-    assert {"has_nan", "f_pad", "m_pad", "table_bytes", "fused",
-            "row_tile"} <= set(by_name["predict/dispatch"]["counts"])
+    assert {"has_nan", "f_pad", "m_pad", "table_bytes", "fused", "row_tile",
+            "code_terms", "thresholds_max"} <= set(
+                by_name["predict/dispatch"]["counts"])
     assert by_name["predict/layout"]["counts"]["layout_bytes"] > 0
+    # what the tables are is said where they are built, too
+    built, used = (by_name[n]["counts"] for n in ("predict/tables",
+                                                  "predict/dispatch"))
+    for k in ("f_pad", "m_pad", "table_bytes", "code_terms",
+              "thresholds_max"):
+        assert built[k] == used[k]
     # one span a load, none a tree
     assert sum(r["name"].startswith("booster/load") for r in recs) == 1
 

@@ -299,11 +299,12 @@ def test_device_stages_carry_scope_names(monkeypatch, table, booster):
     from lightgbm_tpu.models import predictor
     X, _ = table
     tabs = predictor.build_device_tables(booster._gbdt.models, 1, 6)
-    x3 = jnp.zeros((tabs.ohf.shape[2], 8192), jnp.bfloat16)
+    codes = jnp.zeros((tabs.terms * tabs.F_pad, 8192), jnp.int8)
     for fused in (False, True):
         text = predictor._get_device_margin().lower(
-            x3, None, *tabs.arrays, K=1, has_zero=tabs.has_zero, n=4096,
-            fused=fused, interpret=fused).as_text(debug_info=True)
+            codes, *tabs.arrays, K=1, has_nan=tabs.has_nan,
+            has_zero=tabs.has_zero, n=4096, fused=fused,
+            interpret=fused).as_text(debug_info=True)
         for stage in ("feature_select", "path_match", "leaf_sum"):
             assert "predict/" + stage in text, (fused, stage)
     monkeypatch.setenv("LIGHTGBM_TPU_DISABLE_BATCHED", "0")
@@ -554,7 +555,7 @@ def test_every_pallas_site_carries_an_explicit_distinct_name():
     assert all(by_case.values()), by_case
     names = {n for ns in by_case.values() for n in ns}
     assert all(n.startswith("lgbm_") for n in names), names
-    variant = re.compile(r"(_(k|b|lo|t|l|f|m|n|w)\d+|_q|_nan|_zero)*$")
+    variant = re.compile(r"(_(k|b|lo|t|l|f|m|n|w|c)\d+|_q|_nan|_zero)*$")
     families = {variant.sub("", n) for n in names}
     assert families == {
         "lgbm_hist_slots", "lgbm_take_leaf_values", "lgbm_wave_pass",
@@ -566,7 +567,8 @@ def test_every_pallas_site_carries_an_explicit_distinct_name():
         "megakernel B64 f32", "megakernel B64 int8",
         "megakernel hi/lo B256 f32", "slots legacy F28 B256 f32",
         "slots hi/lo F28 B256 f32", "predict_forest L255 F28",
-        "predict_forest L15 F130 K3 nan", "predict_forest L31 F28 cat")]
+        "predict_forest L15 F130 K3 nan", "predict_forest L31 F28 cat",
+        "predict_forest L255 F4 nan c2")]
     assert len(set(variants)) == len(variants), variants
     from lightgbm_tpu.ops.histogram_pallas import wave_pass_pallas
     make, _ = kernel_check._mega(64, False, 128)
