@@ -126,9 +126,6 @@ def main() -> None:
     # BENCH_COMM=1: histogram-exchange collective bench, allreduce vs
     # reduce_scatter vs packed (scripts/bench_comm.py, docs/PERF.md
     # section 5); writes BENCH_COMM.json
-    # BENCH_FUSED=1: fused wave megakernel vs two-pass + 4-bit packed
-    # layout sweep (scripts/bench_fused.py, docs/PERF.md section 6);
-    # writes BENCH_FUSED.json
     # BENCH_RESIL=1: checkpointing overhead vs a plain update loop
     # (scripts/bench_resilience.py, docs/ROBUSTNESS.md); writes
     # BENCH_RESIL.json
@@ -152,7 +149,6 @@ def main() -> None:
     for env, script in (("BENCH_SERVING", "bench_serving.py"),
                         ("BENCH_ROWWISE", "bench_rowwise.py"),
                         ("BENCH_COMM", "bench_comm.py"),
-                        ("BENCH_FUSED", "bench_fused.py"),
                         ("BENCH_RESIL", "bench_resilience.py"),
                         ("BENCH_SLO", "bench_slo.py"),
                         ("BENCH_ONLINE", "bench_online.py"),

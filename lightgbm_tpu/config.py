@@ -15,6 +15,11 @@ from typing import Any, Dict, List, Optional, Union
 
 from .utils.log import log_fatal, log_warning
 
+# the values of `histogram_impl` (described at the field below); also
+# what a checkpoint's pinned value is held to (runtime/checkpoint.py)
+HISTOGRAM_IMPLS = ("auto", "legacy", "tiered", "tiered_hilo", "rowwise",
+                   "rowwise_packed")
+
 # ---------------------------------------------------------------------------
 # Alias table: alias -> canonical name. Mirrors the semantics of the
 # reference's Config::alias_table (src/io/config_auto.cpp) — many aliases per
@@ -89,8 +94,6 @@ _alias("bin_construct_sample_cnt", "bin_construct_sample_cnt",
 _alias("data_random_seed", "data_seed")
 _alias("histogram_impl", "hist_impl", "tpu_histogram_impl")
 _alias("binning_impl", "bin_impl", "tpu_binning_impl")
-_alias("fused_feature_tile", "fused_tile", "grow_fused_feature_tile")
-_alias("fused_relabel_fusion", "fused_wave_fusion", "relabel_fusion")
 _alias("parallel_hist_mode", "hist_comm_mode", "parallel_histogram_mode")
 _alias("is_enable_sparse", "is_sparse", "enable_sparse", "sparse")
 _alias("enable_bundle", "is_enable_bundle", "bundle")
@@ -502,11 +505,6 @@ class Config:
     #   rowwise_packed  rowwise + 4-bit storage pack: two <=16-bin
     #               storage columns per byte, nibble-unpacked in-kernel
     #               (halves the binned-operand stream; same flat buffer)
-    #   fused       wave megakernel with the split scan fused into the
-    #               histogram epilogue — per-leaf histograms stay VMEM-
-    #               resident, no HBM round-trip before the best-split
-    #               search (ops/grow_fused.py; wave grower only — plain
-    #               histogram builds treat it as "auto")
     # force_row_wise/force_col_wise (the reference's knobs) map onto this:
     # force_row_wise pins rowwise, force_col_wise restricts autotune to
     # the col-wise candidates; setting both is an error.
@@ -525,22 +523,6 @@ class Config:
     # launch). LIGHTGBM_TPU_DISABLE_DEVICE_BINNING=1 vetoes the device
     # path everywhere without a config edit.
     binning_impl: str = "auto"
-
-    # -- fused wave-grower geometry (ops/grow_fused.py; docs/PERF.md §6).
-    # fused_feature_tile: lane width of one feature tile in the tiled
-    # megakernel — the grid dimension that lifted the old F<=32 gate.
-    # Each tile holds a (2*tile, num_bins) VMEM accumulator per leaf, so
-    # larger tiles trade leaf capacity (kcap) for fewer grid steps.
-    # fused_relabel_fusion: fold the RELABEL pass of applies-only waves
-    # into the next wave's SPECULATE launch (tiled path only), roughly
-    # halving Pallas launches per tree. Both knobs are orchestration
-    # only — the fused scan is bitwise-identical to the two-pass wave
-    # (tests/test_grow_fused.py), so they never perturb model files.
-    # LIGHTGBM_TPU_DISABLE_FUSED=1 in the environment vetoes the fused
-    # path entirely and makes both knobs inert (the veto is recorded in
-    # device_profile extras as fused_veto_reasons).
-    fused_feature_tile: int = 32
-    fused_relabel_fusion: bool = True
 
     # -- data-parallel histogram exchange (docs/PERF.md §Communication;
     # reference: data_parallel_tree_learner.cpp ReduceScatter +
@@ -617,13 +599,11 @@ class Config:
                 f"'{self.monotone_constraints_method}' (supported: "
                 "'basic', 'intermediate'; the reference's 'advanced' "
                 "method is not implemented — see docs/PARITY.md)")
-        if self.histogram_impl not in ("auto", "legacy", "tiered",
-                                       "tiered_hilo", "rowwise",
-                                       "rowwise_packed", "fused"):
+        if self.histogram_impl not in HISTOGRAM_IMPLS:
             log_fatal(
                 f"Unknown histogram_impl '{self.histogram_impl}' "
-                "(supported: 'auto', 'legacy', 'tiered', 'tiered_hilo', "
-                "'rowwise', 'rowwise_packed', 'fused'; see docs/PERF.md)")
+                f"(supported: {', '.join(map(repr, HISTOGRAM_IMPLS))}; "
+                "see docs/PERF.md)")
         if self.binning_impl not in ("auto", "host", "device"):
             log_fatal(
                 f"Unknown binning_impl '{self.binning_impl}' "
@@ -644,22 +624,6 @@ class Config:
                 "rowwise", "rowwise_packed"):
             log_fatal("force_col_wise conflicts with histogram_impl="
                       f"'{self.histogram_impl}'; drop one")
-        if self.fused_feature_tile not in (32, 64, 128):
-            log_fatal(
-                f"fused_feature_tile={self.fused_feature_tile} is not a "
-                "supported tile width (choose 32, 64 or 128: one VMEM "
-                "feature tile per grid step — docs/PERF.md §6)")
-        # customizing the fused geometry while pinning a non-fused
-        # histogram layout is the same contradiction class as
-        # force_row_wise + a col-wise impl: the knobs would silently do
-        # nothing (config.cpp CheckParamConflict analog)
-        if ((self.fused_feature_tile != 32
-             or not self.fused_relabel_fusion)
-                and self.histogram_impl not in ("auto", "fused")):
-            log_fatal(
-                "fused_feature_tile/fused_relabel_fusion conflict with "
-                f"histogram_impl='{self.histogram_impl}' (the fused wave "
-                "kernel is never taken under that pin); drop one")
         if self.parallel_hist_mode not in ("auto", "allreduce",
                                            "reduce_scatter"):
             log_fatal(
@@ -800,11 +764,6 @@ class Config:
         # chunked scans are md5-identical to the per-iteration loop
         # (tests/test_batched.py), so they must not perturb model files
         "batched_train", "batched_chunk_size",
-        # fused wave-grower geometry: tile width and relabel fusion are
-        # launch-scheduling choices with a bitwise-parity contract vs the
-        # two-pass wave (tests/test_grow_fused.py), so they must not
-        # perturb model files either
-        "fused_feature_tile", "fused_relabel_fusion",
         # binning_impl picks WHERE the value->bin push runs; the device
         # bucketize is bit-identical to the host searchsorted
         # (tests/test_predict_binned.py parity suites), so it must not

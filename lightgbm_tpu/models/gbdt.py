@@ -372,8 +372,6 @@ class GBDT:
             hist_tiers=hist_tiers,
             hist_impl=hist_impl_cfg,
             parallel_hist_mode=str(cfg.parallel_hist_mode),
-            fused_feature_tile=int(cfg.fused_feature_tile),
-            fused_relabel_fusion=bool(cfg.fused_relabel_fusion),
         )
 
         # grower selection: "wave" (default via auto) applies batched
@@ -646,21 +644,6 @@ class GBDT:
                 if self.profiler is not None:
                     self.profiler.extras["autotune"] = decision
 
-        # fused-path eligibility record (docs/PERF.md §6): fused
-        # eligibility used to be a silent fall-off, so every train writes
-        # the veto list (empty = a fused kernel runs) and, when eligible,
-        # the geometry the grower will launch with, into device_profile
-        # extras. The span probe times the actual wave kernels once.
-        if self.profiler is not None and self.grower == "wave":
-            from ..ops.grow_wave import fused_veto_reasons
-            from ..ops.histogram import _use_pallas
-            vetoes = fused_veto_reasons(
-                self.grow_cfg, self.meta, self.use_dist,
-                _use_pallas(self.X_t, self.num_bins_padded))
-            self.profiler.extras["fused_veto_reasons"] = list(vetoes)
-            if not vetoes:
-                self._profile_fused_wave()
-
         if self.profiler is not None and self.grow_cfg.hist_tiers:
             self._profile_hist_tiers()
 
@@ -673,35 +656,6 @@ class GBDT:
             self.profiler.extras["comm"] = dict(self._comm_profile)
 
         self._build_jit_fns()
-
-    def _profile_fused_wave(self) -> None:
-        """Record the fused-wave launch geometry and one fenced span of
-        the kernels the wave grower will actually dispatch (narrow
-        megakernel at F == 32, the feature-tiled one otherwise), so
-        device_profile output carries a per-wave fused-launch cost next
-        to the hist_class_b{lane} spans. The grower itself is one fused
-        jit — per-wave spans inside it are unobservable from the host —
-        so this is the same micro-probe pattern as _profile_hist_tiers."""
-        from ..runtime.autotune import probe_fused_wave
-        cfg = self.grow_cfg
-        F = int(self.X_t.shape[0])
-        narrow = (F == 32 and not cfg.has_categorical
-                  and not cfg.use_quantized_grad
-                  and self.meta.monotone is None
-                  and self.meta.inter_sets is None)
-        tile = int(cfg.fused_feature_tile)
-        self.profiler.extras["fused"] = {
-            "path": "fused" if narrow else "fused_tiled",
-            "feature_tile": tile,
-            "feature_tiles": 1 if narrow else -(-F // tile),
-            "relabel_fusion": bool(cfg.fused_relabel_fusion
-                                   and not narrow)}
-        if self.use_dist:
-            return
-        with self._prof_span("fused_wave_probe"):
-            times = probe_fused_wave(self.X_t, cfg, seed=0)
-        self.profiler.extras["fused"]["probe_s"] = {
-            k: round(float(v), 6) for k, v in times.items()}
 
     def _profile_hist_tiers(self) -> None:
         """Record the dataset's width-class structure and one stage span

@@ -158,17 +158,6 @@ class GrowConfig(NamedTuple):
     # (runtime/autotune.py).
     parallel_hist_mode: str = "auto"
 
-    # fused wave megakernel shape knobs (ops/grow_fused.py).
-    # fused_feature_tile: features per grid tile of the feature-tiled
-    # fused kernel (F > 32 regimes grid over ceil(F / tile) tiles with a
-    # cross-tile argmax merge in the epilogue); must be one of 32/64/128
-    # (int8 sublane multiples). fused_relabel_fusion folds the relabel
-    # pass of an applies-only wave into the NEXT wave's launch prologue
-    # (one fewer Pallas launch and one fewer [N] row-map round-trip per
-    # folded wave).
-    fused_feature_tile: int = 32
-    fused_relabel_fusion: bool = True
-
     @property
     def bundled(self) -> bool:
         return len(self.bundle_col) > 0

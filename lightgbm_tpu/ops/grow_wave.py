@@ -93,52 +93,6 @@ def _wave_buckets(L: int, kcap: int = 128) -> list[int]:
     return [k for k in ladder if k < kmax] + [kmax]
 
 
-def fused_veto_reasons(cfg: GrowConfig, meta, distributed: bool,
-                       pallas_ok: bool) -> list[str]:
-    """Why the fused megakernel family (ops/grow_fused.py) cannot run at
-    all for this training config — empty list means SOME fused kernel is
-    eligible and grow_tree_wave picks the narrow vs the feature-tiled
-    one. Pure Python over static config/meta structure, so it is callable
-    both at trace time here and from GBDT for the training-profile
-    `fused_veto_reasons` extras entry (observability: fused eligibility
-    used to be a silent fallback).
-
-    The listed regimes all have SEARCH-side state the in-kernel scan does
-    not carry (dynamic per-feature penalties/thresholds, cross-shard
-    merges, the monotone-intermediate stale re-search machinery) — wide
-    F, quantized gradients, monotone `basic`, interaction sets and
-    categorical features are NOT vetoed: the tiled kernel covers them."""
-    import os
-    reasons = []
-    if cfg.hist_impl != "fused":
-        reasons.append("histogram_impl=%s (not 'fused')" % cfg.hist_impl)
-    if not pallas_ok:
-        reasons.append("no_tpu_pallas")
-    if os.environ.get("LIGHTGBM_TPU_DISABLE_FUSED", "").lower() \
-            in ("1", "true", "yes"):
-        reasons.append("LIGHTGBM_TPU_DISABLE_FUSED")
-    if cfg.bundled:
-        reasons.append("efb_bundled")
-    if distributed:
-        reasons.append("distributed")
-    if cfg.feature_parallel:
-        reasons.append("feature_parallel")
-    if meta.forced is not None:
-        reasons.append("forced_splits")
-    if cfg.cegb_penalty_split > 0.0 or meta.cegb_coupled is not None:
-        reasons.append("cegb")
-    if cfg.feature_fraction_bynode < 1.0:
-        reasons.append("feature_fraction_bynode")
-    if cfg.extra_trees:
-        reasons.append("extra_trees")
-    if meta.monotone is not None:
-        if cfg.monotone_method == "intermediate":
-            reasons.append("monotone_intermediate")
-        if cfg.monotone_penalty > 0.0:
-            reasons.append("monotone_penalty")
-    return reasons
-
-
 def _oh_dot(oh: jnp.ndarray, flat: jnp.ndarray) -> jnp.ndarray:
     """[K, L] one-hot (f32) times [L, D] values; exact for f32 tables and
     for int32 tables (via two 16-bit planes). Precision.HIGHEST is
@@ -224,18 +178,6 @@ class _WaveState(NamedTuple):
     stale: jnp.ndarray             # [L] bool: bounds moved since the
     #   leaf's own best was searched (needs an own re-search before it
     #   may speculate children again)
-    # -- relabel-fusion carry (tiled fused path only): an applies-only
-    # wave defers its row relabel into the NEXT wave's megakernel launch
-    # (pending pass before the current apply). Flushed in XLA when the
-    # next wave is also applies-only or at the end of the wave loop.
-    pend_leaf: jnp.ndarray         # [KMAX] i32 parent leaf ids (-1 pad)
-    pend_feat: jnp.ndarray         # [KMAX] i32 split feature
-    pend_thr: jnp.ndarray          # [KMAX] i32 split threshold
-    pend_dl: jnp.ndarray           # [KMAX] bool default_left
-    pend_iscat: jnp.ndarray        # [KMAX] bool categorical split
-    pend_bits: jnp.ndarray         # [KMAX, W] u32 categorical bitsets
-    pend_nl0: jnp.ndarray          # [] i32 first new-leaf id of that wave
-    pend_n: jnp.ndarray            # [] i32 number of pending applies
 
 
 class _SimState(NamedTuple):
@@ -278,62 +220,17 @@ def grow_tree_wave(
     max_depth = cfg.max_depth if cfg.max_depth > 0 else 10**9
     quant = cfg.use_quantized_grad
 
-    # fused wave megakernel availability (TPU, dense int8 storage, no
+    # wave megakernel availability (TPU, dense int8 storage, no
     # categorical, narrow enough to hold all features in one kernel block)
     from .histogram import _use_pallas
-    # hist_impl="rowwise" (config pin or autotune) takes the unfused path
+    # hist_impl="rowwise" (config pin or autotune) takes the two-pass path
     # so its waves actually run the row-wise multi-value kernel — the
-    # megakernel's fused histogram is col-wise only
+    # megakernel's histogram is col-wise only
     use_mega = (_use_pallas(X_t, B) and not cfg.bundled
                 and not cfg.has_categorical and X_t.shape[0] <= 32
                 and not cfg.feature_parallel
                 and cfg.hist_impl not in ("rowwise", "rowwise_packed"))
-    # single-pass fused histogram + split-scan megakernels
-    # (grow_fused.py): selected via histogram_impl="fused" (pin or
-    # autotune win). fused_veto_reasons lists the regimes NO fused kernel
-    # covers; within the eligible set the NARROW kernel keeps the
-    # original fast path (in-kernel go_left: F <= 32, float,
-    # unconstrained, no categorical) and the feature-TILED kernel takes
-    # everything else — wide F, quantized gradients, monotone `basic`,
-    # interaction sets, categorical — with membership bits precomputed in
-    # XLA (the wave_apply dec layout).
-    _vetoes = fused_veto_reasons(cfg, meta, dist is not None,
-                                 _use_pallas(X_t, B))
-    from .histogram import pallas_interpret
-    if not _vetoes and not pallas_interpret():
-        # selected on a chip: fail here, not in an autotune probe. The
-        # in-kernel scan traces ops/split.py's search (cumsum, 3-D
-        # reshapes, flat argmax) and reads K-row parent blocks, none of
-        # which Mosaic lowers (CHANGES.md, PR 22)
-        raise NotImplementedError(
-            "histogram_impl=fused does not compile for the TPU: the fused "
-            "megakernels (ops/grow_fused.py) run under "
-            "LIGHTGBM_TPU_PALLAS_INTERPRET only; use histogram_impl=auto")
-    # (the narrow kernel sizes its parent slab from the 32-row padded
-    # block, so it is exact only at 32 storage columns; narrower data
-    # rides the tiled kernel as one partial tile)
-    use_fused = (use_mega and not _vetoes
-                 and not quant and X_t.shape[0] == 32
-                 and meta.monotone is None and meta.inter_sets is None
-                 and not cfg.has_categorical)
-    use_fused_tiled = not _vetoes and not use_fused
-    # the tiled kernel supersedes the unfused megakernel wherever it is
-    # eligible (histogram_impl="fused" routed here on purpose)
-    use_mega = use_mega and not use_fused_tiled
-    if use_fused_tiled:
-        # per-tile VMEM: the [HB*C*K, tile*LO] accumulator block plus the
-        # tile's [K, C*tile*B] parent-histogram slab (same magnitude), so
-        # the narrow kernel's budget math holds with 32 -> tile and the
-        # same fused halving.
-        from .histogram_pallas import _compute_dims
-        B_lane = _compute_dims(B)[0]
-        tile_f = int(cfg.fused_feature_tile)
-        C_stat = 2
-        kcap = 3_400_000 // (C_stat * tile_f * B_lane * 4) // 2
-        kcap = max(1 << (kcap.bit_length() - 1), 1) if kcap >= 1 else 1
-        buckets = _wave_buckets(L, min(kcap, 128))
-        mega_wide_lo = 64 if B_lane > 128 else 128
-    elif use_mega:
+    if use_mega:
         # the megakernel's [HB*C*K, 32*LO] f32 output block lives in VMEM
         # for the whole grid; bound K so it stays within scoped VMEM.
         # The kernel pads the bin axis to the lane-friendly width, so the
@@ -342,11 +239,6 @@ def grow_tree_wave(
         B_lane = _compute_dims(B)[0]
         C_stat = 2          # (grad, hess) in both float and quantized mode
         kcap = 3_400_000 // (C_stat * 32 * B_lane * 4)
-        if use_fused:
-            # the fused kernel additionally holds the [K, C*F*B] parent
-            # histogram operand VMEM-resident for the final-step scan —
-            # same magnitude as the output block, so halve the K cap
-            kcap = kcap // 2
         kcap = max(1 << (kcap.bit_length() - 1), 1) if kcap >= 1 else 1
         buckets = _wave_buckets(L, min(kcap, 128))
         # wide-bin megakernel waves run the hi/lo one-hot decomposition
@@ -354,7 +246,7 @@ def grow_tree_wave(
         # config/autotune pinned the legacy split. VMEM budget is
         # unchanged: HB*LO = B_lane for either choice, so kcap holds.
         mega_wide_lo = 64 if (B_lane > 128 and cfg.hist_impl
-                              in ("auto", "tiered_hilo", "fused")) else 128
+                              in ("auto", "tiered_hilo")) else 128
     else:
         buckets = _wave_buckets(L)
         mega_wide_lo = 128
@@ -896,19 +788,11 @@ def grow_tree_wave(
         bfr=jnp.zeros((L,), bool),
         under=jnp.zeros((L, M), jnp.int8),
         stale=jnp.zeros((L,), bool),
-        pend_leaf=jnp.full((KMAX,), -1, jnp.int32),
-        pend_feat=jnp.zeros((KMAX,), jnp.int32),
-        pend_thr=jnp.zeros((KMAX,), jnp.int32),
-        pend_dl=jnp.zeros((KMAX,), bool),
-        pend_iscat=jnp.zeros((KMAX,), bool),
-        pend_bits=jnp.zeros((KMAX, W), jnp.uint32),
-        pend_nl0=jnp.asarray(0, jnp.int32),
-        pend_n=jnp.asarray(0, jnp.int32),
     )
 
     # wide/categorical/EFB TPU wave path (no feature-count cliff): used
-    # when neither fused megakernel can (see use_apply sites)
-    use_apply = _use_pallas(X_t, B) and not use_mega and not use_fused_tiled
+    # when the wave megakernel cannot (see use_apply sites)
+    use_apply = _use_pallas(X_t, B) and not use_mega
 
     def dec_go_left(tbl_leaf, feat, thr, dl, iscat, bits):
         """[K, N] go-left decision of EVERY row under each table entry's
@@ -1069,97 +953,6 @@ def grow_tree_wave(
 
         mega_branches = [relabel_only_branch] \
             + [make_mega_branch(K) for K in buckets]
-
-    if use_fused:
-        from .grow_fused import (REC_ROWS, pack_fused_meta,
-                                 rec_width, wave_pass_fused_pallas)
-        RECW = rec_width(KMAX)
-        meta_ops_f = pack_fused_meta(meta.num_bins, meta.missing_type,
-                                     meta.default_bin, meta.is_categorical,
-                                     feature_mask)
-
-        def make_fused_branch(K):
-            def branch(args):
-                lor, tbl16, scal, parent_flat = args
-                new_lor, hist, rec = wave_pass_fused_pallas(
-                    X_mega, vals_mega, lor, tbl16, parent_flat, scal,
-                    meta_ops_f, K, B, KMAX, hp, interpret=_interp_m,
-                    wide_lo=mega_wide_lo)
-                if K < KMAX:
-                    hist = jnp.pad(
-                        hist, ((0, KMAX - K), (0, 0), (0, 0), (0, 0)))
-                return new_lor, hist, rec
-            return branch
-
-        def fused_relabel_branch(args):
-            lor, tbl16, scal, parent_flat = args
-            new_lor = wave_relabel_pallas(X_mega, vals_mega, lor, tbl16, B,
-                                          interpret=_interp_m)
-            return (new_lor, jnp.zeros((KMAX, C, F0, B), hist_dtype),
-                    jnp.zeros((REC_ROWS, RECW), jnp.float32))
-
-        fused_branches = [fused_relabel_branch] \
-            + [make_fused_branch(K) for K in buckets]
-
-    # ---- feature-TILED fused wave megakernel: the grid walks feature
-    # tiles so F is unbounded, and the apply/membership decision bits are
-    # precomputed in XLA (wave_apply layout), which frees the kernel from
-    # the narrow path's in-kernel go_left — quantized gradients, monotone
-    # `basic` bounds, interaction-set masks and categorical candidates
-    # all ride through (grow_fused.py docstring). Per-tile [REC_ROWS,
-    # RECW] records are merged on the raw argmax key in the epilogue.
-    if use_fused_tiled:
-        from .histogram_pallas import N_BLK, wave_apply_pallas
-        from .grow_fused import (REC_ROWS, pack_fused_fmask_tiled,
-                                 pack_fused_meta_tiled, pack_fused_scalars,
-                                 rec_width, wave_pass_fused_tiled_pallas)
-        from .histogram import pallas_interpret
-        from ..utils import round_up
-        F0 = X_t.shape[0]
-        n_blk = N_BLK if N >= N_BLK else max(round_up(N, 256), 256)
-        Np = round_up(N, n_blk)
-        # pad/convert once per tree; every wave kernel reuses these
-        X_tiled = jnp.pad(X_t.astype(jnp.int8),
-                          ((0, -F0 % tile_f), (0, Np - N)))
-        vals_tiled = jnp.pad(vals0, ((0, 0), (0, Np - N)))
-        hist_dtype = jnp.int32 if quant else jnp.float32
-        RECW_t = rec_width(KMAX)
-        meta_tiles = pack_fused_meta_tiled(
-            meta.num_bins, meta.missing_type, meta.default_bin,
-            meta.is_categorical, meta.monotone, tile_f)
-        _interp = pallas_interpret()
-
-        def make_tiled_branch(K):
-            def branch(args):
-                (lor, dec, tbl16, pendl, pnl0, scal, parent_flat,
-                 fm_tiles) = args
-                new_lor, hist, rec = wave_pass_fused_tiled_pallas(
-                    X_tiled, vals_tiled, dec, lor, tbl16, pendl, pnl0,
-                    parent_flat, scal, meta_tiles, fm_tiles, F, K, B,
-                    KMAX, hp, tile=tile_f, interpret=_interp,
-                    wide_lo=mega_wide_lo)
-                if K < KMAX:
-                    hist = jnp.pad(
-                        hist, ((0, KMAX - K), (0, 0), (0, 0), (0, 0)))
-                return new_lor, hist, rec
-            return branch
-
-        def tiled_relabel_branch(args):
-            (lor, dec, tbl16, pendl, pnl0, scal, parent_flat,
-             fm_tiles) = args
-            zero_hist = jnp.zeros((KMAX, C, F0, B), hist_dtype)
-            zero_rec = jnp.zeros((REC_ROWS, RECW_t), jnp.float32)
-            if cfg.fused_relabel_fusion:
-                # applies-only wave: DEFER the relabel — it becomes the
-                # pending pass of the next wave's megakernel launch (or
-                # the XLA flush when no kernel wave follows)
-                return lor, zero_hist, zero_rec
-            new_lor, _ = wave_apply_pallas(dec, lor, tbl16,
-                                           interpret=_interp)
-            return new_lor, zero_hist, zero_rec
-
-        tiled_branches = [tiled_relabel_branch] \
-            + [make_tiled_branch(K) for K in buckets]
 
     # ---- serial ORDER simulation: each step touches only [L]-sized gain/
     # ready arrays (~10 tiny ops), so the 254-step sequential chain costs
@@ -1490,7 +1283,7 @@ def grow_tree_wave(
         smaller_is_left = bs.left_count <= bs.right_count    # [K]
 
         if use_mega:
-            # ---- fused megakernel: relabel + candidate membership + slot
+            # ---- wave megakernel: relabel + candidate membership + slot
             # histogram in one device pass
             def gmeta(a, feat):
                 return jnp.take(a, feat, mode="clip").astype(jnp.int32)
@@ -1527,162 +1320,10 @@ def grow_tree_wave(
                     jnp.searchsorted(bucket_bounds, n_cand)
                     .astype(jnp.int32), len(buckets) - 1),
                 0)
-            if use_fused:
-                # hoist the per-child parent scalars and the candidate
-                # parent-histogram gather ahead of the kernel: the fused
-                # scan consumes them in VMEM/SMEM on the final grid step.
-                # Record columns of invalid candidates are discarded by
-                # scat's validity mask, so `bs` garbage on padded entries
-                # is harmless — same contract as the vmapped search.
-                from .grow_fused import pack_fused_scalars
-                scal_f = pack_fused_scalars(bs, smaller_is_left, KMAX)
-                parent_flat = jax.lax.cond(
-                    n_cand > 0,
-                    lambda: _onehot_gather(
-                        st.hist_cache, jnp.where(valid, cand, L)),
-                    lambda: jnp.zeros((KMAX, st.hist_cache.shape[1]),
-                                      st.hist_cache.dtype))
-                with jax.named_scope("train/wave_pass"):
-                    leaf_of_row, hist_wave, rec_wave = jax.lax.switch(
-                        kidx_m, fused_branches,
-                        (st.leaf_of_row, tbl16, scal_f, parent_flat))
-            else:
-                with jax.named_scope("train/wave_pass"):
-                    leaf_of_row, hist_wave = jax.lax.switch(
-                        kidx_m, mega_branches, (st.leaf_of_row, tbl16))
-            st = st._replace(leaf_of_row=leaf_of_row)
-            slot_small = None
-        elif use_fused_tiled:
-            # ---- feature-TILED fused megakernel: per-(entry, row)
-            # go-left bits are precomputed in XLA exactly as on the wide
-            # apply path (bundle-free here; categorical bitsets and
-            # missing handling included), then ONE kernel resolves
-            # membership, accumulates the slot histogram tile by tile and
-            # scans every candidate child's best split in its epilogue.
-            glA = dec_go_left(app_leaf, bs2.feature, bs2.threshold,
-                              bs2.default_left, iscat2, bits2)
-            glC = dec_go_left(cand_tbl, bs.feature, bs.threshold,
-                              bs.default_left, st.best_is_cat[cand],
-                              st.best_bitset[cand])
-            land_small = glC == smaller_is_left[:, None]
-            dec = (glA.astype(jnp.int8)
-                   | (land_small.astype(jnp.int8) << 1))     # [KMAX, N]
-            if cfg.fused_relabel_fusion:
-                # bit2: go-left of the PREVIOUS wave's deferred applies.
-                # Computed only when a pend is live (lax.cond executes
-                # one branch, so the [K, N] pass is usually free).
-                dec = dec | jax.lax.cond(
-                    st.pend_n > 0,
-                    lambda: dec_go_left(
-                        st.pend_leaf, st.pend_feat, st.pend_thr,
-                        st.pend_dl, st.pend_iscat, st.pend_bits
-                    ).astype(jnp.int8) << 2,
-                    lambda: jnp.zeros((KMAX, N), jnp.int8))
-            pad128 = (0, 128 - KMAX)
-            if KMAX < 128:
-                dec = jnp.pad(dec, (pad128, (0, 0)))
-            tbl16 = jnp.zeros((16, 128), jnp.int32)
-            tbl16 = tbl16.at[0].set(
-                jnp.pad(app_leaf, pad128, constant_values=-1))
-            tbl16 = tbl16.at[7].set(
-                jnp.pad(cand_tbl, pad128, constant_values=-1))
-            tbl16 = tbl16.at[15].set(jnp.full((128,), nl0))
-            if cfg.fused_relabel_fusion:
-                pendl = jnp.pad(st.pend_leaf, pad128,
-                                constant_values=-1)
-                pnl0 = st.pend_nl0
-            else:
-                pendl = jnp.full((128,), -1, jnp.int32)
-                pnl0 = jnp.asarray(0, jnp.int32)
-            # per-child parent scalars, monotone-`basic` bounds (±inf
-            # when unconstrained — bitwise no-op in the kernel's clip)
-            # and quantized descale factors ride in SMEM
-            if has_mono:
-                tlmin, tlmax, trmin, trmax = child_bounds(
-                    bs, st.leaf_min[cand], st.leaf_max[cand])
-                bmin_t = jnp.concatenate([tlmin, trmin])
-                bmax_t = jnp.concatenate([tlmax, trmax])
-            else:
-                bmin_t = bmax_t = None
-            from .grow_fused import pack_fused_scalars
-            scal_f = pack_fused_scalars(
-                bs, smaller_is_left, KMAX,
-                leaf_min_lr=bmin_t, leaf_max_lr=bmax_t,
-                grad_scale=g_scale if quant else None,
-                hess_scale=h_scale if quant else None)
-            # per-child feature masks: interaction-set projection (same
-            # reduction as sets_to_fmask, batched) intersected with the
-            # global column-sampling mask; all-true when unmasked
-            if has_inter:
-                csets_t = child_sets(bs, st.leaf_sets[cand])  # [K, S]
-                allow_t = jnp.any(
-                    meta.inter_sets[None, :, :] & csets_t[:, :, None],
-                    axis=1)                                   # [K, F]
-                if feature_mask is not None:
-                    allow_t = allow_t & feature_mask[None, :]
-                fm_children = jnp.concatenate([allow_t, allow_t])
-            elif feature_mask is not None:
-                fm_children = jnp.broadcast_to(feature_mask[None, :],
-                                               (2 * KMAX, F))
-            else:
-                fm_children = jnp.ones((2 * KMAX, F), bool)
-            fm_tiles = pack_fused_fmask_tiled(fm_children, tile_f, KMAX)
-            parent_flat = jax.lax.cond(
-                n_cand > 0,
-                lambda: _onehot_gather(
-                    st.hist_cache, jnp.where(valid, cand, L)),
-                lambda: jnp.zeros((KMAX, st.hist_cache.shape[1]),
-                                  st.hist_cache.dtype))
-            kidx_t = jnp.where(
-                n_cand > 0,
-                1 + jnp.minimum(
-                    jnp.searchsorted(bucket_bounds, n_cand)
-                    .astype(jnp.int32), len(buckets) - 1),
-                0)
-            if cfg.fused_relabel_fusion:
-                # two consecutive applies-only waves would overwrite the
-                # pend and lose the first relabel: flush the OLD pend in
-                # XLA first (rare — branch 0 twice in a row)
-                def _flush_pend(lor):
-                    glp = dec_go_left(
-                        st.pend_leaf, st.pend_feat, st.pend_thr,
-                        st.pend_dl, st.pend_iscat, st.pend_bits)
-                    mP = lor[None, :] == st.pend_leaf[:, None]
-                    slp = jnp.sum(jnp.where(mP, j_iota[:, None], 0),
-                                  axis=0)
-                    glr = jnp.sum(
-                        jnp.where(mP, glp.astype(jnp.int32), 0), axis=0)
-                    hit = jnp.any(mP, axis=0)
-                    return jnp.where(hit & (glr == 0),
-                                     st.pend_nl0 + slp, lor)
-                lor_in = jax.lax.cond(
-                    (kidx_t == 0) & (st.pend_n > 0),
-                    _flush_pend, lambda lor: lor, st.leaf_of_row)
-            else:
-                lor_in = st.leaf_of_row
             with jax.named_scope("train/wave_pass"):
-                leaf_of_row, hist_wave, rec_wave = jax.lax.switch(
-                    kidx_t, tiled_branches,
-                    (lor_in, dec, tbl16, pendl, pnl0, scal_f, parent_flat,
-                     fm_tiles))
-            # applies-only wave with fusion on: the relabel was DEFERRED
-            # (branch 0 returned lor unchanged) — record it so the next
-            # wave's kernel runs it as its pending pass
-            defer = jnp.bool_(cfg.fused_relabel_fusion) & (kidx_t == 0)
-            st = st._replace(
-                leaf_of_row=leaf_of_row,
-                pend_leaf=jnp.where(defer, app_leaf, -1),
-                pend_feat=jnp.where(defer, bs2.feature.astype(jnp.int32),
-                                    0),
-                pend_thr=jnp.where(defer,
-                                   bs2.threshold.astype(jnp.int32), 0),
-                pend_dl=defer & bs2.default_left.astype(bool),
-                pend_iscat=defer & iscat2,
-                pend_bits=jnp.where(defer, bits2,
-                                    jnp.zeros_like(bits2)),
-                pend_nl0=jnp.where(defer, nl0, 0),
-                pend_n=jnp.where(defer, napp, 0),
-            )
+                leaf_of_row, hist_wave = jax.lax.switch(
+                    kidx_m, mega_branches, (st.leaf_of_row, tbl16))
+            st = st._replace(leaf_of_row=leaf_of_row)
             slot_small = None
         elif use_apply:
             # ---- wide/categorical/EFB TPU path: per-(entry, row) go-left
@@ -1744,7 +1385,7 @@ def grow_tree_wave(
         # ---- HIST + SEARCH, skipped entirely when no candidates (e.g.
         # the final wave of a tree)
         def spec_branch(st):
-            if use_mega or use_fused_tiled:
+            if use_mega:
                 hist_local = hist_wave
             else:
                 kidx = jnp.searchsorted(bucket_bounds,
@@ -1778,15 +1419,9 @@ def grow_tree_wave(
                 hist_small = hist_local
             else:
                 hist_small = exchange_hist(hist_local, psum, 1)
-            if use_fused or use_fused_tiled:
-                # the same gather already ran for the kernel's scan
-                # operand — reuse it (XLA CSE would anyway; this keeps
-                # the dependency explicit)
-                hist_parent = parent_flat.reshape((KMAX,) + hshape)
-            else:
-                hist_parent = _onehot_gather(
-                    st.hist_cache, jnp.where(valid, cand, L)
-                ).reshape((KMAX,) + hshape)                  # [K, C, F, B]
+            hist_parent = _onehot_gather(
+                st.hist_cache, jnp.where(valid, cand, L)
+            ).reshape((KMAX,) + hshape)                      # [K, C, F, B]
             hist_large = hist_parent - hist_small
             hist_l = jnp.where(smaller_is_left[:, None, None, None],
                                hist_small, hist_large)
@@ -1857,46 +1492,6 @@ def grow_tree_wave(
                 fidl_k = fidr_k = jnp.full((KMAX,), -1, jnp.int32)
                 fid_lr = None
             n_batch = (3 if research_own else 2) * KMAX
-            if use_fused or use_fused_tiled:
-                # the kernel's final-step scan already searched both
-                # children of every candidate on the identical histogram
-                # values (ops/grow_fused.py) — unpack its record block
-                # instead of re-running the vmapped search. hist_lr and
-                # friends above become dead code XLA eliminates (unless
-                # the categorical epilogue below consumes them); only
-                # hist_small (the next wave's subtraction cache) and the
-                # scalar concatenations survive.
-                from .grow_fused import unpack_fused_records
-                s_lr = unpack_fused_records(rec_wave, KMAX)
-                cat_lr = jnp.zeros((2 * KMAX,), bool)
-                bits_lr = jnp.zeros((2 * KMAX, W), jnp.uint32)
-                forced_lr = jnp.zeros((2 * KMAX,), bool)
-                if use_fused_tiled and cfg.has_categorical:
-                    # the in-kernel scan is numeric-only; run the
-                    # categorical search in XLA on the identical child
-                    # histograms and merge by gain — the exact
-                    # make_search order (categorical wins strict ties
-                    # the same way: catres.gain > num.gain)
-                    def cat_search(h2, sg_, sh_, c_, o_, bn_, bx_, st_):
-                        h3 = with_counts(to_f32(h2), c_, sh_)
-                        fmask_c = (sets_to_fmask(st_, meta, feature_mask)
-                                   if has_inter else feature_mask)
-                        return find_best_split_categorical(
-                            h3, sg_, sh_, c_, o_, meta, hp, cfg.cat,
-                            fmask_c,
-                            leaf_min=bn_ if has_mono else None,
-                            leaf_max=bx_ if has_mono else None)
-
-                    catres, words = jax.vmap(cat_search)(
-                        hist_lr, sg_lr, sh_lr, c_lr, o_lr,
-                        bmin_lr, bmax_lr, sets_lr)
-                    use_cat = catres.gain > s_lr.gain
-                    s_lr = SplitResult(*[
-                        jnp.where(use_cat, cv, nv)
-                        for cv, nv in zip(catres, s_lr)])
-                    cat_lr = use_cat
-                    bits_lr = jnp.where(use_cat[:, None], words,
-                                        jnp.zeros_like(words))
             if bynode:
                 bn_masks = node_masks(
                     jax.random.fold_in(_bn_base,
@@ -1994,7 +1589,7 @@ def grow_tree_wave(
                 # voted-local feature index -> global feature id
                 s_lr = s_lr._replace(feature=jnp.take_along_axis(
                     vf, s_lr.feature[:, None], axis=1)[:, 0])
-            elif not use_fused and not use_fused_tiled:
+            else:
                 xt_rand = (xt_bins(
                     jax.random.fold_in(_xt_base, st.tree.num_waves + 1),
                     n_batch) if xt else None)
@@ -2129,28 +1724,6 @@ def grow_tree_wave(
 
     if L > 1:
         state = jax.lax.while_loop(cond, wave_step, state)
-
-    if use_fused_tiled and cfg.fused_relabel_fusion:
-        # the tree's LAST wave is applies-only, so its deferred relabel
-        # has no successor kernel — run it here in XLA once per tree
-        # (everything below, quantized leaf renewal included, reads the
-        # final leaf_of_row)
-        def _flush_final(st):
-            jf = jnp.arange(KMAX, dtype=jnp.int32)
-            glp = dec_go_left(st.pend_leaf, st.pend_feat, st.pend_thr,
-                              st.pend_dl, st.pend_iscat, st.pend_bits)
-            mP = st.leaf_of_row[None, :] == st.pend_leaf[:, None]
-            slp = jnp.sum(jnp.where(mP, jf[:, None], 0), axis=0)
-            glr = jnp.sum(jnp.where(mP, glp.astype(jnp.int32), 0),
-                          axis=0)
-            hit = jnp.any(mP, axis=0)
-            lor2 = jnp.where(hit & (glr == 0), st.pend_nl0 + slp,
-                             st.leaf_of_row)
-            return st._replace(leaf_of_row=lor2,
-                               pend_n=jnp.asarray(0, jnp.int32))
-
-        state = jax.lax.cond(state.pend_n > 0, _flush_final,
-                             lambda s: s, state)
 
     tree_out = state.tree
     if quant and cfg.quant_renew_leaf and cfg.path_smooth <= 1e-15:

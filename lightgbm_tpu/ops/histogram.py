@@ -39,9 +39,9 @@ from ..utils import round_up as _round_up
 def pallas_interpret() -> bool:
     """LIGHTGBM_TPU_PALLAS_INTERPRET=1 routes every Pallas histogram /
     wave kernel through the Pallas interpreter (any backend): the
-    kernel-true CPU mode the bitwise-parity suites and bench reference
-    rates use (tests/test_grow_fused.py, scripts/bench_fused.py). Read
-    at TRACE time, like the kill switch below."""
+    kernel-true CPU mode the parity suites use
+    (tests/test_wave_kernels.py). Read at TRACE time, like the kill
+    switch below."""
     return os.environ.get("LIGHTGBM_TPU_PALLAS_INTERPRET", "").lower() \
         in ("1", "true", "yes")
 
@@ -66,7 +66,7 @@ def _tier_route(tiers, F: int, num_bins: int, impl: str):
 
     `tiers` is the per-STORAGE-COLUMN bin count tuple in storage order
     (GrowConfig.hist_tiers); `impl` is one of "auto" / "legacy" /
-    "tiered" / "tiered_hilo" / "rowwise" / "rowwise_packed" / "fused"
+    "tiered" / "tiered_hilo" / "rowwise" / "rowwise_packed"
     (config.histogram_impl, possibly overridden by runtime/autotune.py).
 
     Returns None (uniform legacy kernel, caller's num_bins), or
@@ -79,15 +79,11 @@ def _tier_route(tiers, F: int, num_bins: int, impl: str):
     against its C*K output size and falls back to the col-wise route),
     or ("rowwise_packed", rplan, pplan) for its 4-bit packed variant
     (falls back to plain rowwise when fewer than two columns fit a
-    nibble). "fused" names the wave grower's fused megakernel
-    (ops/grow_fused.py) — it has no plain-histogram form, so here it
-    routes like "auto".
+    nibble).
 
     The `len(tiers) != F` guard keeps callers that slice the feature
     axis (feature-parallel shards, compile-warm dummy calls) on the
     legacy kernel rather than mis-applying a full-width plan."""
-    if impl == "fused":
-        impl = "auto"
     if impl == "legacy" or not tiers or len(tiers) != F \
             or max(tiers) > 256:
         return None

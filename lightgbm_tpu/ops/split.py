@@ -298,11 +298,6 @@ def find_best_split(
     mono_pen_factor: jnp.ndarray | None = None,  # scalar: monotone_penalty
     #   gain multiplier for splits on monotone features
     #   (ComputeMonotoneSplitGainPenalty, monotone_constraints.hpp:358)
-    with_raw: bool = False,     # also return the RAW (pre-shift) argmax
-    #   gain — the merge key for the feature-tiled fused kernel's
-    #   cross-tile reduction (ops/grow_fused.py merge_tile_records): the
-    #   shifted gain collapses -inf/non-finite cells, the raw value is
-    #   the exact quantity the flat argmax ordered by
 ) -> SplitResult:
     """Best numerical split over all features for one leaf.
 
@@ -359,13 +354,11 @@ def find_best_split(
             (gain - min_gain_shift) * mono_pen_factor + min_gain_shift,
             gain)
 
-    return _pick_best(gain, stats, F, B, min_gain_shift,
-                      with_raw=with_raw)
+    return _pick_best(gain, stats, F, B, min_gain_shift)
 
 
-def _pick_best(gain, stats, F, B, min_gain_shift, with_raw=False):
-    """Argmax over a filtered [2, F, B] gain map + exact stat selection.
-    With `with_raw` returns (SplitResult, raw_best_gain)."""
+def _pick_best(gain, stats, F, B, min_gain_shift):
+    """Argmax over a filtered [2, F, B] gain map + exact stat selection."""
     lg, lh, lc, rg, rh, rc, lout, rout = stats
     flat = gain.reshape(-1)
     best = jnp.argmax(flat)
@@ -391,7 +384,7 @@ def _pick_best(gain, stats, F, B, min_gain_shift, with_raw=False):
 
     picked = [pick(x) for x in (lg, lh, lc, rg, rh, rc, lout, rout)]
 
-    res = SplitResult(
+    return SplitResult(
         gain=jnp.where(jnp.isfinite(best_gain),
                        best_gain - min_gain_shift, NEG_INF),
         feature=f.astype(jnp.int32),
@@ -401,9 +394,6 @@ def _pick_best(gain, stats, F, B, min_gain_shift, with_raw=False):
         right_sum_g=picked[3], right_sum_h=picked[4], right_count=picked[5],
         left_output=picked[6], right_output=picked[7],
     )
-    if with_raw:
-        return res, best_gain
-    return res
 
 
 def find_best_split_and_forced(

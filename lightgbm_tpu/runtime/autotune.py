@@ -61,9 +61,6 @@ COMM_MODE_PREFERENCE = ("reduce_scatter", "allreduce")
 # train_share_states.cpp InitTrain). "rowwise_packed" is the 4-bit
 # nibble pack (histogram_rowwise.py Pack4Plan); its probe silently runs
 # plain rowwise when nothing is packable, so it never wins a tie.
-# "fused" (the wave megakernel with the in-kernel split scan,
-# ops/grow_fused.py) is NOT in this list: it has no plain-histogram
-# form, so `probe_fused_wave` times it as a whole wave pass instead.
 HIST_IMPL_CANDIDATES = ("tiered_hilo", "tiered", "legacy", "rowwise",
                         "rowwise_packed")
 # force_col_wise restricts the probe to these (models/gbdt.py)
@@ -74,33 +71,14 @@ _MEM_CACHE: Dict[str, Dict[str, Any]] = {}
 
 
 def make_key(n_rows: int, n_features: int, max_bin: int, num_leaves: int,
-             device_kind: str = "", variant: str = "") -> str:
-    """Cache key over the shape signature that determines kernel choice.
-
-    ``variant`` carries the fused-kernel shape signature (feature tile /
-    relabel-fusion, ``fused_variant_sig``) so a decision probed under
-    one tiling never routes a differently-tiled run."""
+             device_kind: str = "") -> str:
+    """Cache key over the shape signature that determines kernel choice."""
     if not device_kind:
         import jax
         device_kind = jax.local_devices()[0].device_kind
     dk = str(device_kind).replace(" ", "_")
-    suffix = f"_{variant}" if variant else ""
     return f"r{int(n_rows)}_f{int(n_features)}_b{int(max_bin)}" \
-           f"_l{int(num_leaves)}_{dk}{suffix}"
-
-
-# default fused-kernel shape signature: folded into the UNsuffixed cache
-# key so caches written before the tiled kernel existed stay valid
-_DEFAULT_FUSED_SIG = "t32rf1"
-
-
-def fused_variant_sig(cfg) -> str:
-    """Tile/variant signature of the fused megakernel configuration —
-    part of the decision-cache key (empty = the default signature)."""
-    tile = int(getattr(cfg, "fused_feature_tile", 32))
-    rf = int(bool(getattr(cfg, "fused_relabel_fusion", True)))
-    sig = f"t{tile}rf{rf}"
-    return "" if sig == _DEFAULT_FUSED_SIG else sig
+           f"_l{int(num_leaves)}_{dk}"
 
 
 def default_cache_path() -> str:
@@ -292,217 +270,6 @@ def probe_hist_impls(X_t, cfg, impl_candidates: Sequence[str]
 
         timings[impl] = _best_of_2(jax.jit(run), (Xs, vals, slot), timer)
     return timings
-
-
-def probe_fused_wave(X_t, cfg, probe_rows: int = DEFAULT_PROBE_ROWS,
-                     seed: int = 0,
-                     timer: Callable[[], float] = time.perf_counter,
-                     ) -> Dict[str, float]:
-    """Time one synthetic wave step both ways: the two-pass shape
-    (``wave_pass_pallas`` then the XLA split search over every child)
-    vs the single-launch fused megakernel with the in-kernel scan
-    (``ops/grow_fused.py``). Past 32 features both arms switch shape:
-    two-pass becomes the wide wave (``wave_apply_pallas`` + the slots
-    histogram + the XLA search) and fused becomes the feature-TILED
-    megakernel (``wave_pass_fused_tiled_pallas``), so the probe times
-    the kernels the grower would actually launch. ``histogram_impl=
-    "fused"`` has no plain-histogram form, so it cannot ride
-    ``probe_hist_impls`` — this is its timing probe, cached in the same
-    decision. Returns ``{"two_pass": s, "fused": s}``, or ``{}`` outside
-    Pallas interpret mode: the fused kernels trace ops/split.py's search
-    inside the kernel body, which Mosaic cannot lower (grow_wave.py
-    raises when they are selected on a chip), so there is nothing to
-    time there."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from ..ops.grow_fused import (pack_fused_meta, pack_fused_scalars,
-                                  wave_pass_fused_pallas)
-    from ..ops.histogram_pallas import T_ROWS, wave_pass_pallas
-    from ..ops.split import (FeatureMeta, SplitHyperParams, find_best_split,
-                             synth_count_channel)
-
-    from ..ops.histogram import pallas_interpret
-    F_all, n = int(X_t.shape[0]), int(X_t.shape[1])
-    B = int(cfg.num_bins_padded)
-    if B > 256 or not pallas_interpret():
-        return {}
-    if F_all > 32:
-        return _probe_fused_wave_tiled(X_t, cfg, probe_rows=probe_rows,
-                                       seed=seed, timer=timer)
-    F = F_all
-    m = max(min(int(probe_rows), n), 1)
-    Xs = jnp.asarray(jax.device_get(X_t[:F, :m]))
-    rng = np.random.RandomState(seed)
-    vals = jnp.asarray(
-        rng.uniform(-0.5, 0.5, size=(2, m)).astype(np.float32))
-    K, KMAX = 4, 8
-    lor = jnp.asarray(rng.randint(0, K, size=m).astype(np.int32))
-    tiers = tuple(int(t) for t in cfg.hist_tiers[:F])
-    nb = np.clip(np.asarray(tiers + (B,) * (F - len(tiers)), np.int32),
-                 2, B)
-
-    # synthetic wave table: K candidate leaves splitting feature 0 at the
-    # mid bin, no applied entries (relabel work is identical either way)
-    tbl = np.full((T_ROWS, 128), -1, np.int32)
-    tbl[7, :K] = np.arange(K)                  # cand leaf ids
-    tbl[8, :K] = 0                             # cand feature
-    tbl[9, :K] = max(int(nb[0]) // 2 - 1, 0)   # cand threshold
-    tbl[10, :K] = 1                            # default_left
-    tbl[11, :K] = 0                            # missing none
-    tbl[12, :K] = 0
-    tbl[13, :K] = nb[0]
-    tbl[14, :K] = 1                            # smaller_is_left
-    tbl[15, :K] = K                            # first new leaf id
-    tbl16 = jnp.asarray(tbl)
-
-    hp = SplitHyperParams(20.0, 1e-3, 0.0, 0.0, 0.0, 0.0, 0.0)
-    meta = FeatureMeta(num_bins=jnp.asarray(nb),
-                       missing_type=jnp.zeros((F,), jnp.int32),
-                       default_bin=jnp.zeros((F,), jnp.int32),
-                       is_categorical=jnp.zeros((F,), bool))
-    fmask = jnp.ones((F,), bool)
-    parent = jnp.full((KMAX, 2, F, B), float(m), jnp.float32)
-
-    class _BS:
-        left_sum_g = jnp.zeros((KMAX,), jnp.float32)
-        left_sum_h = jnp.full((KMAX,), float(m) * 0.25, jnp.float32)
-        left_count = jnp.full((KMAX,), float(m) // K, jnp.float32)
-        left_output = jnp.zeros((KMAX,), jnp.float32)
-        right_sum_g = jnp.zeros((KMAX,), jnp.float32)
-        right_sum_h = jnp.full((KMAX,), float(m) * 0.25, jnp.float32)
-        right_count = jnp.full((KMAX,), float(m) // K, jnp.float32)
-        right_output = jnp.zeros((KMAX,), jnp.float32)
-
-    sil = jnp.ones((KMAX,), jnp.float32)
-    scal = pack_fused_scalars(_BS, sil, KMAX)
-    meta_ops = pack_fused_meta(meta.num_bins, meta.missing_type,
-                               meta.default_bin, meta.is_categorical)
-
-    def two_pass(X, v, l0):
-        new_lor, hist = wave_pass_pallas(X, v, l0, tbl16, K, B,
-                                         interpret=True)
-        hist = jnp.pad(hist, ((0, KMAX - K), (0, 0), (0, 0), (0, 0)))
-        hs = jnp.concatenate([hist, parent - hist], axis=0)  # [2*KMAX,...]
-        h3 = jax.vmap(lambda hh, c, s: synth_count_channel(hh, c, s))(
-            hs, jnp.tile(_BS.left_count, 2), jnp.tile(_BS.left_sum_h, 2))
-        res = jax.vmap(lambda hh, sg, sh, c, o: find_best_split(
-            hh, sg, sh, c, o, meta, hp, fmask))(
-            h3, jnp.tile(_BS.left_sum_g, 2), jnp.tile(_BS.left_sum_h, 2),
-            jnp.tile(_BS.left_count, 2), jnp.tile(_BS.left_output, 2))
-        return new_lor, hist, res.gain
-
-    def fused(X, v, l0):
-        return wave_pass_fused_pallas(X, v, l0, tbl16,
-                                      parent.reshape(KMAX, -1), scal,
-                                      meta_ops, K, B, KMAX, hp,
-                                      interpret=True)
-
-    return {name: _best_of_2(jax.jit(fn), (Xs, vals, lor), timer)
-            for name, fn in (("two_pass", two_pass), ("fused", fused))}
-
-
-def _probe_fused_wave_tiled(X_t, cfg, probe_rows: int = DEFAULT_PROBE_ROWS,
-                            seed: int = 0,
-                            timer: Callable[[], float] = time.perf_counter,
-                            ) -> Dict[str, float]:
-    """F > 32 arm of ``probe_fused_wave``: one synthetic wave step as
-    the wide two-pass wave (precomputed decision bits -> membership
-    kernel -> slots histogram -> XLA child search) vs the feature-tiled
-    fused megakernel. The decision-bit precompute is identical on both
-    sides, so it is built once outside the timed functions."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from ..ops.grow_fused import (pack_fused_fmask_tiled,
-                                  pack_fused_meta_tiled, pack_fused_scalars,
-                                  wave_pass_fused_tiled_pallas)
-    from ..ops.histogram import build_histogram_slots
-    from ..ops.histogram_pallas import T_ROWS, wave_apply_pallas
-    from ..ops.split import (FeatureMeta, SplitHyperParams, find_best_split,
-                             synth_count_channel)
-
-    F, n = int(X_t.shape[0]), int(X_t.shape[1])
-    B = int(cfg.num_bins_padded)
-    tile = int(getattr(cfg, "fused_feature_tile", 32))
-    m = max(min(int(probe_rows), n), 1)
-    Xs = jnp.asarray(jax.device_get(X_t[:, :m]))
-    rng = np.random.RandomState(seed)
-    vals = jnp.asarray(
-        rng.uniform(-0.5, 0.5, size=(2, m)).astype(np.float32))
-    K, KMAX = 4, 8
-    lor = jnp.asarray(rng.randint(0, K, size=m).astype(np.int32))
-    tiers = tuple(int(t) for t in cfg.hist_tiers[:F])
-    nb = np.clip(np.asarray(tiers + (B,) * (F - len(tiers)), np.int32),
-                 2, B)
-    thr = max(int(nb[0]) // 2 - 1, 0)
-
-    # synthetic wave table: K candidate leaves splitting feature 0 at the
-    # mid bin, no applied entries (relabel work is identical either way)
-    tbl = np.full((T_ROWS, 128), -1, np.int32)
-    tbl[7, :K] = np.arange(K)
-    tbl[15, :] = K
-    tbl16 = jnp.asarray(tbl)
-    # decision bits (the wide wave's XLA precompute; common to both arms)
-    glC = (Xs[0].astype(jnp.int32) <= thr)[None, :]          # [1, m]
-    dec8 = jnp.where(jnp.arange(128)[:, None] < K,
-                     glC.astype(jnp.int8) << 1,
-                     jnp.int8(0))                            # [128, m]
-
-    hp = SplitHyperParams(20.0, 1e-3, 0.0, 0.0, 0.0, 0.0, 0.0)
-    meta = FeatureMeta(num_bins=jnp.asarray(nb),
-                       missing_type=jnp.zeros((F,), jnp.int32),
-                       default_bin=jnp.zeros((F,), jnp.int32),
-                       is_categorical=jnp.zeros((F,), bool))
-    fmask = jnp.ones((F,), bool)
-    parent = jnp.full((KMAX, 2, F, B), float(m), jnp.float32)
-
-    class _BS:
-        left_sum_g = jnp.zeros((KMAX,), jnp.float32)
-        left_sum_h = jnp.full((KMAX,), float(m) * 0.25, jnp.float32)
-        left_count = jnp.full((KMAX,), float(m) // K, jnp.float32)
-        left_output = jnp.zeros((KMAX,), jnp.float32)
-        right_sum_g = jnp.zeros((KMAX,), jnp.float32)
-        right_sum_h = jnp.full((KMAX,), float(m) * 0.25, jnp.float32)
-        right_count = jnp.full((KMAX,), float(m) // K, jnp.float32)
-        right_output = jnp.zeros((KMAX,), jnp.float32)
-
-    sil = jnp.ones((KMAX,), jnp.float32)
-    scal = pack_fused_scalars(_BS, sil, KMAX)
-    meta_tiles = pack_fused_meta_tiled(meta.num_bins, meta.missing_type,
-                                       meta.default_bin,
-                                       meta.is_categorical, None, tile)
-    fm_tiles = pack_fused_fmask_tiled(
-        jnp.ones((2 * KMAX, F), bool), tile, KMAX)
-    pendl = jnp.full((128,), -1, jnp.int32)
-    pnl0 = jnp.asarray(0, jnp.int32)
-
-    def two_pass(X, v, l0, d8):
-        new_lor, slot_small = wave_apply_pallas(d8, l0, tbl16,
-                                                interpret=True)
-        hist = build_histogram_slots(X, v, slot_small, K, B,
-                                     rows_per_chunk=cfg.rows_per_chunk,
-                                     tiers=tiers, impl="auto")
-        hist = jnp.pad(hist, ((0, KMAX - K), (0, 0), (0, 0), (0, 0)))
-        hs = jnp.concatenate([hist, parent - hist], axis=0)
-        h3 = jax.vmap(lambda hh, c, s: synth_count_channel(hh, c, s))(
-            hs, jnp.tile(_BS.left_count, 2), jnp.tile(_BS.left_sum_h, 2))
-        res = jax.vmap(lambda hh, sg, sh, c, o: find_best_split(
-            hh, sg, sh, c, o, meta, hp, fmask))(
-            h3, jnp.tile(_BS.left_sum_g, 2), jnp.tile(_BS.left_sum_h, 2),
-            jnp.tile(_BS.left_count, 2), jnp.tile(_BS.left_output, 2))
-        return new_lor, hist, res.gain
-
-    def fused(X, v, l0, d8):
-        return wave_pass_fused_tiled_pallas(
-            X, v, d8, l0, tbl16, pendl, pnl0,
-            parent.reshape(KMAX, -1), scal, meta_tiles, fm_tiles,
-            F, K, B, KMAX, hp, tile=tile, interpret=True)
-
-    return {name: _best_of_2(jax.jit(fn), (Xs, vals, lor, dec8), timer)
-            for name, fn in (("two_pass", two_pass), ("fused", fused))}
 
 
 def probe_comm_modes(mesh, n_features: int, num_bins_padded: int,
@@ -735,11 +502,10 @@ def autotune_decision(X_t, meta, cfg, candidates: Sequence[str], *,
     COL_WISE_HIST_IMPLS under force_col_wise); None = all candidates.
     """
     impl_cands = tuple(hist_impl_candidates or HIST_IMPL_CANDIDATES)
-    # "fused" never rides the plain-histogram probe list but is a valid
-    # cached outcome of the fused-wave probe below
-    impl_ok = (None, "fused", *impl_cands)
-    key = make_key(n_rows, n_features, max_bin, num_leaves,
-                   variant=fused_variant_sig(cfg))
+    # a cached entry naming any other impl (a cache file is input from
+    # outside the program) reads as a miss and is probed again
+    impl_ok = (None, *impl_cands)
+    key = make_key(n_rows, n_features, max_bin, num_leaves)
     if key in _MEM_CACHE \
             and _MEM_CACHE[key].get("hist_impl") in impl_ok:
         return dict(_MEM_CACHE[key], cached="memory")
@@ -780,21 +546,6 @@ def autotune_decision(X_t, meta, cfg, candidates: Sequence[str], *,
             probe_rows=probe_rows, seed=seed, timer=timer)
         hist_impl = _pick_winner(hist_impl_timings, HIST_IMPL_CANDIDATES)
 
-    # fused wave megakernel (ops/grow_fused.py): only reachable when the
-    # wave grower won and the layout choice is open; must beat the
-    # two-pass wave OUTRIGHT (a tie keeps the well-trodden unfused path)
-    fused_timings: Dict[str, float] = {}
-    if getattr(cfg, "hist_impl", "auto") == "auto" \
-            and getattr(cfg, "hist_tiers", ()) \
-            and winner in ("wave", "wave_exact") \
-            and hist_impl not in ("rowwise", "rowwise_packed"):
-        fused_timings = probe_fused_wave(X_t, cfg, probe_rows=probe_rows,
-                                         seed=seed, timer=timer)
-        if "fused" in fused_timings and "two_pass" in fused_timings \
-                and fused_timings["fused"] \
-                < fused_timings["two_pass"] * (1.0 - TIE_TOL):
-            hist_impl = "fused"
-
     decision: Dict[str, Any] = {
         "grower": winner,
         "rows_per_chunk": rows_per_chunk,
@@ -804,9 +555,6 @@ def autotune_decision(X_t, meta, cfg, candidates: Sequence[str], *,
                           for k, v in chunk_timings.items()},
         "hist_impl_timings": {k: round(v, 6)
                               for k, v in hist_impl_timings.items()},
-        "fused_wave_timings": {k: round(v, 6)
-                               for k, v in fused_timings.items()},
-        "fused_variant": fused_variant_sig(cfg) or _DEFAULT_FUSED_SIG,
         "key": key,
         "probe_rows": min(int(probe_rows), int(X_t.shape[1])),
     }

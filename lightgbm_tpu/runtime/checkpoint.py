@@ -39,6 +39,7 @@ import re
 import tempfile
 from typing import Any, Dict, List, Optional, Tuple
 
+from ..config import HISTOGRAM_IMPLS
 from ..utils.log import log_fatal, log_info, log_warning
 
 STATE_FORMAT = 1
@@ -350,6 +351,13 @@ def restore_trainer_state(gbdt, state: Dict[str, Any]) -> None:
         gbdt.grower = saved_grower
         rebuild = True
     pins = state.get("grow_pins") or {}
+    if pins.get("hist_impl", "auto") not in HISTOGRAM_IMPLS:
+        # a checkpoint is input from outside the program: a value this
+        # version does not have is refused, never handed to the grower
+        log_fatal(f"checkpoint pins histogram_impl='{pins['hist_impl']}', "
+                  "which this version does not have (supported: "
+                  f"{', '.join(map(repr, HISTOGRAM_IMPLS))}); resume it with the "
+                  "version that wrote it, or retrain")
     rep = {k: pins[k] for k in ("rows_per_chunk", "hist_impl",
                                 "parallel_hist_mode")
            if k in pins and pins[k] != getattr(gbdt.grow_cfg, k)}

@@ -621,14 +621,3 @@ def pallas_kernel_names(fn: Callable, *args: Any,
         return names
 
     return walk(jax.make_jaxpr(fn, **kwargs)(*args).jaxpr)
-
-
-def count_pallas_launch_sites(fn: Callable, *args: Any,
-                              **kwargs: Any) -> int:
-    """Static count of Pallas kernel launch sites in ``fn``'s jaxpr
-    (``pallas_kernel_names``' walk). Sites inside a while body dispatch
-    once per trip, so for the wave grower this is exactly the
-    launches-per-wave figure the relabel fusion halves (docs/PERF.md §6)
-    — the dispatch-count analog that regression tests pin
-    (tests/test_grow_fused.py)."""
-    return len(pallas_kernel_names(fn, *args, **kwargs))

@@ -28,10 +28,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DOC_PATHS = ["README.md", "docs/PERF.md", "docs/PARITY.md",
              "docs/SERVING.md", "docs/ROBUSTNESS.md", "docs/ONLINE.md"]
 BENCH_GLOBS = ["BENCH_EXTRAS.json", "BENCH_ROWWISE.json",
-               "BENCH_COMM.json", "BENCH_FUSED.json", "BENCH_RESIL.json",
-               "BENCH_SLO.json", "BENCH_ONLINE.json", "BENCH_FLEET.json",
-               "BENCH_EXPORT.json", "BENCH_BATCHED.json", "BASELINE.json",
-               "BENCH_BINNING.json"]
+               "BENCH_COMM.json", "BENCH_RESIL.json", "BENCH_SLO.json",
+               "BENCH_ONLINE.json", "BENCH_FLEET.json", "BENCH_EXPORT.json",
+               "BENCH_BATCHED.json", "BASELINE.json", "BENCH_BINNING.json"]
 REL_TOL = 0.05          # claims are rounded for display (700M vs 680.4M)
 SKIP_BEFORE = "≥≤<>~="  # bound / approximation markers: not measurements
 

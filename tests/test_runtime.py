@@ -248,6 +248,34 @@ def test_autotune_cache_roundtrips_to_disk(binary_data, tmp_path):
     assert d3["cached"] == "memory"
 
 
+def test_stale_cache_entry_naming_a_removed_impl_is_reprobed(binary_data,
+                                                            tmp_path):
+    """A cache file is input from outside the program. An entry an older
+    version wrote with hist_impl='fused' (a kernel family that is gone),
+    or under a key with the old '_t64rf1' variant suffix, must read as a
+    miss: the shape is probed again, the decision names a surviving impl,
+    the grower never sees 'fused', and training proceeds."""
+    X, y = binary_data
+    path = str(tmp_path / "old_cache.json")
+    key = at.make_key(len(y), 6, 255, PARAMS["num_leaves"])
+    old = {"grower": "wave", "rows_per_chunk": 8192, "hist_impl": "fused",
+           "timings": {}, "chunk_timings": {}, "hist_impl_timings": {},
+           "fused_wave_timings": {"two_pass": 0.2, "fused": 0.1},
+           "key": key, "probe_rows": 0}
+    with open(path, "w") as fh:
+        json.dump({key: old,
+                   key + "_t64rf1": dict(old, key=key + "_t64rf1")}, fh)
+    bst = lgb.train(dict(PARAMS, autotune=True, autotune_cache=path),
+                    lgb.Dataset(X, label=y), num_boost_round=3)
+    d = bst._gbdt.autotune_decision
+    assert d["cached"] is False and d["key"] == key
+    assert d["hist_impl"] in (None, *at.HIST_IMPL_CANDIDATES)
+    assert bst._gbdt.grow_cfg.hist_impl in ("auto", *at.HIST_IMPL_CANDIDATES)
+    assert len(d["timings"]) >= 2            # the probes really ran
+    assert json.load(open(path))[key]["hist_impl"] == d["hist_impl"]
+    assert np.mean((bst.predict(X) > 0.5) == (y > 0.5)) > 0.9
+
+
 def test_pick_winner_prefers_ladder_order_on_tie():
     assert at._pick_winner({"masked": 1.0, "compact": 1.0, "wave": 1.0},
                            at.AUTOTUNE_PREFERENCE) == "wave"

@@ -549,7 +549,7 @@ def test_every_pallas_site_carries_an_explicit_distinct_name():
     import kernel_check
     rng = np.random.RandomState(0)
     by_case = {}
-    for case, build, _ in kernel_check.cases():
+    for case, build in kernel_check.cases():
         make, fn_of = build()
         by_case[case] = P.pallas_kernel_names(fn_of(False), *make(rng))
     assert all(by_case.values()), by_case
@@ -560,8 +560,8 @@ def test_every_pallas_site_carries_an_explicit_distinct_name():
     assert families == {
         "lgbm_hist_slots", "lgbm_take_leaf_values", "lgbm_wave_pass",
         "lgbm_wave_apply", "lgbm_wave_relabel", "lgbm_hist_rowwise",
-        "lgbm_hist_rowwise_packed", "lgbm_fused_wave", "lgbm_fused_tiled",
-        "lgbm_bucketize", "lgbm_predict_forest"}, families
+        "lgbm_hist_rowwise_packed", "lgbm_bucketize",
+        "lgbm_predict_forest"}, families
     # one name per compiled variant: bins, hi/lo split and operand type
     variants = [by_case[c][0] for c in (
         "megakernel B64 f32", "megakernel B64 int8",

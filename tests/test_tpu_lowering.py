@@ -18,21 +18,13 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
 
 import kernel_check  # noqa: E402
 
-CASES = {name: (build, broken) for name, build, broken
-         in kernel_check.cases()}
+CASES = dict(kernel_check.cases())
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_family_lowers_for_tpu(name):
-    build, known_broken = CASES[name]
-    make, fn_of = build()
+    make, fn_of = CASES[name]()
     specs = [jax.ShapeDtypeStruct(a.shape, a.dtype)
              for a in map(np.asarray, make(np.random.RandomState(0)))]
     export = jax.export.export(jax.jit(fn_of(False)), platforms=["tpu"])
-    if known_broken:
-        # the fused megakernels: the day these lower, drop the refusal in
-        # grow_tree_wave and the known_broken flag in kernel_check.py
-        with pytest.raises(NotImplementedError, match="cumsum"):
-            export(*specs)
-    else:
-        assert export(*specs).mlir_module().count("tpu_custom_call") >= 1
+    assert export(*specs).mlir_module().count("tpu_custom_call") >= 1
