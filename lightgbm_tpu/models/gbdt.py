@@ -2059,14 +2059,15 @@ class GBDT:
                 key = (start_iteration, end, len(self.models))
                 cache = getattr(self, "_device_tables_cache", None)
                 if cache is None or cache[0] != key:
-                    # None where the tables do not fit beside these rows
+                    # None where the tables do not fit beside the row
+                    # blocks the predictor has in flight
                     with span("predict/tables"):
                         cache = (key, build_device_tables(
                             trees, K, X.shape[1], rows=rows))
                 tables = cache[1]
                 if tables is None or tables.over_budget(rows):
-                    # rows and tables do not fit the device together:
-                    # the host walk below answers, and says so
+                    # row blocks and tables do not fit the device
+                    # together: the host walk below answers, and says so
                     span_count(tables_over_budget=1)
                 else:
                     self._device_tables_cache = cache

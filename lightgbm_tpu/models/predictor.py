@@ -25,6 +25,7 @@ Python loop per tree. The same packed arrays drive:
 
 from __future__ import annotations
 
+import collections
 import functools
 from typing import Any, List, NamedTuple, Optional
 
@@ -458,10 +459,17 @@ class DeviceTables(NamedTuple):
         wide = self.terms > 1 or self.tkeys.shape[1] > _COUNT_CHUNK
         return self.F_pad * (self.terms + (12 if wide else 4))
 
+    def resident_rows(self, rows: int) -> int:
+        """Rows of a table of ``rows`` that predict_margin_device has on
+        the device at once: the blocks in flight, not the table."""
+        block_rows, _, in_flight = _row_blocks(rows, self.F, self.row_tile)
+        return in_flight * block_rows
+
     def over_budget(self, rows: int) -> bool:
-        """The device has no room for these tables beside ``rows`` rows."""
-        return self.nbytes > device_tables_budget(rows, self.F,
-                                                  self.layout_row_bytes)
+        """The device has no room for these tables beside what it holds
+        of a table of ``rows`` rows while that is scored."""
+        return self.nbytes > device_tables_budget(
+            self.resident_rows(rows), self.F, self.layout_row_bytes)
 
 
 def _padded_shape(trees):
@@ -481,8 +489,9 @@ def build_device_tables(trees, num_class_models: int, F: int,
     """Upload per-tree path tables for predict_margin_device (cacheable
     across calls while the model is unchanged — a serving loop should
     reuse them like the host _packed_model cache). With ``rows``, None
-    and no upload where the arrays pass device_tables_budget for that
-    many rows. What the model shows is not needed compiles out: no
+    and no upload where the arrays pass device_tables_budget beside what
+    a table of that many rows keeps on the device (DeviceTables
+    .resident_rows). What the model shows is not needed compiles out: no
     categorical node, W = 0 and no ncat; no node that reads the NaN code
     or tests for zero-as-missing, the flags.
 
@@ -590,12 +599,13 @@ def _device_memory_bytes() -> Optional[int]:
 def device_tables_budget(rows: int, num_features: int,
                          layout_row_bytes: int) -> int:
     """Bytes a forest's tables may take of the device: what its memory
-    leaves beside the rows as `predict_margin_device` holds them, all at
-    once: X float32 and what `_layout` makes of it
-    (DeviceTables.layout_row_bytes). The tables stay in HBM and stream to
-    the kernel a tree a grid step, so room is all they cost. Negative
-    where the rows alone do not fit: the host walk answers then too. 300
-    MB where the backend states no memory."""
+    leaves beside the ``rows`` rows that `predict_margin_device` holds at
+    once (the blocks in flight: DeviceTables.resident_rows): X float32
+    and what `_layout` makes of it (DeviceTables.layout_row_bytes). The
+    tables stay in HBM and stream to the kernel a tree a grid step, so
+    room is all they cost. Negative where the rows alone do not fit: the
+    host walk answers then too. 300 MB where the backend states no
+    memory."""
     memory = _device_memory_bytes()
     if memory is None:
         return 300_000_000
@@ -891,6 +901,32 @@ def _get_device_margin():
                 "interpret")
 
 
+# predict_margin_device scores a table in row blocks: the float32 bytes
+# of the largest block, and how many blocks are on the device at once (one
+# scored, one uploading, one whose margins the host casts)
+_BLOCK_BYTES = 256 << 20
+_IN_FLIGHT = 3
+
+
+def _row_blocks(rows: int, features: int, row_tile: int):
+    """(block_rows, blocks, in_flight) for a table of ``rows`` float32
+    rows: what predict_margin_device cuts it into and DeviceTables
+    .resident_rows budgets by. A table of at most _BLOCK_BYTES is one
+    block, itself. A larger one is cut into blocks of one shape, a whole
+    number of row tiles between half of _BLOCK_BYTES and all of it: the
+    count that scores the fewest tiles twice (the last block ends at the
+    last row and overlaps the one before it), the smaller block on a
+    tie."""
+    tiles = max(-(-rows // row_tile), 1)
+    most = max(_BLOCK_BYTES // (4 * features * row_tile), 1)
+    if tiles <= most:
+        return rows, 1, 1
+    per = min(range((most + 1) // 2, most + 1),
+              key=lambda t: (-(-tiles // t) * t - tiles, t))
+    blocks = -(-tiles // per)
+    return per * row_tile, blocks, min(blocks, _IN_FLIGHT)
+
+
 def predict_margin_device(trees, num_class_models: int, X,
                           tables: Optional[DeviceTables] = None):
     """Device batch margins — the TPU-native matmul formulation (no
@@ -912,7 +948,18 @@ def predict_margin_device(trees, num_class_models: int, X,
     (_forest_pallas); elsewhere the same math runs as XLA scans
     (docs/PERF.md section 9). X is [N, F] float32 (device or host);
     returns [K, N] f64 margins. Linear leaves are not supported (use the
-    host path)."""
+    host path).
+
+    The pass is a software pipeline over row blocks (_row_blocks: one
+    shape, whole row tiles, sized from rows x F x 4 bytes; a small table
+    is one block and the loop runs once). A block is three asynchronous
+    dispatches: upload, _layout, the kernel. At most _IN_FLIGHT blocks
+    are on the device: before block b is issued, block b - _IN_FLIGHT is
+    finished (waited for, fetched, cast into the float64 result), so one
+    block uploads and one is cast while another is scored. The last
+    block ends at the last row, so it has the others' shape and one
+    compilation serves the call; the rows it shares with the block
+    before it are scored twice and written once."""
     import jax
     import jax.numpy as jnp
 
@@ -929,24 +976,51 @@ def predict_margin_device(trees, num_class_models: int, X,
     n = tables.row_tile
     interpret = pallas_interpret()
     fused = interpret or jax.default_backend() == "tpu"
-    with span("predict/upload", bytes_up=N * F * 4):
-        Xd = jnp.asarray(np.asarray(X, np.float32)) \
-            if not isinstance(X, jnp.ndarray) else X.astype(jnp.float32)
-    # the codes: a byte a term of a padded cell
-    with span("predict/layout", layout_bytes=max(_round_up(N, n), n)
-              * tables.terms * tables.F_pad):
-        codes = _get_layout()(Xd, tables.tkeys, tables.ncat, n=n,
-                              terms=tables.terms, has_nan=tables.has_nan,
-                              dual=tables.dual)
-    with span("predict/dispatch", fused=int(fused), row_tile=n,
-              **tables.counts):
-        out_dev = _get_device_margin()(
-            codes, *tables.arrays, K=tables.K, has_nan=tables.has_nan,
-            has_zero=tables.has_zero, n=n, fused=fused, interpret=interpret)
-    # the wait device_get would make anyway, timed apart from the copy
-    with span("predict/wait_device"):
-        out_dev.block_until_ready()
-    with span("predict/download", bytes_down=out_dev.nbytes):
-        out = np.asarray(jax.device_get(out_dev))
-    with span("predict/cast_out"):
-        return out[:, :N].astype(np.float64)
+    block_rows, blocks, in_flight = _row_blocks(N, F, n)
+    span_count(blocks=blocks, block_rows=block_rows, in_flight=in_flight)
+    on_device = isinstance(X, jnp.ndarray)
+    if not on_device:
+        X = np.asarray(X, np.float32)
+    out = np.empty((tables.K, N), np.float64)
+    pending: collections.deque = collections.deque()
+    dispatch_counts = dict(fused=int(fused), row_tile=n, **tables.counts)
+
+    def finish():
+        start, first, out_dev = pending.popleft()
+        # the wait device_get would make anyway, timed apart from the copy
+        with span("predict/wait_device"):
+            out_dev.block_until_ready()
+        with span("predict/download", bytes_down=out_dev.nbytes):
+            got = np.asarray(jax.device_get(out_dev))
+        # from the first row no earlier block answered, without the pad
+        with span("predict/cast_out"):
+            out[:, first:start + block_rows] = \
+                got[:, first - start:block_rows]
+
+    for b in range(blocks):
+        if len(pending) == in_flight:
+            finish()
+        first = b * block_rows
+        start = min(first, N - block_rows)
+        with span("predict/upload", bytes_up=block_rows * F * 4):
+            Xd = X if blocks == 1 else X[start:start + block_rows]
+            Xd = Xd.astype(jnp.float32) if on_device else jax.device_put(Xd)
+            if b == 0 and blocks > 1:
+                # the first block travels alone: copies issued together
+                # share the link, and it would arrive with the third
+                Xd.block_until_ready()
+        # the codes: a byte a term of a padded cell
+        with span("predict/layout", layout_bytes=max(
+                _round_up(block_rows, n), n) * tables.terms * tables.F_pad):
+            codes = _get_layout()(Xd, tables.tkeys, tables.ncat, n=n,
+                                  terms=tables.terms, has_nan=tables.has_nan,
+                                  dual=tables.dual)
+        with span("predict/dispatch", **dispatch_counts):
+            pending.append((start, first, _get_device_margin()(
+                codes, *tables.arrays, K=tables.K, has_nan=tables.has_nan,
+                has_zero=tables.has_zero, n=n, fused=fused,
+                interpret=interpret)))
+        del Xd, codes       # the device drops them when the kernel has run
+    while pending:
+        finish()
+    return out

@@ -51,7 +51,10 @@ import time
 from typing import Any, Callable, Dict, List, Optional
 
 SPAN_PREFIX = "lgbm:"           # a span's name in a profiler trace
-SPAN_RING_SIZE = 4096
+# a pass of the device predictor leaves six spans a row block (118 records
+# at 1M x 968 rows), and a process's set-up spans must outlast some tens
+# of passes: the benchmark reads them after its window
+SPAN_RING_SIZE = 16384
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 

@@ -45,6 +45,26 @@ def rng():
     return np.random.RandomState(42)
 
 
+@pytest.fixture
+def force_row_blocks(monkeypatch):
+    """``force_row_blocks(tiles=1, in_flight=2)`` replaces the device
+    predictor's block rule (models/predictor.py _row_blocks, which has no
+    knob: it reads the table's bytes) by blocks of ``tiles`` row tiles,
+    so that a table the CPU holds makes several."""
+    from lightgbm_tpu.models import predictor
+
+    def force(tiles=1, in_flight=2):
+        def rule(rows, features, row_tile):
+            blocks = -(-rows // (tiles * row_tile))
+            if blocks <= 1:
+                return rows, 1, 1
+            return tiles * row_tile, blocks, min(blocks, in_flight)
+
+        monkeypatch.setattr(predictor, "_row_blocks", rule)
+
+    return force
+
+
 @pytest.fixture(autouse=True)
 def no_leaked_threads():
     """Fail any test that leaves a NON-DAEMON thread running: a leaked

@@ -444,3 +444,96 @@ def test_fused_kernel_is_bit_equal(monkeypatch, name):
     else:
         assert np.array_equal(kernel.astype(np.float32),
                               _removed_xla_body(trees, K, X))
+
+
+# ---------------------------------------------------------------------------
+# the pass as a pipeline over row blocks (predictor._row_blocks)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("rows,features,row_tile,want", [
+    # the Bosch and the Higgs tables: 245 tiles as 19 blocks of 13 (two
+    # tiles scored twice), 2,564 tiles as 5 blocks of 513 (one)
+    (1_000_000, 968, 4096, (13 * 4096, 19, 3)),
+    (10_500_000, 28, 4096, (513 * 4096, 5, 3)),
+    # up to 256 MiB: one block, the table itself, padded by _layout
+    (100_000, 28, 4096, (100_000, 1, 1)),
+    (65_000, 968, 4096, (65_000, 1, 1)),
+    (0, 28, 4096, (0, 1, 1)),
+    # just over: two blocks, fewer in flight than the line allows
+    (70_000, 968, 4096, (9 * 4096, 2, 2)),
+    # a row wider than a block's bytes: a tile a block
+    (20_000, 40_000, 1024, (1024, 20, 3)),
+])
+def test_row_block_rule(rows, features, row_tile, want):
+    from lightgbm_tpu.models import predictor
+    got = predictor._row_blocks(rows, features, row_tile)
+    assert got == want
+    block_rows, blocks, in_flight = got
+    if blocks > 1:
+        # one shape, whole tiles, the last block inside the table
+        assert block_rows % row_tile == 0 and block_rows < rows
+        assert (blocks - 1) * block_rows < rows <= blocks * block_rows
+        assert block_rows * features * 4 <= 256 << 20
+        twice = blocks * block_rows - -(-rows // row_tile) * row_tile
+        assert 0 <= twice <= 0.02 * rows
+
+
+@pytest.mark.parametrize("name", ["ragged_rows", "multiclass3",
+                                  "categorical"])
+def test_row_blocks_are_bit_equal_to_one_block(monkeypatch, force_row_blocks,
+                                               name):
+    """A call of three blocks, the last of which overlaps the second,
+    gives the bits of the one-block call and of the host walk: dense
+    rows, K = 3, a categorical forest with NaN rows (the Bosch-shaped
+    NaN table: tests/test_predictor_missing.py)."""
+    from lightgbm_tpu.models import predictor
+    from lightgbm_tpu.runtime import profiler
+    trees, K, X = _trained_case(name)
+    X = X[np.random.RandomState(5).randint(0, len(X), 9000)]
+    tables = predictor.build_device_tables(trees, K, X.shape[1])
+    assert tables.row_tile == 4096 and len(X) % 4096 != 0
+    monkeypatch.setenv("LIGHTGBM_TPU_PALLAS_INTERPRET", "1")
+
+    def margins():
+        with profiler.span("t/blocks"):
+            out = predictor.predict_margin_device(trees, K, X, tables=tables)
+        return out, [r for r in profiler.spans()
+                     if r["name"] == "t/blocks"][-1]["counts"]
+
+    one, counts = margins()
+    assert counts == {"blocks": 1, "block_rows": 9000, "in_flight": 1}
+    force_row_blocks()
+    many, counts = margins()
+    assert counts == {"blocks": 3, "block_rows": 4096, "in_flight": 2}
+    assert many.dtype == np.float64 and many.shape == (K, 9000)
+    assert np.array_equal(one, many)
+    assert np.array_equal(many.astype(np.float32), _walk_f32(trees, K, X))
+    # a device-resident table is cut on the device, to the same bits
+    import jax.numpy as jnp
+    assert np.array_equal(many, predictor.predict_margin_device(
+        trees, K, jnp.asarray(X), tables=tables))
+
+
+@pytest.mark.parametrize("in_flight", [1, 2, 3, 5])
+def test_rows_two_blocks_score_are_written_once(monkeypatch, force_row_blocks,
+                                                in_flight):
+    """Each row keeps the margin of the first block that holds it: with
+    a body that answers a block's number, rows 4,904 to 8,191 of 9,000
+    (in the second block and again in the third, which starts at 9,000 -
+    4,096) read 1, whatever the number of blocks in flight."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.models import predictor
+    trees, K, X = _hand_case("thresholds300")
+    X = np.concatenate([X] * 4)[:9000]
+    tables = predictor.build_device_tables(trees, K, X.shape[1])
+    force_row_blocks(in_flight=in_flight)
+    seen = []
+
+    def body(codes, *tabs, **static):
+        seen.append(codes.shape)
+        return jnp.full((K, codes.shape[1]), len(seen) - 1, jnp.float32)
+
+    monkeypatch.setattr(predictor, "_get_device_margin", lambda: body)
+    out = predictor.predict_margin_device(trees, K, X, tables=tables)
+    assert seen == [(tables.terms * tables.F_pad, 4096)] * 3
+    assert np.array_equal(out[0], np.arange(9000) // 4096)

@@ -154,6 +154,25 @@ def test_forest_trained_on_holed_data_walks_as_the_reference(monkeypatch):
     assert np.array_equal(got, ref) and counts["has_nan"] == 1
 
 
+@pytest.mark.parametrize("body", BODIES)
+def test_row_blocks_of_the_holed_table_are_bit_equal(monkeypatch,
+                                                     force_row_blocks, body):
+    """The has_nan route over three row blocks (the block rule patched to
+    one row tile a block; the last block overlaps the second): the bits
+    of the one-block call and of the reference."""
+    forest, booster = _forest(15, trees=4)
+    X = _table(9000)
+    trees = booster._gbdt.models
+    one, counts = _device(monkeypatch, trees, X, body)
+    assert (counts["blocks"], counts["has_nan"]) == (1, 1)
+    force_row_blocks()
+    many, counts = _device(monkeypatch, trees, X, body)
+    assert counts["blocks"] == 3 and counts["block_rows"] == 4096
+    assert np.array_equal(one, many)
+    assert np.array_equal(many, forest_ref_missing.score(X, forest,
+                                                        block=4096))
+
+
 def test_forest_over_the_table_budget_is_counted(monkeypatch):
     """A forest whose tables pass the device's budget takes the host
     walk, and the entry says so: tables_over_budget on predict/raw."""
@@ -234,7 +253,7 @@ def test_the_source_settings_at_the_leaf_cap_pass_a_v5e_budget(monkeypatch):
     """500 trees at the 255-leaf cap over 968 columns: 163 MB of tables
     with the int8 selector (782 MB as three bfloat16 terms a column),
     under what a v5e has beside the rows; a device with no room for them
-    gets no upload."""
+    beside the blocks in flight gets no upload."""
     forest, booster = _forest(255, trees=1)
     tables = predictor.build_device_tables(booster._gbdt.models, 1, F)
     one = tables.nbytes - tables.tkeys.nbytes           # the per-tree part
@@ -245,9 +264,17 @@ def test_the_source_settings_at_the_leaf_cap_pass_a_v5e_budget(monkeypatch):
     assert not tables.over_budget(1_000_000)
     assert tables.layout_row_bytes == 992 * 5
     assert 500 * one < predictor.device_tables_budget(1_000_000, F, 992 * 5)
-    assert tables.over_budget(2_000_000)
+    # 2M rows would not fit at once; scored in blocks they never are
+    # there at once: 3 blocks of 256 MiB at most, whatever the table
+    assert predictor.device_tables_budget(2_000_000, F, 992 * 5) < 0
+    assert 100_000 < tables.resident_rows(2_000_000) \
+        <= 3 * (256 << 20) // (4 * F)
+    assert not tables.over_budget(2_000_000)
+    monkeypatch.setattr(predictor, "_device_memory_bytes",
+                        lambda: 1_000_000_000)
+    assert tables.over_budget(1_000_000)
     assert predictor.build_device_tables(booster._gbdt.models, 1, F,
-                                         rows=2_000_000) is None
+                                         rows=1_000_000) is None
 
 
 def test_load_span_and_new_counts_reach_get_profile(monkeypatch):

@@ -148,7 +148,9 @@ def test_ring_is_bounded_and_drops_the_oldest():
             pass
     assert _names(rec.spans()) == [f"t/{i}" for i in range(12, 20)]
     assert _last_root("t/3", rec.spans()) == []
-    assert P._RECORDER.ring.maxlen == P.SPAN_RING_SIZE == 4096
+    # set-up spans outlast a window of block-wise passes: 45 Bosch
+    # passes of 4 + 6 x 19 records are 5,310
+    assert P._RECORDER.ring.maxlen == P.SPAN_RING_SIZE == 16384
 
 
 def test_profile_export_carries_the_ring(table):
@@ -291,6 +293,69 @@ def test_predict_device_route_names_are_pinned(table, booster):
     # under the Pallas interpreter, the XLA scans (fused 0)
     dispatch = by["predict/dispatch"]["counts"]
     assert (dispatch["fused"], dispatch["row_tile"]) == (0, 4096)
+
+
+def test_predict_device_route_by_blocks(table, booster, force_row_blocks):
+    """A call of three row blocks, two in flight: each stage three times
+    under the caller's span, block b - 2 finished before block b is
+    issued; the bytes of the blocks sum to the one-block call's, and the
+    span open at entry says how the table was cut."""
+    from lightgbm_tpu.models import predictor
+    from readers import program_span
+    X, _ = table
+    trees = booster._gbdt.models
+    tables = predictor.build_device_tables(trees, 1, 6)
+
+    def call(name, rows):
+        with P.span(name):
+            out = predictor.predict_margin_device(trees, 1, rows,
+                                                  tables=tables)
+        return out, _last_root(name)
+
+    want, one = call("t/one_block", X[:3 * 4096])
+    assert one[0]["counts"] == {"blocks": 1, "block_rows": 3 * 4096,
+                                "in_flight": 1}
+    force_row_blocks()
+    got, tree = call("t/three_blocks", X[:3 * 4096])
+    assert np.array_equal(got, want)
+    assert tree[0]["counts"] == {"blocks": 3, "block_rows": 4096,
+                                 "in_flight": 2}
+    issue = ["predict/upload", "predict/layout", "predict/dispatch"]
+    finish = ["predict/wait_device", "predict/download", "predict/cast_out"]
+    assert _names(tree[1:]) == issue * 2 + finish + issue + finish * 2
+    assert {r["parent"] for r in tree[1:]} == {tree[0]["id"]}
+    for key in ("bytes_up", "layout_bytes", "bytes_down"):
+        whole = sum(r["counts"].get(key, 0) for r in one)
+        assert whole > 0 and whole == program_span.read(
+            {}, {"root": "t/three_blocks", "count": key})
+    # rows that are no whole number of blocks: the last block is sent,
+    # coded and fetched whole, the rows it repeats with it
+    _, ragged = call("t/ragged_blocks", X[:9000])
+    by = collections.Counter()
+    for r in ragged[1:]:
+        by.update(r["counts"])
+    assert by["bytes_up"] == 3 * 4096 * 6 * 4 > 9000 * 6 * 4
+    assert by["bytes_down"] == 3 * 4096 * 4
+
+
+def test_table_budget_is_beside_the_blocks_in_flight(monkeypatch, booster,
+                                                     force_row_blocks):
+    """The same tables on the same device: over budget beside all of a
+    table's rows, within it beside the blocks that are there at once."""
+    from lightgbm_tpu.models import predictor
+    tables = predictor.build_device_tables(booster._gbdt.models, 1, 6)
+    rows, row_bytes = 1_000_000, 4 * 6 + tables.layout_row_bytes
+    monkeypatch.setattr(predictor, "_device_memory_bytes",
+                        lambda: tables.nbytes + 100_000 * row_bytes)
+    # 24 MB of rows are one block: all of them are resident
+    assert tables.resident_rows(rows) == rows and tables.over_budget(rows)
+    assert predictor.build_device_tables(booster._gbdt.models, 1, 6,
+                                         rows=rows) is None
+    force_row_blocks(tiles=2, in_flight=3)
+    assert tables.resident_rows(rows) == 3 * 2 * 4096
+    assert not tables.over_budget(rows)
+    assert predictor.build_device_tables(booster._gbdt.models, 1, 6,
+                                         rows=rows) is not None
 
 
 def test_device_stages_carry_scope_names(monkeypatch, table, booster):
