@@ -43,7 +43,7 @@ from ..models.tree import MISSING_NAN, MISSING_ZERO
 from .categorical import CatConfig, find_best_split_categorical
 from .histogram import build_histogram
 from .split import (NEG_INF, FeatureMeta, SplitHyperParams, SplitResult,
-                    find_best_split, synth_count_channel)
+                    find_best_split, root_totals, synth_count_channel)
 
 
 class GrowConfig(NamedTuple):
@@ -229,6 +229,35 @@ class _LoopState(NamedTuple):
     done: jnp.ndarray              # bool scalar
 
 
+def _root_tree(L: int, W: int, root_h, root_c) -> DeviceTree:
+    """The one-leaf tree every grower starts from: the root's hessian
+    total and in-bag row count on leaf 0, all else zero."""
+    M = max(L - 1, 1)
+    return DeviceTree(
+        num_leaves=jnp.asarray(1, jnp.int32),
+        split_feature=jnp.zeros((M,), jnp.int32),
+        threshold_bin=jnp.zeros((M,), jnp.int32),
+        default_left=jnp.zeros((M,), bool),
+        split_gain=jnp.zeros((M,), jnp.float32),
+        left_child=jnp.zeros((M,), jnp.int32),
+        right_child=jnp.zeros((M,), jnp.int32),
+        internal_value=jnp.zeros((M,), jnp.float32),
+        internal_weight=jnp.zeros((M,), jnp.float32),
+        internal_count=jnp.zeros((M,), jnp.int32),
+        # leaf 0 stays 0.0 until a split sets it: a no-split tree must be a
+        # constant-zero tree (AsConstantTree(0), gbdt.cpp:443), NOT the root
+        # output
+        leaf_value=jnp.zeros((L,), jnp.float32),
+        leaf_weight=jnp.zeros((L,), jnp.float32).at[0].set(root_h),
+        leaf_count=jnp.zeros((L,), jnp.int32).at[0].set(
+            root_c.astype(jnp.int32)),
+        split_parent_leaf=jnp.zeros((M,), jnp.int32),
+        split_is_cat=jnp.zeros((M,), bool),
+        split_cat_bitset=jnp.zeros((M, W), jnp.uint32),
+        num_waves=jnp.asarray(0, jnp.int32),
+    )
+
+
 def _empty_split_cache(L: int) -> SplitResult:
     z = jnp.zeros((L,), jnp.float32)
     return SplitResult(
@@ -261,13 +290,13 @@ def grow_tree(
 ) -> tuple[DeviceTree, jnp.ndarray]:
     """Grow one tree; returns (DeviceTree, leaf_of_row).
 
-    With `dist`, histograms and root stats are psum-reduced over the mesh data
-    axis, making every device grow the IDENTICAL tree on its row shard —
+    With `dist`, histograms (the root's totals with them) and the root
+    count are psum-reduced over the mesh data axis, making every device
+    grow the IDENTICAL tree on its row shard —
     the invariant of the reference's data-parallel learner (SURVEY.md §3.4).
     """
     F, N = X_t.shape
     L = cfg.num_leaves
-    M = max(L - 1, 1)
     B = cfg.num_bins_padded
     hp = cfg.hp
     max_depth = cfg.max_depth if cfg.max_depth > 0 else 10**9
@@ -398,45 +427,18 @@ def grow_tree(
         return res, use_cat, bits
 
     # ---- root (BeforeTrain: serial_tree_learner.cpp:292-342)
-    root_g = psum(jnp.sum(g))
-    root_h = psum(jnp.sum(h))
-    root_c = psum(jnp.sum(cnt_row))
-    root_out = jnp.asarray(
-        -jnp.sign(root_g) * jnp.maximum(jnp.abs(root_g) - hp.lambda_l1, 0.0)
-        / (root_h + hp.lambda_l2), jnp.float32)
-
     vals0 = jnp.stack([g, h], axis=0)
     hist_root = exchange(build_histogram(X_t, vals0, B, cfg.rows_per_chunk,
                                          tiers=cfg.hist_tiers,
                                          impl=cfg.hist_impl))
+    root_g, root_h, root_c, root_out = root_totals(
+        hist_root, cnt_row, hp, psum, owner=(foff == 0) if rs_on else None)
     root_split, root_is_cat, root_bitset = search(
         hist_root, root_g, root_h, root_c, root_out)
     root_split = root_split._replace(
         gain=jnp.where(max_depth >= 1, root_split.gain, NEG_INF))
 
-    tree = DeviceTree(
-        num_leaves=jnp.asarray(1, jnp.int32),
-        split_feature=jnp.zeros((M,), jnp.int32),
-        threshold_bin=jnp.zeros((M,), jnp.int32),
-        default_left=jnp.zeros((M,), bool),
-        split_gain=jnp.zeros((M,), jnp.float32),
-        left_child=jnp.zeros((M,), jnp.int32),
-        right_child=jnp.zeros((M,), jnp.int32),
-        internal_value=jnp.zeros((M,), jnp.float32),
-        internal_weight=jnp.zeros((M,), jnp.float32),
-        internal_count=jnp.zeros((M,), jnp.int32),
-        # leaf 0 stays 0.0 until a split sets it: a no-split tree must be a
-        # constant-zero tree (AsConstantTree(0), gbdt.cpp:443), NOT the root
-        # output
-        leaf_value=jnp.zeros((L,), jnp.float32),
-        leaf_weight=jnp.zeros((L,), jnp.float32).at[0].set(root_h),
-        leaf_count=jnp.zeros((L,), jnp.int32).at[0].set(
-            root_c.astype(jnp.int32)),
-        split_parent_leaf=jnp.zeros((M,), jnp.int32),
-        split_is_cat=jnp.zeros((M,), bool),
-        split_cat_bitset=jnp.zeros((M, W), jnp.uint32),
-        num_waves=jnp.asarray(0, jnp.int32),
-    )
+    tree = _root_tree(L, W, root_h, root_c)
     cache = _set_cache(_empty_split_cache(L), 0, root_split, True)
     state = _LoopState(
         tree=tree,

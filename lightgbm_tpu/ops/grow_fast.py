@@ -35,11 +35,12 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from .grow import DeviceTree, GrowConfig, _empty_split_cache, _set_cache
+from .grow import (DeviceTree, GrowConfig, _empty_split_cache, _root_tree,
+                   _set_cache)
 from .histogram import build_histogram
 from ..models.tree import MISSING_NAN, MISSING_ZERO
 from .split import (NEG_INF, FeatureMeta, SplitResult, find_best_split,
-                    synth_count_channel)
+                    root_totals, synth_count_channel)
 from .categorical import find_best_split_categorical
 
 _MIN_BUCKET = 256
@@ -94,7 +95,6 @@ def grow_tree_fast(
     """Compacted leaf-wise growth; same contract as ops/grow.py:grow_tree."""
     F, N = X_t.shape
     L = cfg.num_leaves
-    M = max(L - 1, 1)
     B = cfg.num_bins_padded
     W = cfg.cat_words
     hp = cfg.hp
@@ -126,45 +126,18 @@ def grow_tree_fast(
                                           jnp.zeros((W,), jnp.uint32))
 
     # ---- root
-    root_g = psum(jnp.sum(g))
-    root_h = psum(jnp.sum(h))
-    root_c = psum(jnp.sum(cnt_row))
-    root_out = jnp.asarray(
-        -jnp.sign(root_g) * jnp.maximum(jnp.abs(root_g) - hp.lambda_l1, 0.0)
-        / (root_h + hp.lambda_l2), jnp.float32)
-
     vals0 = jnp.stack([g, h], axis=0)
     hist_root = psum(build_histogram(X_t, vals0, B, cfg.rows_per_chunk,
                                      tiers=cfg.hist_tiers,
                                      impl=cfg.hist_impl))
+    root_g, root_h, root_c, root_out = root_totals(hist_root, cnt_row, hp,
+                                                   psum)
     root_split, root_is_cat, root_bitset = search(
         hist_root, root_g, root_h, root_c, root_out)
     root_split = root_split._replace(
         gain=jnp.where(max_depth >= 1, root_split.gain, NEG_INF))
 
-    tree = DeviceTree(
-        num_leaves=jnp.asarray(1, jnp.int32),
-        split_feature=jnp.zeros((M,), jnp.int32),
-        threshold_bin=jnp.zeros((M,), jnp.int32),
-        default_left=jnp.zeros((M,), bool),
-        split_gain=jnp.zeros((M,), jnp.float32),
-        left_child=jnp.zeros((M,), jnp.int32),
-        right_child=jnp.zeros((M,), jnp.int32),
-        internal_value=jnp.zeros((M,), jnp.float32),
-        internal_weight=jnp.zeros((M,), jnp.float32),
-        internal_count=jnp.zeros((M,), jnp.int32),
-        # leaf 0 stays 0.0 until a split sets it: a no-split tree must be a
-        # constant-zero tree (AsConstantTree(0), gbdt.cpp:443), NOT the root
-        # output
-        leaf_value=jnp.zeros((L,), jnp.float32),
-        leaf_weight=jnp.zeros((L,), jnp.float32).at[0].set(root_h),
-        leaf_count=jnp.zeros((L,), jnp.int32).at[0].set(
-            root_c.astype(jnp.int32)),
-        split_parent_leaf=jnp.zeros((M,), jnp.int32),
-        split_is_cat=jnp.zeros((M,), bool),
-        split_cat_bitset=jnp.zeros((M, W), jnp.uint32),
-        num_waves=jnp.asarray(0, jnp.int32),
-    )
+    tree = _root_tree(L, W, root_h, root_c)
     hist_cache = jnp.zeros((L, 2, F, B), jnp.float32).at[0].set(hist_root)
     state = _FastState(
         tree=tree,

@@ -73,10 +73,12 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from .grow import (DeviceTree, GrowConfig, _empty_split_cache, _set_cache)
+from .grow import (DeviceTree, GrowConfig, _empty_split_cache, _root_tree,
+                   _set_cache)
 from .histogram import build_histogram, build_histogram_slots
 from ..models.tree import MISSING_NAN, MISSING_ZERO
-from .split import NEG_INF, FeatureMeta, SplitResult, find_best_split
+from .split import (NEG_INF, FeatureMeta, SplitResult, find_best_split,
+                    leaf_output, root_totals, synth_count_channel)
 from .categorical import find_best_split_categorical
 
 
@@ -269,9 +271,6 @@ def grow_tree_wave(
     # (goss.hpp — bag indices are plain row sets), and 0/1 values stay
     # exact in the bf16 histogram contraction
     cnt_row = (in_bag > 0).astype(jnp.float32)
-    root_g = psum(jnp.sum(g))
-    root_h = psum(jnp.sum(h))
-    root_c = psum(jnp.sum(cnt_row))
 
     # Histograms carry (grad, hess) ONLY — the reference's own entry
     # layout (bin.h:40: kHistEntrySize = 2 doubles). Per-bin counts are
@@ -305,24 +304,17 @@ def grow_tree_wave(
                       -127, 127).astype(jnp.int8)
         h8 = jnp.clip(jnp.trunc(h / h_scale + uh), 0, 127).astype(jnp.int8)
         vals0 = jnp.stack([g8, h8], axis=0)              # [2, N] int8
-        ch_scale = jnp.stack([g_scale, h_scale])[:, None, None]
+        scale = jnp.stack([g_scale, h_scale])
     else:
         vals0 = jnp.stack([g, h], axis=0)                # [2, N] f32
-        ch_scale = None
+        scale = None
     C = vals0.shape[0]
 
     def to_f32(histc):
         """Descale an int32 [C, F, B] histogram (no-op for f32 mode)."""
         if quant:
-            return histc.astype(jnp.float32) * ch_scale
+            return histc.astype(jnp.float32) * scale[:, None, None]
         return histc
-
-    def with_counts(histc, count, sum_h):
-        """[2, F, B] descaled histogram -> [3, F, B] with the count
-        channel synthesized via the reference's cnt_factor
-        (split.synth_count_channel; feature_histogram.hpp:529,844,1077)."""
-        from .split import synth_count_channel
-        return synth_count_channel(histc, count, sum_h)
 
     has_mono = meta.monotone is not None
     has_inter = meta.inter_sets is not None
@@ -459,7 +451,7 @@ def grow_tree_wave(
             hist2 = hist2 + meta.bundle_mfb[None] * miss[:, :, None]
         else:
             hist2 = to_f32(hist2)
-        hist = with_counts(hist2, count, sum_h)   # [3, F, B]
+        hist = synth_count_channel(hist2, count, sum_h)   # [3, F, B]
         fmask = (sets_to_fmask(sets_row, meta_use, fmask_use)
                  if has_inter else fmask_use)
         if fmask_dyn is not None:
@@ -596,7 +588,7 @@ def grow_tree_wave(
         """Split search over the AGGREGATED voted feature columns (exact
         for voted features: global histograms + global parent stats).
         Meta arrays arrive gathered per voted feature (dynamic)."""
-        hist = with_counts(to_f32(hist2), count, sum_h)   # [3, F, B]
+        hist = synth_count_channel(to_f32(hist2), count, sum_h)
         mv = FeatureMeta(
             num_bins=mv_nb, missing_type=mv_mt, default_bin=mv_db,
             is_categorical=jnp.zeros_like(mv_nb, bool),
@@ -665,10 +657,6 @@ def grow_tree_wave(
         return lmin, lmax, rmin, rmax
 
     # ---- root
-    root_out = jnp.asarray(
-        -jnp.sign(root_g) * jnp.maximum(jnp.abs(root_g) - hp.lambda_l1, 0.0)
-        / (root_h + hp.lambda_l2), jnp.float32)
-
     # feature-parallel builds the root on its feature slice only (the
     # whole point of the learner: 1/n of the histogram work per shard)
     with jax.named_scope("train/root_histogram"):
@@ -677,6 +665,13 @@ def grow_tree_wave(
                                           tiers=cfg.hist_tiers,
                                           impl=cfg.hist_impl)
         hist_root = exchange_hist(hist_root_local, psum, 0)
+    # the exchanged root histogram is whole on every rank here (the
+    # ownership modes reduce-scatter the WAVES, not the root; a
+    # feature-parallel shard holds all rows, so its own first column, a
+    # padded all-zero-bin one included, holds every row), and with EFB
+    # its columns are still the raw bundles
+    root_g, root_h, root_c, root_out = root_totals(
+        hist_root, cnt_row, hp, psum, scale)
     root_fid = jnp.asarray(0 if has_forced else -1, jnp.int32)
     used0 = (cegb_used if has_cegb else jnp.zeros((F,), bool))
     root_kwargs = dict(
@@ -732,28 +727,7 @@ def grow_tree_wave(
     hshape = hist_cache0.shape
     hist_cache0 = hist_cache0.reshape(-1)
 
-    tree = DeviceTree(
-        num_leaves=jnp.asarray(1, jnp.int32),
-        split_feature=jnp.zeros((M,), jnp.int32),
-        threshold_bin=jnp.zeros((M,), jnp.int32),
-        default_left=jnp.zeros((M,), bool),
-        split_gain=jnp.zeros((M,), jnp.float32),
-        left_child=jnp.zeros((M,), jnp.int32),
-        right_child=jnp.zeros((M,), jnp.int32),
-        internal_value=jnp.zeros((M,), jnp.float32),
-        internal_weight=jnp.zeros((M,), jnp.float32),
-        internal_count=jnp.zeros((M,), jnp.int32),
-        # leaf 0 stays 0.0 until a split sets it: a no-split tree must be a
-        # constant-zero tree (AsConstantTree(0), gbdt.cpp:443)
-        leaf_value=jnp.zeros((L,), jnp.float32),
-        leaf_weight=jnp.zeros((L,), jnp.float32).at[0].set(root_h),
-        leaf_count=jnp.zeros((L,), jnp.int32).at[0].set(
-            root_c.astype(jnp.int32)),
-        split_parent_leaf=jnp.zeros((M,), jnp.int32),
-        split_is_cat=jnp.zeros((M,), bool),
-        split_cat_bitset=jnp.zeros((M, W), jnp.uint32),
-        num_waves=jnp.asarray(0, jnp.int32),
-    )
+    tree = _root_tree(L, W, root_h, root_c)
     empty = _empty_split_cache(L)
     state = _WaveState(
         tree=tree,
@@ -1542,7 +1516,7 @@ def grow_tree_wave(
                                        par_loc - small_loc)
                 loc_c = jnp.concatenate([loc_c_left,
                                          par_loc - loc_c_left])
-                hist3 = jax.vmap(with_counts)(hist_v, loc_c, loc_h)
+                hist3 = jax.vmap(synth_count_channel)(hist_v, loc_c, loc_h)
                 if bynode:
                     fm_vote = (bn_masks if feature_mask is None
                                else bn_masks & feature_mask[None, :])
@@ -1731,7 +1705,6 @@ def grow_tree_wave(
         # quantized leaf values with outputs from EXACT fp leaf sums —
         # segment sums over leaf_of_row via the slot kernel on a dummy
         # single-bin feature (all mass lands in bin 0)
-        from .split import threshold_l1
         dummy = jnp.zeros((1, N), jnp.uint8)
         fp2 = jnp.stack([g, h], axis=0)
         sums = []
@@ -1744,9 +1717,8 @@ def grow_tree_wave(
             sums.append(hs[:, :, 0, 0])                  # [KMAX, 2]
         sums = jnp.concatenate(sums, axis=0)[:L]
         sg, sh = sums[:, 0], sums[:, 1]
-        lv = -threshold_l1(sg, hp.lambda_l1) / (sh + hp.lambda_l2)
-        if hp.max_delta_step > 0:
-            lv = jnp.clip(lv, -hp.max_delta_step, hp.max_delta_step)
+        # path_smooth is off here: the count and the parent go unread
+        lv = leaf_output(sg, sh, hp, None, None)
         ok = (jnp.arange(L) < tree_out.num_leaves) & (sh > 0.0) \
             & (tree_out.num_leaves > 1)
         tree_out = tree_out._replace(

@@ -147,9 +147,53 @@ def synth_count_channel(hist2: jnp.ndarray, count, sum_h) -> jnp.ndarray:
     return jnp.concatenate([hist2, hist2[1:2] * cntf], axis=0)
 
 
+def _newton_step(sum_g, sum_h, hp: SplitHyperParams):
+    return -threshold_l1(sum_g, hp.lambda_l1) / (sum_h + hp.lambda_l2)
+
+
+def root_totals(hist_root: jnp.ndarray, cnt_row: jnp.ndarray,
+                hp: SplitHyperParams, psum, scale=None, owner=None):
+    """The root's (sum_g, sum_h, count, output): the ONE place that says
+    where a node's totals come from, for all three growers.
+
+    `hist_root` is the root histogram [2, columns, B] as the kernels
+    built it and the data-parallel exchange summed it, before any
+    per-feature re-slicing (EFB's bundle_expand). Every in-bag row falls
+    into exactly one bin of a storage column, so the bins of column 0 sum
+    to the node's gradient and hessian totals AS THE HISTOGRAM HOLDS
+    THEM: operands rounded to bfloat16 on the Pallas path
+    (histogram_pallas._make_W), discretized to int8 under quantized
+    gradients (int32 bins, summed exactly and then descaled by `scale`
+    [2], as GradientDiscretizer's leaf sums are), float32 on the portable
+    path. Every child's totals descend as "parent minus the histogram's
+    left sum" (_numeric_gain_map), so a root total from anywhere else (a
+    float32 sum of the unrounded rows, say) leaves its whole difference in
+    the one leaf at the end of the chain of complement children
+    (docs/PERF.md, Operands and totals).
+
+    The count is no histogram channel: it stays the exact number of
+    in-bag rows (`cnt_row` is 0/1), summed over the shards by `psum`.
+    `owner` (a traced bool) is given only where the exchange left each
+    rank a feature SLICE of the root histogram (grow.py's reduce-scatter
+    ownership): column 0 is whole on the rank whose slice starts at
+    feature 0, and its totals ride to the others in the count's psum;
+    x + 0 + ... + 0 is exact, so every rank holds the owner's bits."""
+    tot = jnp.sum(hist_root[:, 0, :], axis=-1)                   # [2]
+    if scale is not None:
+        tot = tot.astype(jnp.float32) * scale
+    cnt = jnp.sum(cnt_row)
+    if owner is None:
+        root_c = psum(cnt)
+    else:
+        shared = psum(jnp.append(jnp.where(owner, tot, 0.0), cnt))
+        tot, root_c = shared[:2], shared[2]
+    root_out = jnp.asarray(_newton_step(tot[0], tot[1], hp), jnp.float32)
+    return tot[0], tot[1], root_c, root_out
+
+
 def leaf_output(sum_g, sum_h, hp: SplitHyperParams, num_data, parent_output):
     """reference: CalculateSplittedLeafOutput (feature_histogram.hpp:718)."""
-    ret = -threshold_l1(sum_g, hp.lambda_l1) / (sum_h + hp.lambda_l2)
+    ret = _newton_step(sum_g, sum_h, hp)
     if hp.max_delta_step > 0:
         ret = jnp.clip(ret, -hp.max_delta_step, hp.max_delta_step)
     if hp.path_smooth > 1e-15:
