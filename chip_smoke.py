@@ -166,7 +166,7 @@ def _custom_calls_in_step(gbdt) -> int:
     F = len(gbdt.mappers)
     g = jnp.zeros((n,), jnp.float32)
     args = (gbdt.X_t, g, g, jnp.ones((n,), jnp.float32), gbdt.scores[0],
-            jnp.float32(0.1), jnp.ones((F,), bool), jnp.int32(0))
+            jnp.float32(0.1), jnp.ones((F,), bool), jnp.int32(0), gbdt.meta)
     if gbdt.use_dist:
         lowered = gbdt._train_tree.lower(*args)
     else:

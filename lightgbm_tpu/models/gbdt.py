@@ -841,8 +841,13 @@ class GBDT:
         return local[k, :self._local_rows]
 
     def _build_jit_fns(self) -> None:
+        """The trainer's jitted programs. None of them closes over an
+        array derived from the data: the dataset's per-feature facts
+        (`self.meta`) are an ARGUMENT of every one, so the lowered text,
+        and with it the persistent compile cache's key, is a function of
+        shapes, `GrowConfig` and the None-pattern of `FeatureMeta` alone
+        (docs/PERF.md §7; tests/test_compile_reuse.py holds it)."""
         cfg_static = self.grow_cfg
-        meta = self.meta
 
         if self.grower in ("wave", "wave_exact"):
             from ..ops.grow_wave import grow_tree_wave as grow_fn
@@ -855,14 +860,14 @@ class GBDT:
         if self.use_dist:
             from ..parallel import build_data_parallel_train_fn
             self._train_tree = build_data_parallel_train_fn(
-                self.mesh, meta, cfg_static, grow_fn=grow_fn,
+                self.mesh, cfg_static, grow_fn=grow_fn,
                 replicate_rows=self._feat_par)
         else:
             cegb_on = self._cegb_on
 
             @jax.jit
             def train_tree(X_t, grad, hess, in_bag, scores_k, lr,
-                           feat_mask, seed, used):
+                           feat_mask, seed, meta, used):
                 kw = dict(feature_mask=feat_mask)
                 if takes_seed:
                     kw["rng_seed"] = seed
@@ -888,6 +893,9 @@ class GBDT:
             self._train_tree_core = train_tree
 
             def train_tree_wrap(*args):
+                # `_cegb_used` is None unless CEGB is on, and CEGB never
+                # trains in a scan (can_batch_iters): the state is read
+                # here per call, on the host, and never at trace time
                 tree, lor, scores, used = train_tree(*args,
                                                      self._cegb_used)
                 if cegb_on:
@@ -1249,7 +1257,7 @@ class GBDT:
             new_scores, new_vscores, tree_stack, mvals = scan_fn(
                 self.X_t, self.scores, self.label_dev, self.weight_dev,
                 in_bag0, jnp.float32(self.shrinkage_rate),
-                jnp.int32(self.iter), jnp.int32(n), masks_dev,
+                jnp.int32(self.iter), jnp.int32(n), masks_dev, self.meta,
                 tuple(self._valid_Xt),
                 tuple(tuple(m) for m in self._valid_meta),
                 tuple(self._valid_scores),
@@ -1316,7 +1324,7 @@ class GBDT:
 
         @jax.jit
         def scan_fn(X_t, scores0, label, weight, in_bag0, lr, start_iter,
-                    n_active, masks, vXts, vmetas, vscores0, vlabels,
+                    n_active, masks, meta, vXts, vmetas, vscores0, vlabels,
                     vweights, vsumw):
             def step(carry, xs):
                 scores, vscores = carry
@@ -1346,7 +1354,7 @@ class GBDT:
                     tree, _, ns = train_tree(
                         X_t, g[k], h[k],
                         bag if bag.ndim == 1 else bag[k],
-                        new_scores[k], lr, mask, seed)
+                        new_scores[k], lr, mask, seed, meta)
                     new_scores = new_scores.at[k].set(ns)
                     trees.append(tree)
                     for vi in range(n_valid):
@@ -1561,14 +1569,14 @@ class GBDT:
         retry after a transient fault cannot change the trained model."""
         if self._fault_plan is None and self.config.step_max_retries == 0:
             return self._train_tree(X_t, g, h, in_bag, scores_k, lr,
-                                    feat_mask, seed)
+                                    feat_mask, seed, self.meta)
         attempt = 0
         while True:
             try:
                 if self._fault_plan is not None:
                     self._fault_plan.maybe_fail_collective(self.iter)
                 return self._train_tree(X_t, g, h, in_bag, scores_k, lr,
-                                        feat_mask, seed)
+                                        feat_mask, seed, self.meta)
             except Exception as e:
                 from ..parallel import is_collective_error
                 if is_collective_error(e):

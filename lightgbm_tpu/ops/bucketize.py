@@ -49,6 +49,7 @@ parity suites in tests/test_predict_binned.py run there).
 
 from __future__ import annotations
 
+import functools
 import os
 from typing import List, NamedTuple, Optional, Sequence
 
@@ -339,8 +340,6 @@ def _bucketize_pallas(X, t: DeviceBinTable):
     """X [n, F] f32 -> [n, F] u8 via the Pallas kernel (grid over row
     tiles; the bin table is one VMEM-resident block: F_pad*B*8 bytes,
     ~256 KiB at 256 features x 128 bins — docs/PERF.md §8)."""
-    import functools
-
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -386,11 +385,14 @@ def _bucketize_xla(X, t: DeviceBinTable):
 
     F = t.num_features
     F_pad, B = t.table.shape
+    # a packed host table folds in as constants (numpy here); a traced
+    # one (bin_rows_device passes the table as arguments) stays traced
+    xp = np if isinstance(t.table, np.ndarray) else jnp
     # NaN pads (categorical rows) lift to +inf so every row is sorted
     tabc = jnp.asarray(
-        np.where(np.isnan(t.table), np.inf, t.table))[:F]   # [F, B]
+        xp.where(xp.isnan(t.table), xp.inf, t.table))[:F]   # [F, B]
     cv = jnp.asarray(t.cat_val)[:F]
-    meta = np.asarray(t.meta)[:F]
+    meta = t.meta[:F]
     is_cat = jnp.asarray(meta[None, :, _M_IS_CAT])          # [1, F]
     clamp = jnp.asarray(meta[None, :, _M_CLAMP])
     nan_bin = jnp.asarray(meta[None, :, _M_NAN_BIN])
@@ -481,6 +483,26 @@ def bucketize_rows_stacked(X, t: DeviceBinTable, tid, *,
 # ----------------------------------------------------------------------
 # host-side convenience: chunked ingest binning
 # ----------------------------------------------------------------------
+@functools.lru_cache(maxsize=None)
+def _bin_rows_jit():
+    """The ingest program: ONE jitted function of (rows, table, cat_val,
+    meta) for the process (jax imported lazily, as everywhere in this
+    module). The bin bounds are data: as arguments they stay out of the
+    lowered text, so the second dataset of a shape reuses the first's
+    executable, in the process and through the persistent compile cache
+    (tests/test_compile_reuse.py). The serving programs fold their
+    table in on purpose and call ``bucketize_rows`` with the packed
+    table itself."""
+    import jax
+
+    def bin_rows(Xc, table, cat_val, meta):
+        return bucketize_rows(Xc, DeviceBinTable(
+            table, cat_val, meta, num_features=Xc.shape[1],
+            B=table.shape[1], mode="train"))
+
+    return jax.jit(bin_rows)
+
+
 def bin_rows_device(X: np.ndarray, t: DeviceBinTable,
                     chunk: int = 65536) -> np.ndarray:
     """Bin a host matrix through the device table in fixed-size padded
@@ -492,7 +514,8 @@ def bin_rows_device(X: np.ndarray, t: DeviceBinTable,
     n = X.shape[0]
     chunk = max(min(int(chunk), max(_round_up(n, _ROW_TILE), _ROW_TILE)),
                 _ROW_TILE)
-    fn = jax.jit(lambda Xc: bucketize_rows(Xc, t))
+    fn = _bin_rows_jit()
+    table = jax.device_put((t.table, t.cat_val, t.meta))   # once a call
     out = np.empty((n, t.num_features), np.uint8)
     buf = np.zeros((chunk, t.num_features), np.float32)
     for c0 in range(0, n, chunk):
@@ -501,5 +524,5 @@ def bin_rows_device(X: np.ndarray, t: DeviceBinTable,
         buf[:m] = X[c0:c1, :t.num_features]
         if m < chunk:
             buf[m:] = 0.0
-        out[c0:c1] = np.asarray(jax.device_get(fn(buf)))[:m]
+        out[c0:c1] = np.asarray(jax.device_get(fn(buf, *table)))[:m]
     return out

@@ -29,7 +29,6 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..ops.grow import GrowConfig, grow_tree
-from ..ops.split import FeatureMeta
 from .context import DATA_AXIS, DistContext
 
 
@@ -55,24 +54,27 @@ def pad_rows_to(n: int, num_shards: int, multiple: int = 0) -> int:
 
 
 def build_data_parallel_train_fn(mesh: jax.sharding.Mesh,
-                                 meta: FeatureMeta,
                                  cfg: GrowConfig,
                                  grow_fn=grow_tree,
                                  replicate_rows: bool = False):
     """Returns jit(train_step) with the same signature as the serial
     `_train_tree` in models/gbdt.py:
 
-        (X_t [F,N], grad [N], hess [N], in_bag [N], scores_k [N], lr, mask[F])
+        (X_t [F,N], grad [N], hess [N], in_bag [N], scores_k [N], lr, mask[F],
+         seed, meta: FeatureMeta)
         -> (DeviceTree replicated, leaf_of_row [N], new_scores [N])
 
     N must be divisible by the mesh's data-axis size (pad with in_bag == 0
     rows via `pad_rows_to`). `grow_fn` is either the masked grower
-    (ops/grow.py) or the compacted one (ops/grow_fast.py).
+    (ops/grow.py) or the compacted one (ops/grow_fast.py). `meta`, the
+    dataset's per-feature facts, is a replicated argument like the
+    serial step's and never a constant of the program (models/gbdt.py
+    `_build_jit_fns`).
     """
     dist = DistContext(DATA_AXIS)
     takes_seed = "rng_seed" in inspect.signature(grow_fn).parameters
 
-    def step(X_t, grad, hess, in_bag, scores_k, lr, feat_mask, seed):
+    def step(X_t, grad, hess, in_bag, scores_k, lr, feat_mask, seed, meta):
         kw = dict(feature_mask=feat_mask, dist=dist)
         if takes_seed:
             kw["rng_seed"] = seed
@@ -90,7 +92,7 @@ def build_data_parallel_train_fn(mesh: jax.sharding.Mesh,
     sharded = jax.shard_map(
         step, mesh=mesh,
         in_specs=((P() if replicate_rows else P(None, DATA_AXIS)),
-                  row, row, row, row, rep, rep, rep),
+                  row, row, row, row, rep, rep, rep, rep),
         out_specs=(rep, row, row),
         check_vma=False)
     return jax.jit(sharded)

@@ -172,11 +172,14 @@ def probe_strategies(X_t, meta, cfg, candidates: Sequence[str],
         grow_fn, takes_seed = _grower_fn(name)
         cfg_c = cfg._replace(wave_exact=(name == "wave_exact"))
 
-        def run(X, gg, hh, bb, _fn=grow_fn, _cfg=cfg_c, _seed=takes_seed):
+        def run(X, gg, hh, bb, mt, _fn=grow_fn, _cfg=cfg_c,
+                _seed=takes_seed):
             kw = {"rng_seed": jnp.int32(seed)} if _seed else {}
-            return _fn(X, gg, hh, bb, meta, _cfg, **kw)
+            return _fn(X, gg, hh, bb, mt, _cfg, **kw)
 
-        timings[name] = _best_of_2(jax.jit(run), (Xs, g, h, bag), timer)
+        # meta is an argument, as in the trainer's own programs
+        timings[name] = _best_of_2(jax.jit(run), (Xs, g, h, bag, meta),
+                                   timer)
     return timings
 
 
@@ -394,7 +397,7 @@ def probe_binning(mappers, *, probe_rows: int = 16384, seed: int = 0,
     device-packable."""
     import numpy as np
 
-    from ..ops.bucketize import (BinningUnavailable, bucketize_rows,
+    from ..ops.bucketize import (BinningUnavailable, _bin_rows_jit,
                                  pack_bin_table)
 
     try:
@@ -420,9 +423,8 @@ def probe_binning(mappers, *, probe_rows: int = 16384, seed: int = 0,
         host_arm()
         best = min(best, timer() - t0)
     timings["host"] = best
-    import jax
-    timings["device"] = _best_of_2(
-        jax.jit(lambda Xc: bucketize_rows(Xc, table)), (X,), timer)
+    timings["device"] = _best_of_2(       # the ingest program itself
+        _bin_rows_jit(), (X, table.table, table.cat_val, table.meta), timer)
     return timings
 
 

@@ -13,7 +13,11 @@ Two layers live here:
    snapshots it (all spans of one call share their ``root``). Backend
    compilations are counted where they happen: one ``jax.monitoring``
    listener adds ``compiles`` and ``compile_s`` to the innermost open
-   span of the compiling thread.
+   span of the compiling thread, and a second says which of them the
+   persistent compile cache answered (``cache_hits``) and which it had
+   to compile and store (``cache_misses``): a trainer's program that
+   misses on a second dataset of a known shape holds data as a constant
+   (docs/PERF.md §7).
    The recorder is on by default at call / chunk / ingest-phase
    granularity; ``set_spans(False)`` turns recording and annotation off.
  * ``StageProfiler`` — the operator's fenced per-iteration profile
@@ -56,6 +60,9 @@ SPAN_PREFIX = "lgbm:"           # a span's name in a profiler trace
 # of passes: the benchmark reads them after its window
 SPAN_RING_SIZE = 16384
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# the persistent compile cache's own events -> the count each adds
+CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "cache_hits",
+                "/jax/compilation_cache/cache_misses": "cache_misses"}
 
 
 def device_barrier() -> None:
@@ -183,6 +190,12 @@ def _on_duration_event(event: str, secs: float, **_: Any) -> None:
         _RECORDER.on_compile(secs)
 
 
+def _on_event(event: str, **_: Any) -> None:
+    name = CACHE_EVENTS.get(event)
+    if name is not None:
+        _RECORDER.count(**{name: 1})
+
+
 def span(name: str, **counts: float):
     """``with span("predict/upload", bytes_up=n): ...`` on the process's
     recorder. Names are the contract the benchmark's readers match
@@ -192,6 +205,7 @@ def span(name: str, **counts: float):
         import jax.monitoring
         jax.monitoring.register_event_duration_secs_listener(
             _on_duration_event)
+        jax.monitoring.register_event_listener(_on_event)
         _compile_listener_on = True
     return _RECORDER.span(name, **counts)
 
@@ -579,13 +593,13 @@ def probe_stage_breakdown(X_t, grad, hess, meta, cfg,
             cnt = jnp.float32(m)
             hp = cfg.hp
 
-            def split_probe(hh, gs, hs, c):
+            def split_probe(hh, gs, hs, c, mt):
                 h3 = S.synth_count_channel(hh, c, hs)
                 return S.find_best_split(h3, gs, hs, c, jnp.float32(0.0),
-                                         meta, hp)
+                                         mt, hp)
 
             out["split_search_s"] = round(
-                timed(split_probe, hist2, gsum, hsum, cnt), 6)
+                timed(split_probe, hist2, gsum, hsum, cnt, meta), 6)
         except Exception:
             pass
 

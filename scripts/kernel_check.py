@@ -360,6 +360,9 @@ def aot_train_steps(topo):
 
     def sd(shape, dtype, sh=s0):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    def meta_of(g, sh=s0):           # FeatureMeta is an argument: shapes
+        return jax.tree.map(lambda a: sd(a.shape, a.dtype, sh), g.meta)
     try:
         for max_bin in (63, 255):
             p = dict(objective="binary", num_leaves=255,
@@ -374,20 +377,21 @@ def aot_train_steps(topo):
                     sd((rows,), jnp.float32), None, sd((rows,), jnp.float32),
                     sd((), jnp.float32), sd((), jnp.int32),
                     sd((), jnp.int32), sd((n_pad, F), bool),
+                    meta_of(g),
                     (), (), (), (), (), ())
             if max_bin == 63:
                 row = NamedSharding(mesh, P(DATA_AXIS))
                 rep = NamedSharding(mesh, P())
                 fn = build_data_parallel_train_fn(
-                    mesh, g.meta, g.grow_cfg._replace(n_shards=4),
+                    mesh, g.grow_cfg._replace(n_shards=4),
                     grow_fn=grow_tree_wave)
-                yield "4-chip tree_learner=data step", lambda fn=fn: \
+                yield "4-chip tree_learner=data step", lambda fn=fn, g=g: \
                     fn.lower(
                         sd((F, rows), jnp.uint8,
                            NamedSharding(mesh, P(None, DATA_AXIS))),
                         *(sd((rows,), jnp.float32, row) for _ in range(4)),
                         sd((), jnp.float32, rep), sd((F,), bool, rep),
-                        sd((), jnp.int32, rep))
+                        sd((), jnp.int32, rep), meta_of(g, rep))
     finally:
         jax.default_backend = real_backend
 

@@ -236,6 +236,39 @@ def test_compile_is_counted_on_the_open_span():
     assert P.compiles_outside_spans() > outside
 
 
+@pytest.mark.parametrize("count", ["cache_hits", "cache_misses"])
+def test_cache_events_are_counted_on_the_open_span(count):
+    """The persistent compile cache's own events land where `compiles`
+    goes (driven through jax.monitoring: no real cache on the CPU)."""
+    event = "/jax/compilation_cache/" + count
+    assert P.CACHE_EVENTS[event] == count
+    with P.span("t/cache"):
+        with P.span("t/cache/inner"):
+            jax.monitoring.record_event(event)
+            jax.monitoring.record_event(event)
+            jax.monitoring.record_event(
+                "/jax/compilation_cache/compile_requests_use_cache")
+        seen = threading.Thread(
+            target=jax.monitoring.record_event, args=(event,))
+        seen.start()                   # another thread has no open span
+        seen.join()
+    root, inner = _last_root("t/cache")
+    assert inner["counts"] == {count: 2}
+    assert root["counts"] == {}
+
+
+def test_cache_events_go_nowhere_when_spans_are_off():
+    event = "/jax/compilation_cache/cache_misses"
+    with P.span("t/listener"):         # the listeners are registered
+        pass
+    P.set_spans(False)
+    before = P.spans()
+    with P.span("t/cache_off"):
+        jax.monitoring.record_event(event)
+    jax.monitoring.record_event(event)             # and outside any span
+    assert P.spans() == before         # no record, and no count moved
+
+
 def test_span_never_fences_the_device(monkeypatch):
     """No span of the primitive waits for the device."""
     def refuse():
@@ -380,7 +413,7 @@ def test_device_stages_carry_scope_names(monkeypatch, table, booster):
     text = scan.lower(
         g.X_t, g.scores, g.label_dev, g.weight_dev,
         jnp.ones((g._host_pad,), jnp.float32), jnp.float32(0.1),
-        jnp.int32(0), jnp.int32(2), jnp.ones((2, 6), bool),
+        jnp.int32(0), jnp.int32(2), jnp.ones((2, 6), bool), g.meta,
         (), (), (), (), (), ()).as_text(debug_info=True)
     for stage in ("gradients", "root_histogram", "wave_pass",
                   "split_search", "apply", "score_update"):
