@@ -18,8 +18,8 @@ import numpy as np
 
 MISSING = 1.0e300     # a number that could not be taken: over any limit
 
-NAMES = ("bin_mismatch", "split_gain_gap", "leaf_value_gap", "count_gap",
-         "score_gap", "loss_gap")
+NAMES = ("bin_edges_bad", "bin_mass_gap", "bin_mismatch", "split_gain_gap",
+         "leaf_value_gap", "count_gap", "score_gap", "loss_gap")
 
 
 def logloss(scores: np.ndarray, y: np.ndarray) -> float:
@@ -27,10 +27,29 @@ def logloss(scores: np.ndarray, y: np.ndarray) -> float:
     return float(np.mean(np.logaddexp(0.0, s) - y * s))
 
 
+def edge_numbers(edges: Sequence[np.ndarray], bin_count: np.ndarray,
+                 rows: int, max_bin: int) -> Dict[str, float]:
+    """``edges``: per column the inclusive upper bounds, the last +inf.
+    ``bin_count`` [F, B]: the raw rows of each column that the reference
+    counted into each bin. ``bin_edges_bad``: columns with more than
+    ``max_bin`` bins, a bound that does not rise, or a last bound that is
+    not +inf. ``bin_mass_gap``: the heaviest bin's share of the rows times
+    ``max_bin``, less 1, worst column (0 where every column's ``max_bin``
+    bins hold the same; 1 where half as many bins do)."""
+    bad = sum(1 for e in edges
+              if not 1 <= len(e) <= max_bin or np.any(np.diff(e) <= 0)
+              or not np.isposinf(e[-1]))
+    heaviest = float(np.max(bin_count)) / max(rows, 1)
+    return {"bin_edges_bad": float(bad),
+            "bin_mass_gap": heaviest * max_bin - 1.0}
+
+
 def program_view(ref: Dict[str, Any], scores: np.ndarray, y: np.ndarray,
-                 bin_mismatch: int) -> Dict[str, Any]:
+                 bin_mismatch: int, edges: Dict[str, float]
+                 ) -> Dict[str, Any]:
     """From the drained trees (already parsed into the reference's
-    ``TreeTables``) and the device scores after those trees."""
+    ``TreeTables``), the device scores after those trees and ingest's
+    edges as ``edge_numbers`` read them."""
     trees = []
     for rec in ref["trees"]:
         tt = rec["tables"]
@@ -40,7 +59,7 @@ def program_view(ref: Dict[str, Any], scores: np.ndarray, y: np.ndarray,
             "leaf_value": tt.prog_lval, "leaf_count": tt.prog_lcount,
             "node_count": tt.prog_icount})
     return {"trees": trees, "scores": scores, "loss": logloss(scores, y),
-            "bin_mismatch": int(bin_mismatch)}
+            "bin_mismatch": int(bin_mismatch), "edges": edges}
 
 
 def control_view(low: Dict[str, Any], rows: int) -> Dict[str, Any]:
@@ -53,8 +72,10 @@ def control_view(low: Dict[str, Any], rows: int) -> Dict[str, Any]:
                       "leaf_value": rec["leaf_value"],
                       "leaf_count": rec["leaf_count"],
                       "node_count": rec["node_count"]})
+    # the control rounds operands, not bins: it bins as the reference
     return {"trees": trees, "scores": host_scores(low, rows),
-            "loss": low["loss_after"], "bin_mismatch": 0}
+            "loss": low["loss_after"], "bin_mismatch": 0,
+            "edges": {"bin_edges_bad": 0.0, "bin_mass_gap": 0.0}}
 
 
 def host_scores(followed: Dict[str, Any], rows: int) -> np.ndarray:
@@ -96,7 +117,7 @@ def numbers(ref: Dict[str, Any], view: Dict[str, Any], rows: int
     view["rows_off"] = {f"over_{k:g}_moves": int(np.sum(dev > k * moved))
                         for k in (0.01, 0.1, 1.0, 10.0)}
     loss_gap = abs(view["loss"] - ref["loss_after"]) / ref["loss_after"]
-    return {"bin_mismatch": float(view["bin_mismatch"]),
+    return {**view["edges"], "bin_mismatch": float(view["bin_mismatch"]),
             "split_gain_gap": gain_gap, "leaf_value_gap": leaf_gap,
             "count_gap": count_gap, "score_gap": score_gap,
             "loss_gap": float(loss_gap)}

@@ -8,8 +8,12 @@ have passed. Every chunk ends on ``block_until_ready`` of the scores.
 
 After the window the device scores and trees of that first chunk are
 held against the plain reference (bench/reference/gbdt_ref.py) at the
-cell's full size: device binning, gradients, every split, every leaf
-output and count, the score update and the log-loss.
+cell's full size: device binning, gradients, the splits of the first
+``hist_trees`` trees, every leaf output and count, the score update and
+the log-loss. The reference bins with ingest's own edges (the binning
+sample is the program's draw), so the edges are held beside it to what
+the configuration states (compare.edge_numbers): at most ``max_bin``
+rising bounds a column, no bin much heavier than its even share.
 """
 
 from __future__ import annotations
@@ -60,6 +64,9 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
     rows, chunk = int(data_spec["rows"]), int(job["chunk_iters"])
     data_spec.setdefault("cols", int(config["published"]["features"]))
     params = dict(config["params"], verbose=-1)
+    stated = dict(params)         # what the reference and the edges are held to
+    if tamper is not None and hasattr(tamper, "params"):
+        params = tamper.params(params)
 
     t = time.perf_counter()
     X, y = datagen.make(seed, data_spec, threads=int(job.get("threads", 8)))
@@ -83,6 +90,14 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
     log(f"ingest: construct {facts['dataset_construct_s']:.2f} s, booster "
         f"{facts['booster_init_s']:.2f} s, binned on "
         f"{getattr(ds._handle, 'binned_on', '?')}")
+    # which phase of ingest took the time (some runs read 95 s for 57)
+    from readers import program_span
+    for root, kids in program_span.trees(program_span.records() or [],
+                                         "dataset/construct"):
+        log("ingest spans: " + ", ".join(
+            f"{k['name'].split('/')[-1]} "
+            f"{(k['end_ns'] - k['start_ns']) / 1e9:.2f} s"
+            for k in kids if k["parent"] == root["id"]))
     if tamper is not None and hasattr(tamper, "after_init"):
         tamper.after_init(booster)
 
@@ -153,7 +168,7 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
     # as soon as its bins have been compared
     t = time.perf_counter()
     chk = dict(job.get("check", {}))
-    fol = gbdt_ref.Follower(edges, params, sub=int(chk.get("sub", 16384)),
+    fol = gbdt_ref.Follower(edges, stated, sub=int(chk.get("sub", 16384)),
                             upload_subs=int(chk.get("upload_subs", 64)))
     X_t = g.X_t
     mismatch = fol.load_rows(X, y, program_bins=lambda lo, hi: X_t[:, lo:hi])
@@ -162,7 +177,9 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
     hist_trees = int(chk.get("hist_trees", 2))
     ref = fol.follow(first_trees, hist_trees,
                      str(config.get("histogram_operand_dtype", "float32")))
-    view = compare.program_view(ref, first_scores, y, mismatch)
+    view = compare.program_view(
+        ref, first_scores, y, mismatch, compare.edge_numbers(
+            edges, fol.bin_count, rows, int(stated["max_bin"])))
     nums = compare.numbers(ref, view, rows)
     verdict = compare.judge(nums, cell.get("limits", {}))
     facts["reference_s"] = time.perf_counter() - t

@@ -10,8 +10,10 @@ float32 with exact products and compensated sums.
 
 What it is given: the raw float32 rows and labels the harness made from
 the seed, the configuration's parameters, the bin edges that ingest
-produced (they define the model's thresholds, as a vocabulary would),
-and the trees the timed path drained. It *follows* those trees: for every
+produced (they define the model's thresholds, as a vocabulary would;
+what they are held to themselves is compare.edge_numbers, from the
+per-bin row counts this file takes while it bins), and the trees the
+timed path drained. It *follows* those trees: for every
 tree it recomputes, from its own scores, the gradients, the rows of every
 node (by its own binning of the raw rows and its own walk), the histogram
 of every node, what the best split of every node is, and every leaf's
@@ -189,6 +191,17 @@ def _count_mismatch(a, b):
     return jnp.sum((a != b).astype(jnp.int32))
 
 
+@functools.partial(jax.jit, static_argnames=("n_bins",))
+def _bin_counts(bins, valid, n_bins: int):
+    """bins [F32, R] uint8, valid [R] -> [n_bins, F32] int32: the rows of
+    each feature that fell into each bin."""
+    m = valid > 0
+    return jax.lax.map(
+        lambda b: jnp.sum(((bins == b) & m[None, :]).astype(jnp.int32),
+                          axis=1),
+        jnp.arange(n_bins, dtype=jnp.uint8))
+
+
 def _leaf_onehot(bins_blk, featsel, thr_col, P, plen):
     """bins_blk [F32, R] uint8 -> [L, R] bool: the leaf each row reaches."""
     cols = jax.lax.dot_general(
@@ -297,15 +310,17 @@ class Follower:
         self.blocks: List[Dict[str, Any]] = []
         self.rows = 0
         self.pos = 0.0
+        self.bin_count = np.zeros((self.n_feat, self.n_bins), np.int64)
         self._edges_dev = None
 
     # -- rows ---------------------------------------------------------
     def load_rows(self, X: np.ndarray, y: np.ndarray,
                   program_bins=None) -> int:
         """Upload the raw rows block by block, bin them, keep bins, label
-        and validity on the device. ``program_bins(lo, hi)`` returns the
-        program's device bins [F?, hi-lo] for the same rows; the count of
-        cells that differ is returned."""
+        and validity on the device, and count each feature's rows bin by
+        bin (``bin_count``). ``program_bins(lo, hi)`` returns the program's
+        device bins [F?, hi-lo] for the same rows; the count of cells that
+        differ is returned."""
         packed = pack_edges(self.edges)
         self._edges_dev = jnp.asarray(packed)
         n, F = X.shape
@@ -326,12 +341,15 @@ class Follower:
             valid[:k] = 1.0
             bins = _bin_block(jnp.asarray(xb), self._edges_dev,
                               n_bounds=packed.shape[1])
+            valid_d = jnp.asarray(valid)
+            self.bin_count += np.asarray(_bin_counts(
+                bins, valid_d, n_bins=self.n_bins), np.int64).T[:F]
             if program_bins is not None:
                 pb = program_bins(lo, hi)
                 mismatch += int(_count_mismatch(
                     bins[:F, :k], pb[:F].astype(jnp.uint8)))
             self.blocks.append({"bins": bins, "y": jnp.asarray(yb),
-                                "valid": jnp.asarray(valid), "k": k})
+                                "valid": valid_d, "k": k})
         return mismatch
 
     # -- follow -------------------------------------------------------

@@ -40,6 +40,16 @@ class HalfTheBatch:
         g._in_bag_ones = g._in_bag_dev = half
 
 
+class CoarseBins:
+    """Ingest at the next narrower kernel width: the program is handed
+    ``max_bin`` 31 where the configuration states 63, so every bin holds
+    twice its share of the rows. The trees are sound trees of those
+    bins; only the edges' own numbers see it."""
+
+    def params(self, params):
+        return dict(params, max_bin=31)
+
+
 class LeafAltered:
     """An answer altered where it is produced: one leaf output of the
     second tree moved by a hundredth of itself."""
@@ -63,7 +73,7 @@ def test_sound_run_is_correct():
 
 
 @pytest.mark.parametrize("fault", [StateUnchanged, HalfTheBatch,
-                                   LeafAltered])
+                                   CoarseBins, LeafAltered])
 def test_fault_reads_not_correct(fault):
     res = _run(fault())
     over = [k for k, (v, lim) in res["compared"].items() if v > lim]

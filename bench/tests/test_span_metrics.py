@@ -54,3 +54,19 @@ def test_traced_run_prints_the_forest_metrics():
                  "score_wait_device_ms_per_pass", "score_idle_named_pct",
                  "score_idle_in_casts_pct"):
         assert name not in m, name
+
+
+def test_training_cell_reads_its_set_up_from_the_same_spans():
+    # higgs28-b63.train lists forest_construct_s, forest_init_s and
+    # forest_compile_s: the spans around the calls the kind also clocks
+    from conftest import TOY as TRAIN_TOY
+    from lightgbm_tpu.runtime import profiler
+    profiler._RECORDER.ring.clear()
+    res = harness.run_cell("higgs28-b63.train", 2**31 + 79, 0.1, True,
+                           require_chip=False, overrides=TRAIN_TOY)
+    assert res["correct"], res["compared"]
+    m, info = res["metrics"], res["info"]
+    assert 0.0 < m["forest_construct_s"]["value"] \
+        <= info["dataset_construct_s"]
+    assert 0.0 < m["forest_init_s"]["value"] <= info["booster_init_s"]
+    assert m["forest_compile_s"]["value"] >= 0.0
