@@ -652,7 +652,8 @@ class Booster:
         data.construct()
         metrics = [m for m in (create_metric(n, self._config)
                                for n in self._metric_names) if m is not None]
-        self._gbdt.add_valid_dataset(data._handle, name, metrics)
+        with span("booster/add_valid", rows=data._handle.num_data):
+            self._gbdt.add_valid_dataset(data._handle, name, metrics)
         self._valid_metrics.append(metrics)
         self.name_valid_sets.append(name)
         return self
@@ -669,7 +670,7 @@ class Booster:
                                              np.asarray(hess))
         return self._gbdt.train_one_iter()
 
-    def update_batch(self, n: int, chunk: Optional[int] = None) -> None:
+    def update_batch(self, n: int, chunk: Optional[int] = None):
         """Run `n` boosting iterations with whole-chunk device scans (no
         host round-trip per iteration) when semantics allow, else fall
         back to per-iteration updates. TPU-native extension; the
@@ -678,13 +679,25 @@ class Booster:
 
         Tail iterations (n % chunk) run through the SAME compiled scan,
         padded to the chunk size with inert steps, so a single executable
-        covers every chunk regardless of n (docs/PERF.md §7)."""
+        covers every chunk regardless of n (docs/PERF.md §7).
+
+        Returns what the scans computed of the valid sets' metrics: a
+        device array [iterations run in a scan, M], one row a tree, the
+        columns under ``batched_eval_layout()``'s names; None where no
+        valid metric rides in the scan (or no iteration ran in one)."""
         if self._gbdt._stopped:
-            return
+            return None
         if chunk is None:
             chunk = self._config.batched_chunk_size
         done = 0
         chunks_done = 0
+        evals = []
+
+        def results():
+            if not evals:
+                return None
+            import jax.numpy as jnp
+            return evals[0] if len(evals) == 1 else jnp.concatenate(evals)
         if self._gbdt.can_batch_iters(min(n, chunk)):
             n_chunks = (n + chunk - 1) // chunk
             while done < n:
@@ -693,7 +706,9 @@ class Booster:
                     # a host-mode resample falls inside THIS chunk's
                     # window; finish the remainder per-iteration
                     break
-                self._gbdt.train_iters_batched(step, n_pad=chunk)
+                mvals = self._gbdt.train_iters_batched(step, n_pad=chunk)
+                if mvals is not None:
+                    evals.append(mvals)
                 done += step
                 chunks_done += 1
                 # amortized no-more-splits check (one sync) at power-of-2
@@ -706,10 +721,17 @@ class Booster:
                         and (chunks_done & (chunks_done - 1)) == 0 \
                         and self._gbdt._check_stopped():
                     self._gbdt._stopped = True
-                    return
+                    return results()
         for _ in range(n - done):
             if self.update():
                 break
+        return results()
+
+    def batched_eval_layout(self):
+        """(valid_name, result_name, higher_better) per column of what
+        ``update_batch`` returns; None where some valid metric has no
+        device analog."""
+        return self._gbdt.batched_eval_layout()
 
     def __inner_raw_score(self) -> np.ndarray:
         import jax

@@ -69,12 +69,27 @@ class Metric:
         multi_error@k differs from the class-level name."""
         return self.name
 
+    def result_names(self) -> List[str]:
+        """Names of everything eval() reports, in its order: one column
+        each of the in-scan metric stack."""
+        return [self.result_name()]
+
     def device_eval_fn(self, objective) -> Optional[Callable]:
         """Traceable `fn(score, label, weight, sum_weights) -> f32 scalar`
         evaluating this metric on device inside a scan body, or None when
         no device analog exists (batched training then routes through the
         per-iteration host loop). Device values are f32 — low-bit
-        divergence from the f64 host value is expected and documented."""
+        divergence from the f64 host value is expected and documented.
+
+        A metric that reports several results returns an f32 vector in
+        ``result_names()`` order; one that owns device state built from
+        the data (``device_state()`` not None) takes it as a fifth
+        argument, `fn(score, label, weight, sum_weights, state)`."""
+        return None
+
+    def device_state(self):
+        """Pytree of device arrays the device fn reads, or None; the
+        trainer passes it through its jitted programs' arguments."""
         return None
 
     def _w(self) -> np.ndarray:
@@ -454,6 +469,99 @@ class MultiErrorMetric(Metric):
 class NDCGMetric(Metric):
     name = "ndcg"
     is_higher_better = True
+    # live cells of one block of queries while it is ranked (plen x plen
+    # comparisons a query), as LambdarankNDCG.pair_block_bytes
+    rank_block_bytes = 256 << 20
+
+    def init(self, metadata, num_data: int) -> None:
+        super().init(metadata, num_data)
+        self._state = None
+
+    def device_state(self):
+        """Built on first use (``GBDT.add_valid_dataset`` asks once, so a
+        valid set's build falls under its ``booster/add_valid`` span; a
+        training metric is never asked and builds nothing)."""
+        if self._state is None and self.query_boundaries is not None \
+                and self.label is not None:
+            from ..runtime.profiler import count as span_count, span
+            with span("metric/init", rows=self.num_data,
+                      queries=len(self.query_boundaries) - 1):
+                self._state, cells, n_buckets = self._build_device_state()
+                span_count(padded_rows=cells, buckets=n_buckets)
+        return self._state
+
+    def _build_device_state(self):
+        """The query buckets of ``rank_buckets`` (the layout the device
+        LambdaRank objective reads through) with, per query, the label
+        gains, the inverse max DCG at each ``eval_at`` (0 where a query
+        has no relevant document: it then counts 1, rank_metric.hpp) and
+        the query's weight (its first row's, as eval_ndcg)."""
+        from .rank_buckets import (RANK_TEMPS, bucket_labels, build_buckets,
+                                   inverse_max_dcg_at, label_gains,
+                                   queries_per_block)
+        from .rank_utils import default_label_gain
+        ks = [int(k) for k in self.config.eval_at]
+        lg = np.asarray(self.config.label_gain, np.float64) \
+            if len(self.config.label_gain) else default_label_gain(
+                int(np.max(self.label)) if len(self.label) else 1)
+        buckets, _ = build_buckets(
+            self.query_boundaries, self.num_data,
+            lambda plen: queries_per_block(plen * plen * RANK_TEMPS,
+                                           self.rank_block_bytes // 4))
+        qb = np.asarray(self.query_boundaries, np.int64)
+        dev, cells = [], 0
+        for bk in buckets:
+            lab = bucket_labels(bk, self.label)
+            imd = inverse_max_dcg_at(lab, lg, ks)
+            live = bk["qids"] >= 0         # a block's tail: empty queries
+            qw = live.astype(np.float64) if self.weight is None \
+                else np.where(live, np.asarray(self.weight, np.float64)[
+                    qb[np.maximum(bk["qids"], 0)]], 0.0)
+            dev.append({"idx": jnp.asarray(bk["idx"]),
+                        "gain": jnp.asarray(label_gains(lab, lg)),
+                        "cnt": jnp.asarray(bk["cnt"]),
+                        "imd": jnp.asarray(imd.astype(np.float32)),
+                        "qw": jnp.asarray(qw.astype(np.float32))})
+            cells += lab.size
+        sumw = float(len(qb) - 1) if self.weight is None else float(
+            np.sum(np.asarray(self.weight, np.float64)[qb[:-1]]))
+        state = {"buckets": tuple(dev), "sumw": jnp.float32(sumw)}
+        return state, cells, len(buckets)
+
+    def result_names(self) -> List[str]:
+        return [f"ndcg@{k}" for k in self.config.eval_at]
+
+    def device_eval_fn(self, objective):
+        if self.device_state() is None:
+            return None           # no groups: eval() reports it
+        from .rank_buckets import gather_scores, map_blocks, rank_by_score
+        ks = jnp.asarray([int(k) for k in self.config.eval_at], jnp.int32)
+
+        def block(s, bk):
+            """[len(eval_at)]: the weighted NDCG sums of one block of
+            queries: each cell's rank by counting (stable: ties keep
+            row order), then the gains of the ranks under k,
+            discounted, summed in float32."""
+            rank, has_row = rank_by_score(
+                gather_scores(s, bk["idx"]), bk["cnt"])
+            d = jnp.where(has_row, bk["gain"]
+                          / jnp.log2(2.0 + rank.astype(jnp.float32)), 0.0)
+            dcg = jnp.sum(jnp.where(
+                rank[:, None, :] < ks[None, :, None], d[:, None, :], 0.0),
+                axis=2)                                      # [nq, k]
+            ndcg = jnp.where(bk["imd"] > 0, dcg * bk["imd"], 1.0)
+            return jnp.sum(ndcg * bk["qw"][:, None], axis=0)
+
+        def fn(score, label, weight, sum_weights, state):
+            """NDCG at every ``eval_at`` of the flat raw scores."""
+            with jax.named_scope("lgbm_rank_ndcg"):
+                s = jnp.reshape(score, (-1,))
+                total = jnp.zeros(ks.shape, jnp.float32)
+                for bk in state["buckets"]:
+                    part = map_blocks(lambda b: block(s, b), bk)
+                    total = total + part.reshape(-1, part.shape[-1]).sum(0)
+                return total / state["sumw"]
+        return fn
 
     def eval(self, score, objective) -> List[MetricResult]:
         from .rank_utils import eval_ndcg

@@ -904,17 +904,26 @@ class GBDT:
 
             self._train_tree = train_tree_wrap
 
+        # a table without categorical columns grows no bitset split: the
+        # walk is told so statically (structure, like a None of
+        # FeatureMeta), which frees it to take the path-matrix walk
+        has_cat = any(m.bin_type == BIN_TYPE_CATEGORICAL
+                      for m in self.mappers)
+
         @jax.jit
         def valid_update(split_feature, threshold_bin, default_left,
                          left_child, right_child, num_leaves, leaf_value,
                          Xv_t, vmeta_arrs, scores_k, lr, split_is_cat,
                          split_cat_bitset):
             vmeta = FeatureMeta(*vmeta_arrs)
-            leaf = predict_leaf_binned(split_feature, threshold_bin,
-                                       default_left, left_child, right_child,
-                                       num_leaves, Xv_t, vmeta,
-                                       split_is_cat, split_cat_bitset)
-            return scores_k + (leaf_value * lr)[leaf]
+            if not has_cat:
+                split_is_cat = split_cat_bitset = None
+            with jax.named_scope("lgbm_valid_update"):
+                leaf = predict_leaf_binned(
+                    split_feature, threshold_bin, default_left, left_child,
+                    right_child, num_leaves, Xv_t, vmeta, split_is_cat,
+                    split_cat_bitset)
+                return scores_k + (leaf_value * lr)[leaf]
 
         self._valid_update = valid_update
 
@@ -922,11 +931,11 @@ class GBDT:
             obj = self.objective
 
             @jax.jit
-            def grad_fn(scores, label, weight):
+            def grad_fn(scores, label, weight, ostate):
                 if obj.num_model_per_iteration == 1:
-                    g, h = obj.get_gradients(scores[0], label, weight)
+                    g, h = _gradients(obj, scores[0], label, weight, ostate)
                     return g[None, :], h[None, :]
-                return obj.get_gradients(scores, label, weight)
+                return _gradients(obj, scores, label, weight, ostate)
 
             self._grad_fn = grad_fn
         else:
@@ -956,6 +965,7 @@ class GBDT:
         self.valid_names.append(name)
         for m in metrics:
             m.init(ds.metadata, ds.num_data)
+            m.device_state()      # built here, once, not at the first chunk
         # in-scan eval state (docs/PERF.md §7): the batched path computes
         # these metrics on device inside the boosting scan, so it needs
         # device-resident label/weight and the metric objects themselves
@@ -1084,7 +1094,8 @@ class GBDT:
                 h = np.pad(h, pad)
             return (self._put_rows(jnp.asarray(g), row_axis=1),
                     self._put_rows(jnp.asarray(h), row_axis=1))
-        return self._grad_fn(self.scores, self.label_dev, self.weight_dev)
+        return self._grad_fn(self.scores, self.label_dev, self.weight_dev,
+                             self.objective.device_state())
 
     # ------------------------------------------------------------------
     # batched training: host-free boosting chunks (docs/PERF.md §7)
@@ -1113,7 +1124,8 @@ class GBDT:
         """[(vi, metric, device_fn)] covering EVERY valid-set metric, or
         None when any metric lacks a device analog (the batched path then
         defers to per-iteration host eval). Order defines the metric
-        column layout of train_iters_batched's stacked values."""
+        column layout of train_iters_batched's stacked values: a metric
+        takes one column per result it reports (``result_names()``)."""
         out = []
         for vi, metrics in enumerate(self._valid_metrics):
             for m in metrics:
@@ -1131,8 +1143,8 @@ class GBDT:
         lay = self._device_metric_layout()
         if lay is None:
             return None
-        return [(self.valid_names[vi], m.result_name(), m.is_higher_better)
-                for vi, m, _ in lay]
+        return [(self.valid_names[vi], name, m.is_higher_better)
+                for vi, m, _ in lay for name in m.result_names()]
 
     def can_batch_iters(self, n: int) -> bool:
         """Whether `n` whole-chunk device iterations (train_iters_batched)
@@ -1206,7 +1218,10 @@ class GBDT:
         d0 = self.dispatch_count
         with span("train/chunk", trees=n, trees_padded=n_pad):
             mvals = self._train_chunk(n, n_pad)
-            span_count(dispatches=self.dispatch_count - d0)
+            span_count(dispatches=self.dispatch_count - d0,
+                       valid_rows=sum(v.num_data for v in self.valid_sets),
+                       metric_columns=0 if mvals is None
+                       else int(mvals.shape[-1]))
         return mvals
 
     def _train_chunk(self, n: int, n_pad: int) -> Optional[jnp.ndarray]:
@@ -1263,7 +1278,10 @@ class GBDT:
                 tuple(self._valid_scores),
                 tuple(self._valid_label_dev),
                 tuple(self._valid_weight_dev),
-                tuple(jnp.float32(s) for s in self._valid_sumw))
+                tuple(jnp.float32(s) for s in self._valid_sumw),
+                self.objective.device_state(),
+                tuple(m.device_state() for _, m, _ in
+                      self._device_metric_layout() or ()))
         with span("train/chunk/submit"):
             self.scores = new_scores
             for vi, vs in enumerate(new_vscores):
@@ -1304,7 +1322,7 @@ class GBDT:
         growth across chunk-size changes would pin stale executables."""
         K = self.num_tree_per_iteration
         metric_layout = self._device_metric_layout() or []
-        metric_sig = tuple((vi, type(m).__name__, m.result_name())
+        metric_sig = tuple((vi, type(m).__name__, tuple(m.result_names()))
                            for vi, m, _ in metric_layout)
         key = (n_pad, K, mode, len(self.valid_sets), metric_sig)
         cache = getattr(self, "_scan_fns", None)
@@ -1325,7 +1343,7 @@ class GBDT:
         @jax.jit
         def scan_fn(X_t, scores0, label, weight, in_bag0, lr, start_iter,
                     n_active, masks, meta, vXts, vmetas, vscores0, vlabels,
-                    vweights, vsumw):
+                    vweights, vsumw, ostate=None, mstates=()):
             def step(carry, xs):
                 scores, vscores = carry
                 mask, i = xs
@@ -1333,10 +1351,12 @@ class GBDT:
                 active = i < n_active
                 with jax.named_scope("train/gradients"):
                     if K == 1:
-                        g, h = obj.get_gradients(scores[0], label, weight)
+                        g, h = _gradients(obj, scores[0], label, weight,
+                                          ostate)
                         g, h = g[None, :], h[None, :]
                     else:
-                        g, h = obj.get_gradients(scores, label, weight)
+                        g, h = _gradients(obj, scores, label, weight,
+                                          ostate)
                 if mode == "scan":
                     # device-side bagging/GOSS: pure function of `it`
                     # (+ this step's gradients for GOSS), bit-identical
@@ -1373,10 +1393,17 @@ class GBDT:
                     jnp.where(active, nv, ov)
                     for nv, ov in zip(new_vscores, vscores))
                 if metric_fns:
-                    mvals = jnp.stack([
-                        fn(new_vscores[vi], vlabels[vi], vweights[vi],
-                           vsumw[vi])
-                        for vi, fn in metric_fns])
+                    # one column per result: a scalar metric gives one,
+                    # a vector metric (ndcg@k) one per entry; a metric's
+                    # own device state rides in as an argument
+                    mvals = jnp.concatenate([
+                        jnp.reshape(
+                            fn(new_vscores[vi], vlabels[vi], vweights[vi],
+                               vsumw[vi], *(() if st is None else (st,))),
+                            (-1,))
+                        for (vi, fn), st in zip(
+                            metric_fns,
+                            mstates or (None,) * len(metric_fns))])
                 else:
                     mvals = jnp.zeros((0,), jnp.float32)
                 stacked = jax.tree.map(lambda *a: jnp.stack(a), *trees)
@@ -2266,6 +2293,14 @@ class GBDT:
             gbdt.models.append(Tree.from_string(body))
         gbdt.iter = len(gbdt.models) // max(gbdt.num_tree_per_iteration, 1)
         return gbdt
+
+
+def _gradients(obj, scores, label, weight, ostate):
+    """``obj.get_gradients`` with the objective's device state, where it
+    has one, handed in from the caller's arguments."""
+    if ostate is None:
+        return obj.get_gradients(scores, label, weight)
+    return obj.get_gradients(scores, label, weight, ostate)
 
 
 class _AsyncTreeDrain:

@@ -52,7 +52,8 @@ class RF(GBDT):
         else:
             scores_dev = self._put_rows(jnp.asarray(tmp), row_axis=1)
             self._fixed_g, self._fixed_h = self._grad_fn(
-                scores_dev, self.label_dev, self.weight_dev)
+                scores_dev, self.label_dev, self.weight_dev,
+                self.objective.device_state())
 
     # -- overrides ----------------------------------------------------
     def _boost_from_average(self) -> np.ndarray:

@@ -53,6 +53,15 @@ class ObjectiveFunction:
                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         raise NotImplementedError
 
+    def device_state(self):
+        """Pytree of device arrays built from the data that
+        ``get_gradients`` reads, or None. An objective that has one
+        takes it back as ``get_gradients(score, label, weight, state)``:
+        the trainer hands it through its jitted programs' arguments, so
+        a dataset's facts stay out of the lowered text
+        (docs/PERF.md §7)."""
+        return None
+
     def boost_from_score(self, class_id: int) -> float:
         return 0.0
 

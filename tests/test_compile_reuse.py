@@ -110,3 +110,54 @@ def test_structure_of_meta_is_still_static():
     assert plain.meta.monotone is None and mono.meta.monotone is not None
     assert jax.tree.structure(plain.meta) != jax.tree.structure(mono.meta)
     assert _lower_scan(plain).as_text() != _lower_scan(mono).as_text()
+
+
+def _rank_gbdt(seed):
+    """A ranking job with a held-out set: the same multiset of query
+    lengths in another order, other rows, other labels."""
+    rng = np.random.RandomState(seed)
+    p = {"objective": "lambdarank", "metric": "ndcg", "eval_at": [1, 3, 5],
+         "num_leaves": 15, "max_bin": 63, "min_data_in_leaf": 5,
+         "verbosity": -1}
+
+    def part(lengths):
+        ln = rng.permutation(lengths)
+        X = rng.normal(size=(int(ln.sum()), F)).astype(np.float32)
+        y = rng.randint(0, 5, size=len(X)).astype(np.float64)
+        return X, y, ln
+
+    X, y, ln = part(np.r_[np.arange(1, 40), [70, 150, 300]])
+    ds = lgb.Dataset(X, label=y, group=ln, params=p)
+    bst = lgb.Booster(params=p, train_set=ds)
+    Xv, yv, lv = part(np.r_[np.arange(1, 25), [90]])
+    bst.add_valid(lgb.Dataset(Xv, label=yv, group=lv, reference=ds), "v")
+    return bst._gbdt
+
+
+def test_two_ranking_datasets_lower_to_one_scan():
+    """The objective's query buckets and the NDCG metric's reach the scan
+    as arguments (`device_state()`), so a second ranking dataset of the
+    same shape loads the compiled scan from the cache."""
+    def lower(g):
+        n_pad = 2
+        lay = g._device_metric_layout()
+        assert lay and g.objective.device_state() is not None
+        return g._get_scan_fn(n_pad, g._batched_sampling_mode()).lower(
+            g.X_t, g.scores, g.label_dev, g.weight_dev,
+            jnp.ones((g._host_pad,), jnp.float32), jnp.float32(0.1),
+            jnp.int32(0), jnp.int32(n_pad),
+            jnp.ones((n_pad, len(g.mappers)), bool), g.meta,
+            tuple(g._valid_Xt), tuple(tuple(m) for m in g._valid_meta),
+            tuple(g._valid_scores), tuple(g._valid_label_dev),
+            tuple(g._valid_weight_dev),
+            tuple(jnp.float32(s) for s in g._valid_sumw),
+            g.objective.device_state(),
+            tuple(m.device_state() for _, m, _ in lay)).as_text()
+
+    a, b = _rank_gbdt(21), _rank_gbdt(22)
+    assert not np.array_equal(
+        np.asarray(a.objective.device_state()["pos_of_row"]),
+        np.asarray(b.objective.device_state()["pos_of_row"]))
+    ta, tb = lower(a), lower(b)
+    assert ta == tb, next((x, y) for x, y in
+                          zip(ta.splitlines(), tb.splitlines()) if x != y)
