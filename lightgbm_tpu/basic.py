@@ -21,8 +21,10 @@ from .data.dataset import (BinnedDataset, construct_from_matrix,
 from .metrics import Metric, create_metric, default_metric_for_objective
 from .models.gbdt import GBDT
 from .objectives import create_objective
+from .runtime.device import start_backend
 from .runtime.profiler import (compiles_outside_spans,
-                               count as span_count, span, spans)
+                               count as span_count, span, spans,
+                               spans_dropped)
 from .utils.log import log_fatal, log_info, log_warning
 
 # streaming device bin table "not yet resolved" marker (None is the
@@ -128,6 +130,7 @@ class Dataset:
     def construct(self) -> "Dataset":
         if self._handle is not None:
             return self
+        start_backend()
         cfg = resolve_params(self.params)
 
         # file-path data: binary cache (npz/zip magic) or text
@@ -529,11 +532,9 @@ class Dataset:
         table = self._stream_bin_table() \
             if batch.dtype == np.float32 else None
         if table is not None:
-            from .ops.bucketize import bin_rows_device
-            raw = np.ascontiguousarray(batch[:, h.real_feature_index],
-                                       np.float32)
-            h.X_binned[lo:hi, :] = bin_rows_device(raw, table).astype(
-                h.X_binned.dtype)
+            from .data.dataset import bin_rows_into
+            bin_rows_into(h.X_binned[lo:hi, :], batch, h.real_feature_index,
+                          table)
         else:
             for inner, (m, orig) in enumerate(zip(h.mappers,
                                                   h.real_feature_index)):
@@ -755,13 +756,15 @@ class Booster:
         """Device-profile export (runtime/profiler.py to_dict): per-stage
         seconds, per-iteration ring buffer, row-iters/s, HBM watermark,
         and under "spans" the process's unfenced span ring (beside it
-        the backend compilations that fired under no span).
+        the backend compilations that fired under no span, and the
+        spans pushed out of the full ring).
         None unless trained with device_profile=true."""
         prof = getattr(self._gbdt, "profiler", None)
         if prof is None:
             return None
         return dict(prof.to_dict(), spans=spans(),
-                    compiles_outside_spans=compiles_outside_spans())
+                    compiles_outside_spans=compiles_outside_spans(),
+                    spans_dropped=spans_dropped())
 
     def num_model_per_iteration(self) -> int:
         return self._gbdt.num_tree_per_iteration

@@ -38,6 +38,7 @@ from ..ops.grow import DeviceTree, GrowConfig, grow_tree
 from ..ops.predict import predict_leaf_binned
 from ..ops.split import FeatureMeta
 from ..utils.log import log_fatal, log_info, log_warning
+from ..runtime.device import start_backend
 from ..runtime.profiler import count as span_count, span
 from .tree import Tree, make_decision_type
 
@@ -1563,13 +1564,6 @@ class GBDT:
         self.iter += 1
         if prof is not None:
             prof.iter_end(n_rows=self.num_data)
-            if "stage_probe" not in prof.extras and not self.use_dist:
-                # one-time micro-probe decomposition of the fused "grow"
-                # span into histogram / split-search / partition kernels
-                from ..runtime.profiler import probe_stage_breakdown
-                prof.extras["stage_probe"] = probe_stage_breakdown(
-                    self.X_t, g_dev[0], h_dev[0], self.meta,
-                    self.grow_cfg)
         # The stop condition requires a host readback (a full device
         # drain), so it is only REALLY evaluated at power-of-2 iterations
         # and then every _stop_check_interval; in between, training streams
@@ -2244,6 +2238,7 @@ class GBDT:
     def load_model_from_string(cls, model_str: str,
                                config: Optional[Config] = None) -> "GBDT":
         """reference: GBDT::LoadModelFromString (gbdt_model_text.cpp:590)."""
+        start_backend()
         with span("booster/load", bytes=len(model_str)):
             gbdt = cls._load_model_from_string(model_str, config)
             span_count(trees=len(gbdt.models))

@@ -56,6 +56,7 @@ from typing import List, NamedTuple, Optional, Sequence
 import numpy as np
 
 from ..models.tree import MISSING_NAN
+from ..runtime.profiler import count as span_count, span
 from ..utils import kernel_name, round_up as _round_up
 
 # meta row layout ([F, 8] f32, one row per feature)
@@ -507,13 +508,17 @@ def bin_rows_device(X: np.ndarray, t: DeviceBinTable,
                     chunk: int = 65536) -> np.ndarray:
     """Bin a host matrix through the device table in fixed-size padded
     chunks (one compiled shape regardless of n): [n, F] raw f32 ->
-    [n, F] uint8. The ingest-side entry point (data/dataset.py,
-    basic.py push_rows)."""
+    [n, F] uint8. The ingest-side entry point (data/dataset.py
+    ``bin_rows_into``). The span open at entry counts ``blocks`` and
+    ``block_rows``; each block is three spans: ``fill`` (the staging
+    copy), ``device`` (upload, kernel and download, waited for) and
+    ``store`` (the block's bins into the result)."""
     import jax
 
     n = X.shape[0]
     chunk = max(min(int(chunk), max(_round_up(n, _ROW_TILE), _ROW_TILE)),
                 _ROW_TILE)
+    span_count(blocks=-(-n // chunk), block_rows=chunk)
     fn = _bin_rows_jit()
     table = jax.device_put((t.table, t.cat_val, t.meta))   # once a call
     out = np.empty((n, t.num_features), np.uint8)
@@ -521,8 +526,13 @@ def bin_rows_device(X: np.ndarray, t: DeviceBinTable,
     for c0 in range(0, n, chunk):
         c1 = min(c0 + chunk, n)
         m = c1 - c0
-        buf[:m] = X[c0:c1, :t.num_features]
-        if m < chunk:
-            buf[m:] = 0.0
-        out[c0:c1] = np.asarray(jax.device_get(fn(buf, *table)))[:m]
+        with span("dataset/bucketize/fill", rows=m):
+            buf[:m] = X[c0:c1, :t.num_features]
+            if m < chunk:
+                buf[m:] = 0.0
+        with span("dataset/bucketize/device", bytes_up=buf.nbytes,
+                  bytes_down=chunk * t.num_features):
+            got = np.asarray(jax.device_get(fn(buf, *table)))
+        with span("dataset/bucketize/store"):
+            out[c0:c1] = got[:m]
     return out

@@ -6,6 +6,12 @@ Every dispatch in the package keys on ``jax.default_backend()``: a
 process that finds no chip would otherwise train on the CPU and exit 0.
 ``require_tpu`` is the one place that turns "no accelerator" into an
 error, for the callers whose numbers only mean something on the chip.
+
+The backend's start (on a TPU, the runtime's: seconds) happens under
+the first ``jax.devices()`` of the process. ``start_backend`` times it
+as the root span ``runtime/backend``; the program's first device
+touches call it, so the span holds the start whichever path the user
+took.
 """
 
 from __future__ import annotations
@@ -25,9 +31,30 @@ _CACHE_DIR = os.path.join(
         os.path.abspath(__file__)))), ".jax_cache")
 
 
+_backend_seen = False
+
+
+def start_backend() -> None:
+    """Start JAX's backend under the root span ``runtime/backend``
+    {devices}, if no backend is up yet; every later call does nothing.
+    A backend that something else started first is not timed."""
+    global _backend_seen
+    if _backend_seen:
+        return
+    _backend_seen = True
+    import jax
+    from jax._src import xla_bridge     # no public way to ask
+    if xla_bridge.backends_are_initialized():
+        return
+    from .profiler import count, span
+    with span("runtime/backend"):
+        count(devices=len(jax.devices()))
+
+
 def device_info() -> Dict[str, Any]:
     """The device as JAX reports it (initializes the backend)."""
     import jax
+    start_backend()
     devs = jax.devices()
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
             "count": len(devs)}

@@ -7,10 +7,14 @@ Two layers live here:
    the device: no ``block_until_ready``, no barrier. Each span also
    enters ``jax.profiler.TraceAnnotation("lgbm:" + name)``, so under
    ``jax.profiler.start_trace`` it lands in the trace's ``/host:CPU``
-   plane beside the device planes, on the same clock, over the idle gap
-   it explains; with no profiler session the annotation is inert.
-   Finished spans go to one bounded ring (oldest dropped); ``spans()``
-   snapshots it (all spans of one call share their ``root``). Backend
+   plane beside the device planes, over the idle gap it explains; with
+   no profiler session the annotation is inert. The annotation carries
+   the span's ring id as its statistic ``ring_id``: the trace's times
+   are relative to its session and the ring's are ``perf_counter_ns``,
+   so one joined pair gives the offset for every span of the process.
+   Finished spans go to one bounded ring (oldest dropped, and counted:
+   ``spans_dropped()``); ``spans()`` snapshots it (all spans of one
+   call share their ``root``). Backend
    compilations are counted where they happen: one ``jax.monitoring``
    listener adds ``compiles`` and ``compile_s`` to the innermost open
    span of the compiling thread, and a second says which of them the
@@ -36,12 +40,6 @@ Two layers live here:
    (``jax.local_devices()[0].memory_stats()``) accumulate for the whole
    run. ``to_dict``/``export_json`` emit the JSON shape consumed by
    bench.py / BENCH_*.json and by the ``--profile`` CLI flag.
-
-The growers are single fused jits, so the host cannot fence *inside*
-them; ``probe_stage_breakdown`` fills that gap by timing jitted
-micro-probes of the constituent kernels (histogram build, split search,
-partition) once, giving a representative per-stage decomposition of the
-opaque ``grow`` span.
 """
 
 from __future__ import annotations
@@ -84,11 +82,12 @@ def device_barrier() -> None:
 # spans and counts
 # ---------------------------------------------------------------------------
 
-def _trace_annotation(name: str):
-    """The profiler's own host annotation for ``name`` (jax imported
-    lazily: this module stays importable host-only)."""
+def _trace_annotation(name: str, ring_id: int):
+    """The profiler's own host annotation for ``name``, carrying the
+    span's ring id (jax imported lazily: this module stays importable
+    host-only)."""
     import jax.profiler
-    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name)
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, ring_id=ring_id)
 
 
 class _Span:
@@ -112,7 +111,7 @@ class _Span:
             self.parent, self.root = None, self.id
         self.thread = threading.current_thread().name
         stack.append(self)
-        self._ann = _trace_annotation(self.name)
+        self._ann = _trace_annotation(self.name, self.id)
         self._ann.__enter__()
         self.start_ns = time.perf_counter_ns()
         return self
@@ -121,7 +120,10 @@ class _Span:
         self.end_ns = time.perf_counter_ns()
         self._ann.__exit__(*exc)
         self._rec._stack().pop()
-        self._rec.ring.append(self)
+        ring = self._rec.ring
+        if len(ring) == ring.maxlen:
+            self._rec.dropped += 1
+        ring.append(self)
 
     def to_dict(self) -> Dict[str, Any]:
         return {"name": self.name, "id": self.id, "parent": self.parent,
@@ -143,6 +145,7 @@ class SpanRecorder:
         self.ring: collections.deque = collections.deque(maxlen=maxlen)
         self.enabled = True
         self.compiles_outside_spans = 0
+        self.dropped = 0                # spans pushed out of a full ring
         self._ids = itertools.count(1)
         self._local = threading.local()
 
@@ -220,6 +223,12 @@ def spans() -> List[Dict[str, Any]]:
 
 def compiles_outside_spans() -> int:
     return _RECORDER.compiles_outside_spans
+
+
+def spans_dropped() -> int:
+    """Spans pushed out of the full ring so far: a reader that finds
+    children missing can tell an overwritten record from a short one."""
+    return _RECORDER.dropped
 
 
 def set_spans(on: bool) -> None:
@@ -534,79 +543,6 @@ class LatencyStats:
             "p99_ms": round((self.percentile(99.0) or 0.0) * 1e3, 3),
             "max_ms": round(self.max_s * 1e3, 3),
         }
-
-
-def probe_stage_breakdown(X_t, grad, hess, meta, cfg,
-                          n_probe_rows: int = 16384) -> Dict[str, float]:
-    """One-time decomposition of the fused grow step into its constituent
-    kernels (histogram build, split search, partition), each timed as a
-    separate jit with device fencing.
-
-    The per-iteration ``grow`` span is opaque (one fused jit); this gives
-    the stage-level attribution the reference gets from USE_TIMETAG
-    phases. Returned seconds are representative single-shot costs at the
-    probe size, not exact shares of the fused kernel.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from ..ops import histogram as H
-    from ..ops import split as S
-
-    n = int(X_t.shape[1])
-    m = min(int(n_probe_rows), n)
-    Xs = jnp.asarray(jax.device_get(X_t[:, :m]))
-    g = jnp.asarray(jax.device_get(grad[:m]), jnp.float32)
-    h = jnp.asarray(jax.device_get(hess[:m]), jnp.float32)
-    B = int(cfg.num_bins_padded)
-
-    def timed(fn, *args) -> float:
-        jitted = jax.jit(fn)
-
-        def run():
-            out = jitted(*args)
-            jax.tree_util.tree_map(
-                lambda x: x.block_until_ready() if hasattr(
-                    x, "block_until_ready") else x, out)
-            return out
-
-        run()                       # compile + warm
-        device_barrier()
-        t0 = time.perf_counter()
-        run()
-        return time.perf_counter() - t0
-
-    out: Dict[str, float] = {"probe_rows": m}
-
-    vals = jnp.stack([g, h])                                # [2, N]
-    out["histogram_s"] = round(
-        timed(lambda X, v: H.build_histogram(X, v, B), Xs, vals), 6)
-
-    # split search on the probe histogram; skipped when the histogram
-    # feature axis doesn't match meta (EFB bundles re-slice it at search
-    # time inside the grower, which the micro-probe doesn't replicate)
-    if not getattr(cfg, "bundled", False):
-        try:
-            hist2 = jax.jit(
-                lambda X, v: H.build_histogram(X, v, B))(Xs, vals)
-            gsum, hsum = jnp.sum(g), jnp.sum(h)
-            cnt = jnp.float32(m)
-            hp = cfg.hp
-
-            def split_probe(hh, gs, hs, c, mt):
-                h3 = S.synth_count_channel(hh, c, hs)
-                return S.find_best_split(h3, gs, hs, c, jnp.float32(0.0),
-                                         mt, hp)
-
-            out["split_search_s"] = round(
-                timed(split_probe, hist2, gsum, hsum, cnt, meta), 6)
-        except Exception:
-            pass
-
-    thr = jnp.int32(B // 2)
-    out["partition_s"] = round(
-        timed(lambda X, t: (X[0] <= t).astype(jnp.int32), Xs, thr), 6)
-    return out
 
 
 def pallas_kernel_names(fn: Callable, *args: Any,

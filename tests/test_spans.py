@@ -16,6 +16,7 @@ import pytest
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.runtime import profiler as P
+from lightgbm_tpu.runtime.device import start_backend
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 for _p in (os.path.join(ROOT, "bench"), os.path.join(ROOT, "scripts")):
@@ -148,6 +149,7 @@ def test_ring_is_bounded_and_drops_the_oldest():
             pass
     assert _names(rec.spans()) == [f"t/{i}" for i in range(12, 20)]
     assert _last_root("t/3", rec.spans()) == []
+    assert rec.dropped == 12
     # set-up spans outlast a window of block-wise passes: 45 Bosch
     # passes of 4 + 6 x 19 records are 5,310
     assert P._RECORDER.ring.maxlen == P.SPAN_RING_SIZE == 16384
@@ -164,6 +166,7 @@ def test_profile_export_carries_the_ring(table):
     assert out["spans"][-1]["name"] == "t/export"
     assert out["spans"][-1]["counts"] == {"rows": 2}
     assert out["compiles_outside_spans"] == P.compiles_outside_spans()
+    assert out["spans_dropped"] == P.spans_dropped()
 
 
 def test_count_adds_to_the_innermost_open_span():
@@ -183,7 +186,7 @@ def test_off_records_nothing_and_enters_no_annotation(monkeypatch):
     real = P._trace_annotation
     monkeypatch.setattr(
         P, "_trace_annotation",
-        lambda name: (entered.append(name), real(name))[1])
+        lambda name, ring_id: (entered.append(name), real(name, ring_id))[1])
     with P.span("t/on"):
         pass
     assert entered == ["t/on"]
@@ -203,8 +206,8 @@ def test_annotation_carries_the_trace_prefix(monkeypatch):
     made = []
 
     class Annotation:
-        def __init__(self, name):
-            made.append(name)
+        def __init__(self, name, ring_id):
+            made.append((name, ring_id))
 
         def __enter__(self):
             return self
@@ -215,7 +218,7 @@ def test_annotation_carries_the_trace_prefix(monkeypatch):
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
     with P.span("t/prefixed"):
         pass
-    assert made == ["lgbm:t/prefixed"]
+    assert made == [("lgbm:t/prefixed", _last_root("t/prefixed")[0]["id"])]
 
 
 def test_compile_is_counted_on_the_open_span():
@@ -449,7 +452,7 @@ def test_update_batch_records_chunks_and_drain(monkeypatch, table):
     construct = _last_root("dataset/construct")
     assert _names(construct) == [
         "dataset/construct", "dataset/sample", "dataset/find_bins",
-        "dataset/host_bin", "dataset/finalize"]
+        "dataset/bucketize/table", "dataset/host_bin", "dataset/finalize"]
     assert construct[0]["counts"] == {"rows": 4000, "features": 6,
                                       "binned_on_device": 0}
     init = _last_root("booster/init")
@@ -497,6 +500,7 @@ def test_per_iteration_training_opens_no_span_per_tree():
     X = rng.normal(size=(64, 2)).astype(np.float32)
     y = (X[:, 0] > 0).astype(np.float64)
     params = dict(PARAMS, num_leaves=2, min_data_in_leaf=1)
+    start_backend()                      # its span is not this test's
     P._RECORDER.ring.clear()
     bst = lgb.Booster(params=params,
                       train_set=lgb.Dataset(X, label=y, params=params))
@@ -550,6 +554,181 @@ def test_timer_and_its_switch_are_gone():
         assert not hasattr(P, gone) and not hasattr(rt, gone)
     with pytest.raises(ImportError):
         import lightgbm_tpu.utils.timer  # noqa: F401
+
+
+# ---------------------------------------------------------------------------
+# set-up: ingest block by block, the backend's start, one clock with a trace
+# ---------------------------------------------------------------------------
+
+INGEST_ROWS, INGEST_COLS, BLOCK = 200_000, 28, 65536
+DEVICE_BINS = dict(PARAMS, binning_impl="device")
+
+
+@pytest.fixture(scope="module")
+def wide_table():
+    rng = np.random.RandomState(11)
+    X = rng.normal(size=(INGEST_ROWS, INGEST_COLS)).astype(np.float32)
+    return X, (X[:, 0] > 0).astype(np.float64)
+
+
+def _constructed(X, y):
+    lgb.Dataset(X, label=y, params=DEVICE_BINS).construct()
+    return _last_root("dataset/construct")
+
+
+def _pushed(X, y):
+    ref = lgb.Dataset(X[:5000], label=y[:5000], params=DEVICE_BINS)
+    stream = lgb.Dataset(None, reference=ref, params=DEVICE_BINS)
+    stream.init_streaming(len(X))
+    stream.push_rows(X)
+    return _last_root("dataset/bucketize")
+
+
+@pytest.mark.parametrize("entry", [_constructed, _pushed],
+                         ids=["construct", "push_rows"])
+def test_ingest_blocks_nest_under_bucketize(wide_table, entry):
+    """Device ingest is the gather, three spans a row block and the cast,
+    all under ``dataset/bucketize``, whichever call pushed the rows."""
+    X, y = wide_table
+    tree = entry(X, y)
+    bucketize = next(r for r in tree if r["name"] == "dataset/bucketize")
+    kids = _children(tree, bucketize)
+    blocks = -(-INGEST_ROWS // BLOCK)
+    assert bucketize["counts"] == {"blocks": blocks, "block_rows": BLOCK}
+    assert _names(kids) == (["dataset/bucketize/gather"]
+                            + ["dataset/bucketize/fill",
+                               "dataset/bucketize/device",
+                               "dataset/bucketize/store"] * blocks
+                            + ["dataset/bucketize/cast"])
+    assert all(bucketize["start_ns"] <= r["start_ns"] <= r["end_ns"]
+               <= bucketize["end_ns"] for r in kids)
+    assert not any(_children(tree, r) for r in kids)
+    fills = [r["counts"]["rows"] for r in kids
+             if r["name"] == "dataset/bucketize/fill"]
+    assert sum(fills) == INGEST_ROWS and fills[-1] == INGEST_ROWS % BLOCK
+    assert kids[0]["counts"] == {"bytes": INGEST_ROWS * INGEST_COLS * 4}
+
+
+@pytest.mark.parametrize("key, per_block", [
+    ("bytes_up", BLOCK * INGEST_COLS * 4),    # the staging buffer, whole
+    ("bytes_down", BLOCK * INGEST_COLS),      # a block of uint8 bins
+])
+def test_ingest_bytes_sum_over_whole_blocks(wide_table, key, per_block):
+    from readers import program_span
+    X, y = wide_table
+    _constructed(X, y)
+    blocks = -(-INGEST_ROWS // BLOCK)
+    assert program_span.read({}, {"root": "dataset/construct",
+                                  "count": key}) == blocks * per_block
+
+
+def test_ingest_children_cover_construct(wide_table):
+    """What a reader of the ingest phases finds: the gather, the host
+    copies and the waits on the device fill ``dataset/bucketize``, and
+    the children of ``dataset/construct`` (the table's among them) leave
+    it little time of its own."""
+    from readers import program_span
+    X, y = wide_table
+    tree = _constructed(X, y)
+    construct = tree[0]
+    assert "dataset/bucketize/table" in _names(_children(tree, construct))
+    bucketize = next(r for r in tree if r["name"] == "dataset/bucketize")
+    phases = [program_span.read({}, {"root": "dataset/construct",
+                                     "match": [f"^{name}$"]})
+              for name in ("dataset/bucketize/gather",
+                           "dataset/bucketize/(fill|store|cast)",
+                           "dataset/bucketize/device")]
+    whole = (bucketize["end_ns"] - bucketize["start_ns"]) / 1e9
+    assert 0.9 * whole <= sum(phases) <= whole
+    unnamed = program_span.read({}, {"root": "dataset/construct",
+                                     "minus": ["."]})
+    assert unnamed < 0.05 * (construct["end_ns"] - construct["start_ns"]) / 1e9
+
+
+BACKEND_SCRIPT = """
+import json, sys
+import numpy as np
+import lightgbm_tpu as lgb
+from lightgbm_tpu.runtime import device, profiler
+X = np.random.RandomState(0).normal(size=(300, 3)).astype(np.float32)
+y = (X[:, 0] > 0).astype(np.float64)
+params = {"objective": "binary", "num_leaves": 3, "verbose": -1}
+first, model_file = sys.argv[1:]
+if first == "jax":
+    import jax
+    jax.devices()                  # something else started the backend
+elif first == "device_info":
+    device.device_info()
+elif first == "dataset":
+    lgb.Dataset(X, label=y, params=params).construct()
+elif first == "booster":
+    lgb.Booster(params=params, train_set=lgb.Dataset(X, label=y))
+elif first == "model_file":
+    lgb.Booster(model_file=model_file)
+# every later touch
+device.device_info()
+lgb.Dataset(X, label=y, params=params).construct()
+bst = lgb.Booster(params=params, train_set=lgb.Dataset(X, label=y))
+lgb.Booster(model_str=bst.model_to_string())
+recs = profiler.spans()
+print(json.dumps([r for r in recs if r["name"] == "runtime/backend"]
+                 + [recs[0]["name"]]))
+"""
+
+
+@pytest.mark.parametrize("first", [
+    "device_info", "dataset", "booster", "model_file", "jax"])
+def test_backend_start_is_one_root_span(first, booster, tmp_path):
+    """In a fresh process the first device touch, whichever it is, opens
+    ``runtime/backend`` as the first root span, once; a backend that was
+    up before is not timed."""
+    import subprocess
+    model_file = tmp_path / "model.txt"
+    model_file.write_text(booster.model_to_string())
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
+    out = subprocess.run(
+        [sys.executable, "-c", BACKEND_SCRIPT, first, str(model_file)],
+        env=env, capture_output=True, text=True, timeout=240, cwd=ROOT)
+    assert out.returncode == 0, out.stderr[-2000:]
+    *recs, first_name = json.loads(out.stdout.strip().splitlines()[-1])
+    if first == "jax":
+        assert recs == [] and first_name != "runtime/backend"
+        return
+    assert first_name == "runtime/backend" and len(recs) == 1
+    assert recs[0]["parent"] is None and recs[0]["root"] == recs[0]["id"]
+    assert recs[0]["counts"] == {"devices": len(jax.devices())}
+    assert recs[0]["end_ns"] > recs[0]["start_ns"]
+
+
+def test_trace_events_join_the_ring_by_id(tmp_path):
+    """Each ``lgbm:`` event of a profiler trace carries its span's ring id,
+    so one joined pair gives the offset between the two clocks: a span
+    opened 10 ms after another lands within 200 us of its own event."""
+    import time
+    import trace_reduce
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    with P.span("t/joined_a"):
+        time.sleep(0.01)
+    with P.span("t/joined_b"):
+        time.sleep(0.001)
+    jax.profiler.stop_trace()
+    data = ProfileData.from_file(trace_reduce.find_xplane(str(tmp_path)))
+    events = {}
+    for plane in data.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(P.SPAN_PREFIX + "t/joined_"):
+                    events[dict(ev.stats)["ring_id"]] = ev
+    ring = {r["id"]: r for r in P.spans()
+            if r["name"].startswith("t/joined_")}
+    assert len(events) == 2 and set(events) == set(ring)
+    for rid, ev in events.items():
+        assert ev.name == P.SPAN_PREFIX + ring[rid]["name"]
+    a, b = sorted(ring.values(), key=lambda r: r["id"])
+    offset = events[a["id"]].start_ns - a["start_ns"]
+    assert b["start_ns"] - a["start_ns"] >= 10_000_000
+    assert abs(b["start_ns"] + offset - events[b["id"]].start_ns) < 200_000
 
 
 # ---------------------------------------------------------------------------
