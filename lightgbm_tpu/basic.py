@@ -532,9 +532,9 @@ class Dataset:
         table = self._stream_bin_table() \
             if batch.dtype == np.float32 else None
         if table is not None:
-            from .data.dataset import bin_rows_into
-            bin_rows_into(h.X_binned[lo:hi, :], batch, h.real_feature_index,
-                          table)
+            from .ops.bucketize import bin_rows_device
+            bin_rows_device(h.X_binned[lo:hi, :], batch,
+                            h.real_feature_index, table)
         else:
             for inner, (m, orig) in enumerate(zip(h.mappers,
                                                   h.real_feature_index)):

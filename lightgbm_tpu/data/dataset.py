@@ -474,23 +474,6 @@ def ingest_bin_table(ds: BinnedDataset, config: Config, n_rows: int):
         return None
 
 
-def bin_rows_into(dest: np.ndarray, data: np.ndarray,
-                  columns: Sequence[int], table) -> None:
-    """Device ingest of raw float32 rows into ``dest`` (the binned matrix,
-    or a row range of it) under the span ``dataset/bucketize``: the
-    column gather into one contiguous array (``…/gather`` {bytes}), the
-    row blocks (ops/bucketize.py ``bin_rows_device``) and the cast into
-    ``dest`` (``…/cast``) each under a child of their own."""
-    from ..ops.bucketize import bin_rows_device
-    with span("dataset/bucketize"):
-        with span("dataset/bucketize/gather"):
-            raw = np.ascontiguousarray(data[:, columns], np.float32)
-            span_count(bytes=raw.nbytes)
-        binned = bin_rows_device(raw, table)
-        with span("dataset/bucketize/cast"):
-            dest[...] = binned.astype(dest.dtype)
-
-
 def _finalize(ds: BinnedDataset, config: Config,
               label, weight, group, init_score,
               reference: Optional[BinnedDataset]) -> BinnedDataset:
@@ -555,14 +538,15 @@ def construct_from_matrix(
         # push rows: device bucketize when the raw matrix is f32 and the
         # mapper set packs (bit-identical to the host loop — docs/PERF.md
         # §8); per-feature vectorized value->bin on host otherwise
-        X = _alloc_binned(ds)       # zero pages: touched first by the cast
+        X = _alloc_binned(ds)       # zero pages: touched first by a store
         table = None
         if data.dtype == np.float32:
             with span("dataset/bucketize/table"):
                 table = ingest_bin_table(ds, config, num_data)
         span_count(binned_on_device=int(table is not None))
         if table is not None:
-            bin_rows_into(X, data, ds.real_feature_index, table)
+            from ..ops.bucketize import bin_rows_device
+            bin_rows_device(X, data, ds.real_feature_index, table)
             ds.binned_on = "device"
         else:
             with span("dataset/host_bin"):

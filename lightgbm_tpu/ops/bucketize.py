@@ -49,6 +49,7 @@ parity suites in tests/test_predict_binned.py run there).
 
 from __future__ import annotations
 
+import collections
 import functools
 import os
 from typing import List, NamedTuple, Optional, Sequence
@@ -504,35 +505,72 @@ def _bin_rows_jit():
     return jax.jit(bin_rows)
 
 
-def bin_rows_device(X: np.ndarray, t: DeviceBinTable,
-                    chunk: int = 65536) -> np.ndarray:
-    """Bin a host matrix through the device table in fixed-size padded
-    chunks (one compiled shape regardless of n): [n, F] raw f32 ->
-    [n, F] uint8. The ingest-side entry point (data/dataset.py
-    ``bin_rows_into``). The span open at entry counts ``blocks`` and
-    ``block_rows``; each block is three spans: ``fill`` (the staging
-    copy), ``device`` (upload, kernel and download, waited for) and
-    ``store`` (the block's bins into the result)."""
+# bin_rows_device's row block (one compiled shape for every table of at
+# least this many rows) and how many blocks are on the device at once
+_BLOCK_ROWS = 65536
+_IN_FLIGHT = 3
+
+
+def bin_rows_device(dest: np.ndarray, data: np.ndarray,
+                    columns: Sequence[int], t: DeviceBinTable) -> None:
+    """Device ingest: bin the raw float32 ``data[:, columns]`` through the
+    device table into ``dest`` (the binned matrix, or a row range of it),
+    under the span ``dataset/bucketize``.
+
+    A software pipeline over row blocks of one padded shape (a table
+    under one block is one block of its own rounded size). For each
+    block: ``fill`` copies its rows and columns from ``data`` into a
+    staging buffer (a plain slice where ``columns`` is ``0..F-1``, else
+    a take of the columns); ``issue`` uploads the buffer, dispatches the
+    ingest program and starts the bins' download, without waiting. At
+    most _IN_FLIGHT blocks are on the device: before block b is issued,
+    block b - _IN_FLIGHT is finished: ``device`` waits for its bins and
+    fetches them, ``store`` writes its rows once into ``dest``. There is
+    a staging buffer a block in flight plus one, so a buffer handed to
+    the device is refilled only after its block was fetched.
+    ``dataset/bucketize`` counts ``blocks``, ``block_rows``,
+    ``in_flight`` and ``column_slice`` (1 where the fill was a slice)."""
     import jax
 
-    n = X.shape[0]
-    chunk = max(min(int(chunk), max(_round_up(n, _ROW_TILE), _ROW_TILE)),
-                _ROW_TILE)
-    span_count(blocks=-(-n // chunk), block_rows=chunk)
-    fn = _bin_rows_jit()
-    table = jax.device_put((t.table, t.cat_val, t.meta))   # once a call
-    out = np.empty((n, t.num_features), np.uint8)
-    buf = np.zeros((chunk, t.num_features), np.float32)
-    for c0 in range(0, n, chunk):
-        c1 = min(c0 + chunk, n)
-        m = c1 - c0
-        with span("dataset/bucketize/fill", rows=m):
-            buf[:m] = X[c0:c1, :t.num_features]
-            if m < chunk:
-                buf[m:] = 0.0
-        with span("dataset/bucketize/device", bytes_up=buf.nbytes,
-                  bytes_down=chunk * t.num_features):
-            got = np.asarray(jax.device_get(fn(buf, *table)))
-        with span("dataset/bucketize/store"):
-            out[c0:c1] = got[:m]
-    return out
+    with span("dataset/bucketize"):
+        n, F = data.shape[0], t.num_features
+        chunk = max(min(_BLOCK_ROWS, _round_up(n, _ROW_TILE)), _ROW_TILE)
+        blocks = -(-n // chunk)
+        in_flight = min(blocks, _IN_FLIGHT)
+        cols = np.asarray(columns, np.intp)
+        column_slice = bool(np.array_equal(cols, np.arange(F)))
+        span_count(blocks=blocks, block_rows=chunk, in_flight=in_flight,
+                   column_slice=int(column_slice))
+        fn = _bin_rows_jit()
+        table = jax.device_put((t.table, t.cat_val, t.meta))  # once a call
+        bufs = [np.zeros((chunk, F), np.float32)
+                for _ in range(in_flight + 1)]
+        pending = collections.deque()
+
+        def finish():
+            c0, c1, bins = pending.popleft()
+            with span("dataset/bucketize/device", bytes_down=bins.nbytes):
+                got = np.asarray(bins)
+            with span("dataset/bucketize/store"):
+                dest[c0:c1] = got[:c1 - c0]
+
+        for b in range(blocks):
+            c0 = b * chunk
+            c1 = min(c0 + chunk, n)
+            buf = bufs[b % len(bufs)]
+            with span("dataset/bucketize/fill", rows=c1 - c0):
+                if column_slice:
+                    buf[:c1 - c0] = data[c0:c1, :F]
+                else:
+                    # mode "clip": "raise" would stage the take elsewhere
+                    np.take(data[c0:c1], cols, axis=1, out=buf[:c1 - c0],
+                            mode="clip")
+                buf[c1 - c0:] = 0.0
+            if len(pending) == in_flight:
+                finish()
+            with span("dataset/bucketize/issue", bytes_up=buf.nbytes):
+                bins = fn(jax.device_put(buf), *table)
+                bins.copy_to_host_async()
+                pending.append((c0, c1, bins))
+        while pending:
+            finish()

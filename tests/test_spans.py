@@ -584,29 +584,46 @@ def _pushed(X, y):
     return _last_root("dataset/bucketize")
 
 
+def _pipeline_order(blocks, in_flight):
+    """The children of ``dataset/bucketize`` in start order: block b is
+    filled, block b - in_flight finished (``device``, ``store``), then
+    block b issued; the last blocks are finished after the loop."""
+    names, done = [], 0
+    for b in range(blocks):
+        names.append("fill")
+        if b - done == in_flight:
+            names += ["device", "store"]
+            done += 1
+        names.append("issue")
+    names += ["device", "store"] * (blocks - done)
+    return ["dataset/bucketize/" + n for n in names]
+
+
 @pytest.mark.parametrize("entry", [_constructed, _pushed],
                          ids=["construct", "push_rows"])
 def test_ingest_blocks_nest_under_bucketize(wide_table, entry):
-    """Device ingest is the gather, three spans a row block and the cast,
-    all under ``dataset/bucketize``, whichever call pushed the rows."""
+    """Device ingest is a pipeline of row blocks under
+    ``dataset/bucketize``: one ``fill``, ``issue``, ``device`` and
+    ``store`` a block, three blocks in flight, whichever call pushed the
+    rows; the table's columns are all kept in order, so each fill is a
+    slice."""
     X, y = wide_table
     tree = entry(X, y)
     bucketize = next(r for r in tree if r["name"] == "dataset/bucketize")
     kids = _children(tree, bucketize)
     blocks = -(-INGEST_ROWS // BLOCK)
-    assert bucketize["counts"] == {"blocks": blocks, "block_rows": BLOCK}
-    assert _names(kids) == (["dataset/bucketize/gather"]
-                            + ["dataset/bucketize/fill",
-                               "dataset/bucketize/device",
-                               "dataset/bucketize/store"] * blocks
-                            + ["dataset/bucketize/cast"])
+    assert bucketize["counts"] == {"blocks": blocks, "block_rows": BLOCK,
+                                   "in_flight": 3, "column_slice": 1}
+    assert _names(kids) == _pipeline_order(blocks, 3)
     assert all(bucketize["start_ns"] <= r["start_ns"] <= r["end_ns"]
                <= bucketize["end_ns"] for r in kids)
     assert not any(_children(tree, r) for r in kids)
     fills = [r["counts"]["rows"] for r in kids
              if r["name"] == "dataset/bucketize/fill"]
     assert sum(fills) == INGEST_ROWS and fills[-1] == INGEST_ROWS % BLOCK
-    assert kids[0]["counts"] == {"bytes": INGEST_ROWS * INGEST_COLS * 4}
+    ups = [r["counts"]["bytes_up"] for r in kids
+           if r["name"] == "dataset/bucketize/issue"]
+    assert ups == [BLOCK * INGEST_COLS * 4] * blocks
 
 
 @pytest.mark.parametrize("key, per_block", [
@@ -623,10 +640,10 @@ def test_ingest_bytes_sum_over_whole_blocks(wide_table, key, per_block):
 
 
 def test_ingest_children_cover_construct(wide_table):
-    """What a reader of the ingest phases finds: the gather, the host
-    copies and the waits on the device fill ``dataset/bucketize``, and
-    the children of ``dataset/construct`` (the table's among them) leave
-    it little time of its own."""
+    """What a reader of the ingest phases finds: the host copies (fill,
+    store) and the dispatches and waits on the device (issue, device)
+    fill ``dataset/bucketize``, and the children of ``dataset/construct``
+    (the table's among them) leave it little time of its own."""
     from readers import program_span
     X, y = wide_table
     tree = _constructed(X, y)
@@ -635,9 +652,8 @@ def test_ingest_children_cover_construct(wide_table):
     bucketize = next(r for r in tree if r["name"] == "dataset/bucketize")
     phases = [program_span.read({}, {"root": "dataset/construct",
                                      "match": [f"^{name}$"]})
-              for name in ("dataset/bucketize/gather",
-                           "dataset/bucketize/(fill|store|cast)",
-                           "dataset/bucketize/device")]
+              for name in ("dataset/bucketize/(fill|store)",
+                           "dataset/bucketize/(issue|device)")]
     whole = (bucketize["end_ns"] - bucketize["start_ns"]) / 1e9
     assert 0.9 * whole <= sum(phases) <= whole
     unnamed = program_span.read({}, {"root": "dataset/construct",
