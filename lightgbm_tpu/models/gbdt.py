@@ -163,6 +163,10 @@ class GBDT:
         # (DeviceTree, bias_to_fold). Drained in ONE device_get by
         # _materialize_models().
         self._pending: List[Tuple[Any, float]] = []
+        # the open train/chunk span, and a chunk record's bias list (by
+        # id) -> its span, which the drain gives the chunk's cat_splits
+        self._chunk_span = None
+        self._chunk_spans: Dict[int, Any] = {}
         # how often train_one_iter really checks the "no more splits"
         # condition; every check costs one host sync, so it is amortized
         self._stop_check_interval = 32
@@ -1031,12 +1035,16 @@ class GBDT:
         else:
             flat = [host]
             bias = [bias]
+        sp = self._chunk_spans.pop(id(bias), None)
         out = []
         for h, b in zip(flat, bias):
             tree = self._device_tree_to_host(h)
             if abs(b) > _KEPS:
                 tree.add_bias(b)
             out.append(tree)
+        if sp is not None:
+            # the chunk's categorical splits, on its train/chunk span
+            sp.counts["cat_splits"] = sum(int(t.num_cat) for t in out)
         return out
 
     def _check_stopped(self) -> bool:
@@ -1217,8 +1225,13 @@ class GBDT:
         along."""
         n_pad = max(n, int(n_pad or n))
         d0 = self.dispatch_count
-        with span("train/chunk", trees=n, trees_padded=n_pad):
+        # cat_splits is filled in when the chunk's trees reach the host
+        # (the drain's own transfer, _host_record_to_trees): no new sync
+        with span("train/chunk", trees=n, trees_padded=n_pad, cat_splits=0,
+                  wave_route=self._wave_route() or "none") as sp:
+            self._chunk_span = sp
             mvals = self._train_chunk(n, n_pad)
+            self._chunk_span = None
             span_count(dispatches=self.dispatch_count - d0,
                        valid_rows=sum(v.num_data for v in self.valid_sets),
                        metric_columns=0 if mvals is None
@@ -1305,6 +1318,8 @@ class GBDT:
                 float(init_scores[k]) if (self.iter + i) == 0 else 0.0
                 for i in range(n) for k in range(K)]
             record = (tree_stack, biases)
+            if self._chunk_span is not None:
+                self._chunk_spans[id(biases)] = self._chunk_span
             if self._drain is not None:
                 self._drain.submit(record)
             else:

@@ -12,12 +12,13 @@ Vectorized TPU formulation of FeatureHistogram::FindBestThresholdCategoricalInne
     l2 -> lambda_l2 + cat_l2. Both direction scans become cumulative sums
     over the sorted histogram, evaluated for all features at once.
 
-Deviation from the reference (documented): the reference's
-`cnt_cur_group >= min_data_per_group` *stepping* rule (it skips candidate
-prefixes until a new group has accumulated min_data_per_group rows,
-feature_histogram.cpp:316) is sequential; here every prefix that satisfies
-the hard left/right count+hessian constraints is evaluated. The
-`right_count >= min_data_per_group` hard constraint is kept.
+The reference's stepping rule is kept (feature_histogram.cpp:316): a
+prefix is evaluated only once `min_data_per_group` rows have gathered
+since the last evaluated one (prefixes that fail the left count/hessian
+constraints add their rows but are not evaluated; the right side's
+constraints end the scan). It is sequential, so it runs as a scan of at
+most `max_cat_threshold` steps over the prefixes, vectorized over
+features.
 
 The chosen left-set is returned as a BIN-index bitset ([W] uint32 words);
 bin 0 (the missing/other-category bin) is never selected, so missing values
@@ -29,6 +30,7 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 from .split import (NEG_INF, FeatureMeta, SplitHyperParams, SplitResult,
@@ -60,6 +62,24 @@ def _gain_and_outputs(lg, lh, lc, rg, rh, rc, hp, parent_output,
     gain = (leaf_gain_given_output(lg, lh, hp, lout)
             + leaf_gain_given_output(rg, rh, hp, rout))
     return gain, lout, rout
+
+
+def _group_steps(lc, ok, min_data_per_group, steps: int):
+    """[F, B] bool: the prefixes the stepping rule evaluates. Prefix i is
+    evaluated when it passes the constraints (``ok``) and at least
+    ``min_data_per_group`` rows (``lc``: the prefixes' cumulative counts)
+    have gathered since the last evaluated prefix; none past ``steps``
+    (max_cat_threshold bounds the prefixes)."""
+    F = lc.shape[0]
+
+    def step(last, x):
+        c, o = x
+        ev = o & (c - last >= min_data_per_group)
+        return jnp.where(ev, c, last), ev
+
+    _, ev = jax.lax.scan(step, jnp.zeros((F,), lc.dtype),
+                         (lc[:, :steps].T, ok[:, :steps].T))
+    return jnp.pad(ev.T, ((0, 0), (0, lc.shape[1] - steps)))
 
 
 def find_best_split_categorical(
@@ -146,6 +166,8 @@ def find_best_split_categorical(
         ok = ((pos < jnp.minimum(used_bin, max_num_cat)[:, None])
               & ~onehot_f & is_cat[:, None]
               & constraints_ok(lh, lc, rh, rc, cat.min_data_per_group))
+        ok = ok & _group_steps(lc, ok, cat.min_data_per_group,
+                               min(B, cat.max_cat_threshold))
         gain = jnp.where(ok & (gain > min_gain_shift), gain, NEG_INF)
         stats = (lg, lh, lc, rg, rh, rc, lout, rout)
         return gain, stats, rank

@@ -196,25 +196,35 @@ class _SimState(NamedTuple):
     #   landed this wave (intermediate-method serialization)
 
 
+# rows a partition of a categorical table's root histogram (at 100M
+# rows, 24 partitions). Partitions and exact counts are on for
+# categorical tables only, so that a numeric table's kernels keep their
+# text: a temporary fork (ROADMAP C17)
+ROOT_PART_ROWS = 1 << 22
+
+
 def wave_route(X_t: jnp.ndarray, cfg: GrowConfig) -> str:
     """How grow_tree_wave moves rows between leaves and builds its wave
     histograms, from what the table shows (the grower and the trainer's
     `wave_decide_trees` counter both ask here):
 
       "mega"      the wave megakernel (<= 32 dense storage columns, no
-                  categorical, no EFB, not feature-parallel, col-wise
-                  histograms): relabel, membership and histogram in one
-      "decide"    past it, numeric and unbundled: wave_decide_pallas
-                  decides each row in the kernel, then the slots kernel
-      "matrix"    past it, categorical or EFB: the go-left bits of every
-                  row under every entry as a [128, N] XLA matrix (bitset
-                  words, bundle unpack), then wave_apply_pallas
+                  EFB, not feature-parallel, col-wise histograms):
+                  relabel, membership and histogram in one
+      "decide"    past it, unbundled: wave_decide_pallas decides each
+                  row in the kernel, then the slots kernel
+      "matrix"    EFB: the go-left bits of every row under every entry as
+                  a [128, N] XLA matrix (bundle unpack), then
+                  wave_apply_pallas
       "portable"  no Pallas (CPU meshes, >8-bit bins): XLA select chains
+
+    A categorical table takes the route a numeric table of its width
+    takes: its kernels test each entry's bitset (wave_cat_words).
     """
     from .histogram import _use_pallas
     if not _use_pallas(X_t, cfg.num_bins_padded):
         return "portable"
-    if cfg.bundled or cfg.has_categorical:
+    if cfg.bundled:
         return "matrix"
     # hist_impl="rowwise" (config pin or autotune) takes the two-pass path
     # so its waves actually run the row-wise multi-value kernel — the
@@ -253,10 +263,13 @@ def grow_tree_wave(
 
     # how a wave moves rows between leaves and builds its histograms
     # (wave_route): the wave megakernel; past it (use_apply) the
-    # F-gridded slots kernel after wave_decide_pallas (numeric tables) or
-    # after wave_apply_pallas over a [128, N] decision matrix
-    # (categorical, EFB); XLA select chains where Pallas does not run
+    # F-gridded slots kernel after wave_decide_pallas or, on EFB
+    # storage, after wave_apply_pallas over a [128, N] decision matrix;
+    # XLA select chains where Pallas does not run
     route = wave_route(X_t, cfg)
+    # a categorical search reads counts (cat_smooth, min_data_per_group):
+    # on such a table the children's counts are counted, not synthesized
+    exact_counts = cfg.has_categorical
     use_mega = route == "mega"
     use_apply = route in ("decide", "matrix")
     if use_mega:
@@ -267,7 +280,10 @@ def grow_tree_wave(
         from .histogram_pallas import _compute_dims
         B_lane = _compute_dims(B)[0]
         C_stat = 2          # (grad, hess) in both float and quantized mode
-        kcap = 3_400_000 // (C_stat * 32 * B_lane * 4)
+        # a categorical table's kernels cover its own columns (below);
+        # a numeric table's the 32 rows of the padded table, as before
+        F_k = X_t.shape[0] if cfg.has_categorical else 32
+        kcap = 3_400_000 // (C_stat * F_k * B_lane * 4)
         kcap = max(1 << (kcap.bit_length() - 1), 1) if kcap >= 1 else 1
         buckets = _wave_buckets(L, min(kcap, 128))
         # wide-bin megakernel waves run the hi/lo one-hot decomposition
@@ -544,12 +560,13 @@ def grow_tree_wave(
         if not cfg.has_categorical:
             merged, use_cat, bits = num, jnp.zeros((), bool), nob
         else:
-            catres, bitset = find_best_split_categorical(
-                hist, sum_g, sum_h, count, out, meta_use, hp, cfg.cat,
-                fmask,
-                leaf_min=bmin if has_mono else None,
-                leaf_max=bmax if has_mono else None,
-                cegb_pen=pen)
+            with jax.named_scope("lgbm_cat_split"):
+                catres, bitset = find_best_split_categorical(
+                    hist, sum_g, sum_h, count, out, meta_use, hp, cfg.cat,
+                    fmask,
+                    leaf_min=bmin if has_mono else None,
+                    leaf_max=bmax if has_mono else None,
+                    cegb_pen=pen)
             use_cat = catres.gain > num.gain
             merged = SplitResult(*[
                 jnp.where(use_cat, cv, nv) for cv, nv in zip(catres, num)])
@@ -687,10 +704,31 @@ def grow_tree_wave(
     # feature-parallel builds the root on its feature slice only (the
     # whole point of the learner: 1/n of the histogram work per shard)
     with jax.named_scope("train/root_histogram"):
-        hist_root_local = build_histogram(X_hist if fp else X_t, vals0, B,
-                                          cfg.rows_per_chunk,
-                                          tiers=cfg.hist_tiers,
-                                          impl=cfg.hist_impl)
+        X_root = X_hist if fp else X_t
+        if cfg.has_categorical and N > ROOT_PART_ROWS:
+            # in row partitions, summed apart and then together, so the
+            # root's float32 bins do not drift (histogram_pallas
+            # wave_parts); a categorical search sorts the bins they
+            # leave in every larger child. The partitions are a vmapped
+            # axis: one call, whose kernels take it as a grid axis
+            from ..utils import round_up
+            P = -(-N // ROOT_PART_ROWS)
+            rows = round_up(-(-N // P), 1024)
+
+            def parts(a):
+                a = jnp.pad(a, ((0, 0), (0, P * rows - N)))
+                return a.reshape(a.shape[0], P, rows).transpose(1, 0, 2)
+
+            hist_root_local = jnp.sum(jax.vmap(
+                lambda x, v: build_histogram(
+                    x, v, B, cfg.rows_per_chunk, tiers=cfg.hist_tiers,
+                    impl=cfg.hist_impl))(parts(X_root), parts(vals0)),
+                axis=0)
+        else:
+            hist_root_local = build_histogram(X_root, vals0, B,
+                                              cfg.rows_per_chunk,
+                                              tiers=cfg.hist_tiers,
+                                              impl=cfg.hist_impl)
         hist_root = exchange_hist(hist_root_local, psum, 0)
     # the exchanged root histogram is whole on every rank here (the
     # ownership modes reduce-scatter the WAVES, not the root; a
@@ -913,11 +951,14 @@ def grow_tree_wave(
     # bundled (EFB) storage, categorical splits, or wide feature counts.
     if use_mega:
         from .histogram_pallas import (wave_pass_pallas,
-                                       wave_relabel_pallas, N_BLK)
+                                       wave_relabel_pallas, wave_parts,
+                                       N_BLK)
         from ..utils import round_up
         F0 = X_t.shape[0]
         n_blk = N_BLK if N >= N_BLK else max(round_up(N, 256), 256)
-        Np = round_up(N, n_blk)
+        # a categorical table's wave pass sums row partitions apart
+        Np = round_up(N, n_blk * (wave_parts(N) if cfg.has_categorical
+                                  else 1))
         # pad/convert once per tree; every wave kernel reuses these
         X_mega = jnp.pad(X_t.astype(jnp.int8),
                          ((0, 32 - F0), (0, Np - N)))
@@ -928,25 +969,37 @@ def grow_tree_wave(
 
         def make_mega_branch(K):
             def branch(args):
-                lor, tbl16 = args
-                new_lor, hist = wave_pass_pallas(X_mega, vals_mega, lor,
-                                                 tbl16, K, B,
-                                                 interpret=_interp_m,
-                                                 wide_lo=mega_wide_lo)
-                hist = hist[:, :, :F0, :]
+                lor, tbl16, *cat = args
+                # a categorical table's kernel also counts each slot's
+                # in-bag rows (the smaller children's exact counts)
+                out = wave_pass_pallas(X_mega, vals_mega, lor, tbl16, K, B,
+                                       interpret=_interp_m,
+                                       wide_lo=mega_wide_lo,
+                                       cat=cat[0] if cat else None,
+                                       in_bag=cnt_row if cat else None,
+                                       features=F0 if cat else 0)
+                new_lor, hist = out[0], out[1][:, :, :F0, :]
                 if K < KMAX:
                     hist = jnp.pad(
                         hist, ((0, KMAX - K), (0, 0), (0, 0), (0, 0)))
-                return new_lor, hist
+                if not cat:
+                    return new_lor, hist
+                cnt = jnp.pad(out[2], (0, KMAX - K)).astype(jnp.float32)
+                return new_lor, hist, cnt
             return branch
 
         def relabel_only_branch(args):
             # final wave of a tree: splits to apply, no candidates left —
             # skip the histogram contraction entirely
-            lor, tbl16 = args
+            lor, tbl16, *cat = args
             new_lor = wave_relabel_pallas(X_mega, vals_mega, lor, tbl16, B,
-                                          interpret=_interp_m)
-            return new_lor, jnp.zeros((KMAX, C, F0, B), hist_dtype)
+                                          interpret=_interp_m,
+                                          cat=cat[0] if cat else None,
+                                          features=F0 if cat else 0)
+            hist = jnp.zeros((KMAX, C, F0, B), hist_dtype)
+            if not cat:
+                return new_lor, hist
+            return new_lor, hist, jnp.zeros((KMAX,), jnp.float32)
 
         mega_branches = [relabel_only_branch] \
             + [make_mega_branch(K) for K in buckets]
@@ -1307,6 +1360,14 @@ def grow_tree_wave(
                 # leaf — the kernel applies every active table entry)
                 tbl16 = jnp.pad(tbl16, ((0, 0), (0, 128 - KMAX)),
                                 constant_values=-1)
+            # a categorical table's kernels decide every entry by its
+            # bin-domain bitset, a second operand beside the table
+            kargs = (tbl16,)
+            if cfg.has_categorical and route != "matrix":
+                from .histogram_pallas import wave_cat_words
+                kargs = wave_cat_words(tbl16, iscat2, bits2,
+                                       st.best_is_cat[cand],
+                                       st.best_bitset[cand])
         if use_mega:
             # ---- wave megakernel: relabel + candidate membership + slot
             # histogram in one device pass. The histogram width tracks
@@ -1320,21 +1381,22 @@ def grow_tree_wave(
                     .astype(jnp.int32), len(buckets) - 1),
                 0)
             with jax.named_scope("train/wave_pass"):
-                leaf_of_row, hist_wave = jax.lax.switch(
-                    kidx_m, mega_branches, (st.leaf_of_row, tbl16))
+                leaf_of_row, hist_wave, *cnt_wave = jax.lax.switch(
+                    kidx_m, mega_branches, (st.leaf_of_row,) + kargs)
             st = st._replace(leaf_of_row=leaf_of_row)
             slot_small = None
         elif route == "decide":
-            # ---- numeric tables: the kernel reads the binned rows and
-            # decides each row under its own entry's split alone, so no
-            # [K, N] array reaches HBM
+            # ---- the kernel reads the binned rows and decides each row
+            # under its own entry's split alone, so no [K, N] array
+            # reaches HBM
             from .histogram_pallas import wave_decide_pallas
             from .histogram import pallas_interpret
             leaf_of_row, slot_small = wave_decide_pallas(
-                X_t, st.leaf_of_row, tbl16, interpret=pallas_interpret())
+                X_t, st.leaf_of_row, kargs[0], interpret=pallas_interpret(),
+                cat=kargs[1] if len(kargs) > 1 else None)
             st = st._replace(leaf_of_row=leaf_of_row)
         elif use_apply:
-            # ---- categorical/EFB tables: per-(entry, row) go-left
+            # ---- EFB tables: per-(entry, row) go-left
             # decisions are INDEPENDENT of leaf membership, so they are
             # precomputed here as a [128, N] bit matrix in plain XLA
             # (vectorized over entries — bundle unpack and categorical
@@ -1384,6 +1446,26 @@ def grow_tree_wave(
         # ---- HIST + SEARCH, skipped entirely when no candidates (e.g.
         # the final wave of a tree)
         def spec_branch(st):
+            bs_c = bs
+            if exact_counts:
+                # the candidates' children take their exact in-bag row
+                # counts (the smaller child's counted, the larger's the
+                # parent's less it), as the reference's data partition
+                # gives them; a categorical search's cat_smooth and
+                # min_data_per_group steps read them
+                if use_mega:
+                    small_n = cnt_wave[0]
+                else:
+                    small_n = jnp.sum(jnp.where(
+                        slot_small[None, :]
+                        == jnp.arange(KMAX, dtype=jnp.int32)[:, None],
+                        cnt_row[None, :], 0.0), axis=1)
+                par_n = st.tree.leaf_count[cand].astype(jnp.float32)
+                ln = jnp.where(smaller_is_left, psum(small_n),
+                               par_n - psum(small_n))
+                bs_c = bs._replace(
+                    left_count=ln.astype(bs.left_count.dtype),
+                    right_count=(par_n - ln).astype(bs.right_count.dtype))
             if use_mega:
                 hist_local = hist_wave
             else:
@@ -1460,7 +1542,7 @@ def grow_tree_wave(
                          st.leaf_sum_g[rs_i] if research_own else None)
             sh_lr = cat3(bs.left_sum_h, bs.right_sum_h,
                          st.leaf_sum_h[rs_i] if research_own else None)
-            c_lr = cat3(bs.left_count, bs.right_count,
+            c_lr = cat3(bs_c.left_count, bs_c.right_count,
                         st.tree.leaf_count[rs_i].astype(
                             bs.left_count.dtype) if research_own
                         else None)
@@ -1687,6 +1769,11 @@ def grow_tree_wave(
                 bfl=scat(st.bfl, forced_lr[:KMAX]),
                 bfr=scat(st.bfr, forced_lr[KMAX:2 * KMAX]),
             )
+            if exact_counts:
+                st2 = st2._replace(best=st2.best._replace(
+                    left_count=scat(st2.best.left_count, bs_c.left_count),
+                    right_count=scat(st2.best.right_count,
+                                     bs_c.right_count)))
             if research_own:
                 # install the stale leaves' re-searched bests and clear
                 # their staleness (they re-enter as candidates next wave)

@@ -437,34 +437,117 @@ def _masked_pick(m, col):
     return jnp.sum(jnp.where(m, col, 0), axis=0)          # [R] i32
 
 
+# ---- categorical tables: every entry's bitset beside the packed table.
+# A categorical split sends a row left when bit `bin` of its left-set is
+# set (ops/categorical.py; bin 0, the other/NaN bin, is never in it). On
+# a table that holds one, the kernels decide EVERY entry by a bitset over
+# the bin domain: a numeric entry's is the prefix {b <= thr}, so
+# `col == miss_bin ? default_left : bit(col)` is its own rule unchanged,
+# and a categorical entry's missing rule is turned off (its bin 0 is in
+# the bitset's zeros). The bitsets are a second operand, present only on
+# such a table (a numeric table's kernels take the operands and the body
+# they had before): the kernels read them as bytes, and a row picks its
+# entry's bytes with one small matmul against its entry mask (the MXU,
+# not W masked sums on the VPU), then byte `bin >> 3`, bit `bin & 7`.
+
+def wave_cat_words(table, app_is_cat, app_bits, cand_is_cat, cand_bits):
+    """(table', words): the semantic table with the categorical entries'
+    missing type set to none, and [128, 2W] i32 bitset words for the
+    applied entries (app_*: [K] bool, [K, W] u32) and the candidates."""
+    t = table.astype(jnp.int32)
+    W = app_bits.shape[1]
+
+    def words(is_cat, bits, thr):
+        K = is_cat.shape[0]
+        n = jnp.clip(thr[:K, None] + 1 - 32 * jnp.arange(W)[None, :],
+                     0, 32).astype(jnp.uint32)             # [K, W]
+        prefix = jnp.where(n >= 32, jnp.uint32(0xFFFFFFFF),
+                           (jnp.uint32(1) << jnp.minimum(n, 31))
+                           - jnp.uint32(1))
+        w = jnp.where(is_cat[:, None], bits.astype(jnp.uint32), prefix)
+        w = jax.lax.bitcast_convert_type(w, jnp.int32)
+        return jnp.pad(w, ((0, 128 - K), (0, 0)))
+
+    def no_miss(mt, is_cat):
+        K = is_cat.shape[0]
+        return mt.at[:K].set(jnp.where(is_cat, 0, mt[:K]))
+
+    t = t.at[_T_APP_MT].set(no_miss(t[_T_APP_MT], app_is_cat)) \
+        .at[_T_CAND_MT].set(no_miss(t[_T_CAND_MT], cand_is_cat))
+    cw = jnp.concatenate([words(app_is_cat, app_bits, t[_T_APP_THR]),
+                          words(cand_is_cat, cand_bits, t[_T_CAND_THR])],
+                         axis=1)
+    return t, cw
+
+
+def _cat_operand(cat):
+    """(kernel kwargs, operands, in_specs) for an optional [128, 2W]
+    bitset operand: nothing at all on a numeric table. The kernels take
+    it as bytes, [8W, 128] float32 (0..255): row ``4w + j`` is byte j of
+    word w of each entry's applied bitset (bins ``32w + 8j`` up), rows
+    from 4W on the candidate's."""
+    if cat is None:
+        return {}, [], []
+    W = cat.shape[1] // 2
+    byts = (cat.astype(jnp.int32)[:, :, None]
+            >> (8 * jnp.arange(4, dtype=jnp.int32))) & 0xFF   # [128, 2W, 4]
+    byts = byts.reshape(128, 8 * W).T.astype(jnp.float32)
+    return ({"W": W}, [byts],
+            [pl.BlockSpec((8 * W, 128), lambda n: (0, 0),
+                          memory_space=pltpu.VMEM)])
+
+
+def _pick_bytes(m, cat_ref, first, W):
+    """The bitset bytes of each row's own table entry, [4W, R]: one small
+    matmul of the byte table (rows ``first..first+4W`` of cat_ref, [4W,
+    128] f32 holding 0..255, entry-major along the lanes) with the rows'
+    [128, R] entry mask. Each output sums one byte x 1 product, so
+    bfloat16 operands and a float32 result are exact."""
+    tb = cat_ref[first:first + 4 * W, :].astype(jnp.bfloat16)
+    return jax.lax.dot_general(
+        tb, m.astype(jnp.bfloat16), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(jnp.int32)
+
+
+def _bit_of(byts, col):
+    """Bit ``col`` of a row's bin-domain bitset ([4W, R] bytes: byte
+    ``col >> 3``, bit ``col & 7``)."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, byts.shape, 0)
+    byte = jnp.sum(jnp.where(iota == (col >> 3)[None, :], byts, 0), axis=0)
+    return (byte >> (col & 7)) & 1
+
+
 def _wave_logic(x_ref, v_ref, lor_ref, tbl_ref, nl0_ref, newlor_ref, *,
-                K, C, F, HB, quantized, with_hist):
+                K, C, F, HB, quantized, with_hist, cat_ref=None, W=0):
     """Shared relabel + candidate-membership body. The APPLY side always
     walks all 128 table rows (inactive rows have leaf -1 and never match
     — [128, R] compares cost ~2 VPU ops/row-block, so there is nothing
     to bucket), while the candidate side is bucketed to K because the
     MXU contraction cost scales with it. Returns oh_small [K, R] (None
-    when with_hist=False)."""
+    when with_hist=False). With ``cat_ref`` (a categorical table, W > 0)
+    every entry decides by its bitset (wave_cat_words)."""
     R = lor_ref.shape[1]
     xx_log = x_ref[0:F, :].astype(jnp.int32)               # [F, R]
     if HB > 1:
         xx_log = xx_log & 0xFF
     iota_f = jax.lax.broadcasted_iota(jnp.int32, (F, R), 0)
 
-    def go_left(p):
+    def go_left(p, byts=None):
         feat = p & 31
         thr = (p >> 5) & 0xFF
         dl = (p >> 13) & 1
         mb = (p >> 14) & 0x1FF
         col = jnp.sum(jnp.where(feat[None, :] == iota_f, xx_log, 0),
                       axis=0)                              # [R]
+        if byts is not None:
+            return jnp.where(col == mb, dl, _bit_of(byts, col))
         return jnp.where(col == mb, dl, (col <= thr).astype(jnp.int32))
 
     # ---- applied splits: relabel rows of split leaves
     lor = lor_ref[0, :]                                    # [R] i32
     mA = lor[None, :] == tbl_ref[:, 0:1]                   # [128, R]
     pA = _masked_pick(mA, tbl_ref[:, 1:2])
-    glA = go_left(pA)
+    glA = go_left(pA, _pick_bytes(mA, cat_ref, 0, W) if W else None)
     nl0 = nl0_ref[0]
     new_lor = jnp.where((((pA >> 24) & 1) == 1) & (glA == 0),
                         nl0 + ((pA >> 25) & 127), lor)
@@ -475,54 +558,76 @@ def _wave_logic(x_ref, v_ref, lor_ref, tbl_ref, nl0_ref, newlor_ref, *,
     # ---- candidate membership on the post-apply leaf
     mC = new_lor[None, :] == tbl_ref[:K, 2:3]              # [K, R]
     pC = _masked_pick(mC, tbl_ref[:K, 3:4])
-    glC = go_left(pC)
+    # the bytes are picked over all 128 entries (those past K are
+    # inactive and match no row), so the matmul's contraction is whole
+    glC = go_left(pC, _pick_bytes(new_lor[None, :] == tbl_ref[:, 2:3],
+                                  cat_ref, 4 * W, W) if W else None)
     silC = (pC >> 23) & 1
     in_small = (((pC >> 24) & 1) == 1) & (glC == silC)     # [R]
     return mC & in_small[None, :]                          # [K, R]
 
 
-def _wave_relabel_kernel(x_ref, v_ref, lor_ref, tbl_ref, nl0_ref,
-                         newlor_ref, *, C, F, HB, quantized):
+def _wave_relabel_kernel(x_ref, v_ref, lor_ref, tbl_ref, nl0_ref, *refs,
+                         C, F, HB, quantized, W=0):
     """Relabel-only wave (a tree's final wave has applied splits but no
     candidates left — paying a full histogram pass there is pure waste)."""
+    cat_ref, (newlor_ref,) = (refs[0], refs[1:]) if W else (None, refs)
     _wave_logic(x_ref, v_ref, lor_ref, tbl_ref, nl0_ref, newlor_ref,
-                K=0, C=C, F=F, HB=HB, quantized=quantized, with_hist=False)
+                K=0, C=C, F=F, HB=HB, quantized=quantized, with_hist=False,
+                cat_ref=cat_ref, W=W)
 
 
-def _wave_kernel(x_ref, v_ref, lor_ref, tbl_ref, nl0_ref, newlor_ref,
-                 out_ref, *, K, C, LO, HB, F, Fc, quantized):
+def _wave_kernel(x_ref, v_ref, lor_ref, tbl_ref, nl0_ref, *refs,
+                 K, C, LO, HB, F, Fc, quantized, W=0):
     """Grid (N_blocks,). x_ref [F_pad, R]; v_ref [C, R]; lor_ref [1, R];
-    tbl_ref [128, 8] i32 packed; nl0_ref [1] i32 in SMEM;
-    newlor_ref [1, R]; out_ref [HB*C*K, Fh*LO] (VMEM-resident across the
-    whole grid).
+    tbl_ref [128, 8] i32 packed; nl0_ref [1] i32 in SMEM; on a
+    categorical table (W > 0) also cat_ref [8W, 128] f32 bitset bytes and
+    inb_ref [1, R] int8 in-bag rows; newlor_ref [1, R]; out_ref
+    [HB*C*K, Fh*LO] (VMEM-resident across the whole grid); on a
+    categorical table cnt_ref [K, 128] i32, each slot's in-bag rows
+    (every lane the same; resident too).
 
     All per-row logic runs either on full [F, R] / [K, R] tiles or on a
     handful of [1, R] ops — 1-sublane [1, R] chains are ~8x below VPU
     width, so the per-feature column extraction is a masked [F, R]
     reduction, and per-entry table values arrive as ONE packed int32 via
     a masked [K, R] reduction."""
-    n = pl.program_id(0)
+    if W:
+        cat_ref, inb_ref, newlor_ref, out_ref, cnt_ref = refs
+    else:
+        (newlor_ref, out_ref), cat_ref = refs, None
+    # a categorical table's grid is (partition, block): each partition
+    # starts its own histogram (wave_parts)
+    n = pl.program_id(1 if W else 0)
 
     @pl.when(n == 0)
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
+        if W:
+            cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
     oh_small = _wave_logic(x_ref, v_ref, lor_ref, tbl_ref, nl0_ref,
                            newlor_ref, K=K, C=C, F=F, HB=HB,
-                           quantized=quantized, with_hist=True)
+                           quantized=quantized, with_hist=True,
+                           cat_ref=cat_ref, W=W)
+    if W:
+        # the smaller children's exact in-bag row counts
+        inb = inb_ref[0, :].astype(jnp.int32) > 0
+        cnt_ref[...] += jnp.sum(jnp.where(oh_small & inb[None, :], 1, 0),
+                                axis=1, keepdims=True)
 
     # ---- slot histogram (shared contraction)
-    W = _make_W(v_ref[...], oh_small, C, K, quantized)
+    Wv = _make_W(v_ref[...], oh_small, C, K, quantized)
     xx_all = x_ref[0:F, :].astype(jnp.int32)
     if HB > 1:
         xx_all = xx_all & 0xFF
-    _hist_chunks(xx_all, W, out_ref, Fc, C=C, K=K, LO=LO, HB=HB,
+    _hist_chunks(xx_all, Wv, out_ref, Fc, C=C, K=K, LO=LO, HB=HB,
                  quantized=quantized)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("num_slots", "num_bins", "interpret",
-                                    "wide_lo"))
+                                    "wide_lo", "features"))
 def wave_pass_pallas(
     X_binned_t: jnp.ndarray,   # [F, N] int8/uint8 (feature-major, F <= 32)
     vals: jnp.ndarray,         # [C, N] f32 (bag-masked) or int8 (quantized)
@@ -532,15 +637,26 @@ def wave_pass_pallas(
     num_bins: int,
     interpret: bool = False,
     wide_lo: int = 128,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
+    cat: jnp.ndarray | None = None,  # [128, 2W] i32 (wave_cat_words)
+    in_bag: jnp.ndarray | None = None,  # [N'] in-bag rows (with cat)
+    features: int = 0,
+) -> tuple[jnp.ndarray, ...]:
     """Fused wave pass: returns (new_leaf_of_row [N] i32,
-    hist [K, C, F, num_bins]). X/vals may be pre-padded (F to 32, rows to
+    hist [K, C, F, num_bins]), and on a categorical table a third,
+    counts [K] i32: each slot's in-bag rows (``in_bag`` > 0), which the
+    grower takes as the smaller children's exact counts. X/vals may be pre-padded (F to 32, rows to
     a block multiple) by the caller so the pad/convert cost is paid once
     per tree instead of once per wave; `leaf_of_row` keeps the true row
     count and the outputs are sliced to it. `wide_lo` selects the
     wide-bin (>128) hi/lo decomposition (_compute_dims); the VMEM
     footprint of the output block is identical for either choice
-    (HB*LO = B), so the caller's K cap is unaffected."""
+    (HB*LO = B), so the caller's K cap is unaffected. `cat`, on a
+    categorical table, is every entry's bitset (wave_cat_words); without
+    it the kernel is the numeric one, operand for operand. `features`,
+    with `cat`, is the table's own column count when X is pre-padded
+    past it: the histogram and the row's column pick cover those rows of
+    X alone (a numeric table's kernel covers every row of X). With `cat`
+    the rows are summed in partitions (wave_parts)."""
     F, NX = X_binned_t.shape
     C = vals.shape[0]
     N = leaf_of_row.shape[0]
@@ -549,15 +665,17 @@ def wave_pass_pallas(
     B, LO, HB = _compute_dims(num_bins, wide_lo)
     assert F <= 32, "wave megakernel requires F <= 32 storage columns"
     Fp = 32
-    rows = HB * C * K
-    Fc = _feat_chunk(F, LO, rows)
-    Fh = _round_up(F, Fc)
     n_blk = N_BLK if NX >= N_BLK else max(_round_up(NX, 256), 256)
-    Np = _round_up(NX, n_blk)
-
+    P = wave_parts(NX) if cat is not None else 1
+    Np = _round_up(NX, n_blk * P)
     X = X_binned_t.astype(jnp.int8)
     if Fp != F or Np != NX:
         X = jnp.pad(X, ((0, Fp - F), (0, Np - NX)))
+    if cat is not None and features:
+        F = features
+    rows = HB * C * K
+    Fc = _feat_chunk(F, LO, rows)
+    Fh = _round_up(F, Fc)
     v = vals if quantized else vals.astype(jnp.float32)
     if v.shape[1] != Np:
         v = jnp.pad(v, ((0, 0), (0, Np - v.shape[1])))
@@ -568,9 +686,19 @@ def wave_pass_pallas(
     nl0 = table[_T_NL0, 0:1].astype(jnp.int32)
 
     out_dtype = jnp.int32 if quantized else jnp.float32
-    grid = (Np // n_blk,)
+    W, cat_in, cat_spec = _cat_operand(cat)
     kernel = functools.partial(_wave_kernel, K=K, C=C, LO=LO, HB=HB, F=F,
-                               Fc=Fc, quantized=quantized)
+                               Fc=Fc, quantized=quantized, **W)
+    name = kernel_name("wave_pass", k=K, b=num_bins, lo=LO, q=quantized,
+                       **({"f": F, "cat": True} if W else {}))
+    if W:
+        newlor, out, cnt = _wave_pass_cat(
+            kernel, name, interpret, X, v, lor, tblp, nl0, cat_in, cat_spec,
+            in_bag, Fp=Fp, n_blk=n_blk, P=P, C=C, K=K, rows=rows, Fh=Fh,
+            LO=LO, out_dtype=out_dtype)
+        hist = _unflatten_hist(out, K, C, F, Fh, LO, HB, num_bins)
+        return newlor[0, :N], hist, cnt
+    grid = (Np // n_blk,)
     newlor, out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -595,7 +723,7 @@ def wave_pass_pallas(
             jax.ShapeDtypeStruct((1, Np), jnp.int32),
             jax.ShapeDtypeStruct((rows, Fh * LO), out_dtype),
         ],
-        name=kernel_name("wave_pass", k=K, b=num_bins, lo=LO, q=quantized),
+        name=name,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=HIST_VMEM_LIMIT_BYTES),
@@ -608,6 +736,76 @@ def wave_pass_pallas(
 
     hist = _unflatten_hist(out, K, C, F, Fh, LO, HB, num_bins)
     return newlor[0, :N], hist
+
+
+# Row partitions of a categorical table's wave pass. A histogram bin's
+# float32 sum over 100M rows, one row block at a time, drifts: adding a
+# block's few units to a sum of 10^5 drops their low bits, one way more
+# often than the other (a simulated hub airport's hessian bin read -28.5
+# of 367,743 after 48,828 blocks). The drift stays in every larger child
+# (its histogram is the parent's less the smaller's), where the bins are
+# hundreds of times smaller, and a categorical search sorts those bins by
+# G / (H + cat_smooth): the order, and the left set, move. Summing P
+# partitions of the rows apart and then the P partial histograms shrinks
+# the accumulator, and with it the drift (0.68 at P = 32, simulated).
+WAVE_PARTS = 32
+
+
+def wave_parts(rows: int) -> int:
+    """Row partitions of a categorical wave pass over ``rows`` rows."""
+    n_blk = N_BLK if rows >= N_BLK else max(_round_up(rows, 256), 256)
+    return max(1, min(WAVE_PARTS, -(-rows // n_blk)))
+
+
+def _wave_pass_cat(kernel, name, interpret, X, v, lor, tblp, nl0, cat_in,
+                   cat_spec, in_bag, *, Fp, n_blk, P, C, K, rows, Fh, LO,
+                   out_dtype):
+    """The categorical megakernel over a (partition, block) grid: each
+    partition accumulates its own histogram and slot counts, summed here
+    (wave_parts)."""
+    Np = X.shape[1]
+    inner = Np // n_blk // P
+    inb = (in_bag > 0).astype(jnp.int8)
+    if inb.shape[0] != Np:
+        inb = jnp.pad(inb[:Np], (0, Np - min(inb.shape[0], Np)))
+
+    def rows_of(o, i):
+        return (0, o * inner + i)
+
+    def whole(o, i):
+        return (0, 0)
+
+    newlor, out, cnt = pl.pallas_call(
+        kernel,
+        grid=(P, inner),
+        in_specs=[
+            pl.BlockSpec((Fp, n_blk), rows_of, memory_space=pltpu.VMEM),
+            pl.BlockSpec((C, n_blk), rows_of, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, n_blk), rows_of, memory_space=pltpu.VMEM),
+            pl.BlockSpec((128, 8), whole, memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(cat_spec[0].block_shape, whole,
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, n_blk), rows_of, memory_space=pltpu.VMEM),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, n_blk), rows_of, memory_space=pltpu.VMEM),
+            pl.BlockSpec((None, rows, Fh * LO), lambda o, i: (o, 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((None, K, 128), lambda o, i: (o, 0, 0),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((1, Np), jnp.int32),
+            jax.ShapeDtypeStruct((P, rows, Fh * LO), out_dtype),
+            jax.ShapeDtypeStruct((P, K, 128), jnp.int32),
+        ],
+        name=name,
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=HIST_VMEM_LIMIT_BYTES),
+    )(X, v, lor[None, :], tblp, nl0, *cat_in, inb[None, :])
+    return newlor, jnp.sum(out, axis=0), jnp.sum(cnt[:, :, 0], axis=0)
 
 
 def _wave_apply_kernel(dec_ref, lor_ref, tbl_ref, nl0_ref, newlor_ref,
@@ -651,7 +849,7 @@ def wave_apply_pallas(
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Split application + candidate smaller-child slot assignment for
-    the WIDE/categorical/EFB wave path: returns (new_leaf_of_row [N],
+    the EFB wave path: returns (new_leaf_of_row [N],
     slot_small [N] with -1 = no candidate). The histogram then runs as a
     separate build_histogram_slots_pallas pass (whose grid feature-blocks
     arbitrary F)."""
@@ -710,18 +908,20 @@ def wave_apply_pallas(
 # F). The table is _pack_wave_table's (its 5-bit feature field ignored)
 # plus the whole feature index in col 4 (applied) and col 5 (candidate).
 
-def _wave_decide_kernel(x_ref, lor_ref, tbl_ref, nl0_ref, newlor_ref,
-                        slot_ref):
+def _wave_decide_kernel(x_ref, lor_ref, tbl_ref, nl0_ref, *refs, W=0):
     """Grid (N_blocks,). x_ref [F_pad, R] uint8 or int8 binned rows; lor_ref
-    [1, R]; tbl_ref [128, 8] i32 packed; nl0_ref [1] i32 SMEM. Outputs
+    [1, R]; tbl_ref [128, 8] i32 packed; nl0_ref [1] i32 SMEM; on a
+    categorical table (W > 0) cat_ref [8W, 128] f32 bitset bytes. Outputs
     new_lor [1, R] and the candidate slot of each row's smaller child
     [1, R] (-1 = none). Rows of x_ref past F, and columns past N in the
     last block, hold whatever the block's copy left there: no entry
     names such a feature, and such a row's results are not written."""
+    cat_ref, (newlor_ref, slot_ref) = (refs[0], refs[1:]) if W \
+        else (None, refs)
     R = lor_ref.shape[1]
     iota_t = jax.lax.broadcasted_iota(jnp.int32, (F_BLK, R), 0)
 
-    def go_left(p, feat):
+    def go_left(p, feat, byts=None):
         # one tile holds the row's feature, the others add 0 (an
         # unmatched row reads feature 0, unused); the signed byte is
         # masked after the sum
@@ -735,19 +935,23 @@ def _wave_decide_kernel(x_ref, lor_ref, tbl_ref, nl0_ref, newlor_ref,
         thr = (p >> 5) & 0xFF
         dl = (p >> 13) & 1
         mb = (p >> 14) & 0x1FF
+        if byts is not None:
+            return jnp.where(col == mb, dl, _bit_of(byts, col))
         return jnp.where(col == mb, dl, (col <= thr).astype(jnp.int32))
 
     lor = lor_ref[0, :]
     mA = lor[None, :] == tbl_ref[:, 0:1]                   # [128, R]
     pA = _masked_pick(mA, tbl_ref[:, 1:2])
-    glA = go_left(pA, _masked_pick(mA, tbl_ref[:, 4:5]))
+    glA = go_left(pA, _masked_pick(mA, tbl_ref[:, 4:5]),
+                  _pick_bytes(mA, cat_ref, 0, W) if W else None)
     new_lor = jnp.where((((pA >> 24) & 1) == 1) & (glA == 0),
                         nl0_ref[0] + ((pA >> 25) & 127), lor)
     newlor_ref[0, :] = new_lor
 
     mC = new_lor[None, :] == tbl_ref[:, 2:3]               # [128, R]
     pC = _masked_pick(mC, tbl_ref[:, 3:4])
-    glC = go_left(pC, _masked_pick(mC, tbl_ref[:, 5:6]))
+    glC = go_left(pC, _masked_pick(mC, tbl_ref[:, 5:6]),
+                  _pick_bytes(mC, cat_ref, 4 * W, W) if W else None)
     in_small = (((pC >> 24) & 1) == 1) & (glC == ((pC >> 23) & 1))
     slot_ref[0, :] = jnp.where(in_small, (pC >> 25) & 127, -1)
 
@@ -758,13 +962,15 @@ def wave_decide_pallas(
     leaf_of_row: jnp.ndarray,  # [N] int32
     table: jnp.ndarray,        # [T_ROWS, 128] int32 semantic wave table
     interpret: bool = False,
+    cat: jnp.ndarray | None = None,  # [128, 2W] i32 (wave_cat_words)
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """wave_apply_pallas's results, (new_leaf_of_row [N], slot_small [N]
     with -1 = no candidate), with each row's decision made in the kernel
-    from the binned rows: numeric splits on unbundled storage only (the
-    categorical bitsets and the EFB unpack stay on the matrix route).
-    The rows are read where they lie: the blocks' edges past F and N are
-    the grid's, so nothing of size [F, N] is copied."""
+    from the binned rows: unbundled storage only (the EFB unpack stays on
+    the matrix route); a categorical table passes `cat`, every entry's
+    bitset (wave_cat_words). The rows are read where they lie: the
+    blocks' edges past F and N are the grid's, so nothing of size [F, N]
+    is copied."""
     F, N = X_binned_t.shape
     Fp = _round_up(F, F_BLK)
     # rows a block: fewer than N_BLK where the [F_pad, R] block would pass
@@ -774,8 +980,9 @@ def wave_decide_pallas(
     t = table.astype(jnp.int32)
     tblp = _pack_wave_table(t).at[:, 4].set(t[_T_APP_FEAT]) \
         .at[:, 5].set(t[_T_CAND_FEAT])
+    W, cat_in, cat_spec = _cat_operand(cat)
     newlor, slot = pl.pallas_call(
-        _wave_decide_kernel,
+        functools.partial(_wave_decide_kernel, **W),
         grid=(pl.cdiv(N, n_blk),),
         in_specs=[
             pl.BlockSpec((Fp, n_blk), lambda n: (0, n),
@@ -785,7 +992,7 @@ def wave_decide_pallas(
             pl.BlockSpec((128, 8), lambda n: (0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
+        ] + cat_spec,
         out_specs=[
             pl.BlockSpec((1, n_blk), lambda n: (0, n),
                          memory_space=pltpu.VMEM),
@@ -796,14 +1003,15 @@ def wave_decide_pallas(
             jax.ShapeDtypeStruct((1, N), jnp.int32),
             jax.ShapeDtypeStruct((1, N), jnp.int32),
         ],
-        name=kernel_name("wave_apply", f=Fp),
+        name=kernel_name("wave_apply", f=Fp, cat=bool(W)),
         interpret=interpret,
     )(X_binned_t, leaf_of_row.astype(jnp.int32)[None, :], tblp,
-      t[_T_NL0, 0:1])
+      t[_T_NL0, 0:1], *cat_in)
     return newlor[0], slot[0]
 
 
-@functools.partial(jax.jit, static_argnames=("num_bins", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("num_bins", "interpret", "features"))
 def wave_relabel_pallas(
     X_binned_t: jnp.ndarray,   # [F, N] int8/uint8 (feature-major, F <= 32)
     vals: jnp.ndarray,         # [C, N] (unused; kept for a uniform ABI)
@@ -811,11 +1019,14 @@ def wave_relabel_pallas(
     table: jnp.ndarray,        # [T_ROWS, 128] int32 semantic wave table
     num_bins: int,
     interpret: bool = False,
+    cat: jnp.ndarray | None = None,  # [128, 2W] i32 (wave_cat_words)
+    features: int = 0,
 ) -> jnp.ndarray:
     """Split application only: returns new_leaf_of_row [N] i32. Used for
     a tree's final wave (no candidates left to speculate). `vals` is only
     consulted for its dtype — the kernel streams a [C, 128] stub instead
-    of DMAing the real value channels it never reads."""
+    of DMAing the real value channels it never reads. `cat` and
+    `features` as wave_pass_pallas's."""
     F, NX = X_binned_t.shape
     C = vals.shape[0]
     N = leaf_of_row.shape[0]
@@ -828,14 +1039,17 @@ def wave_relabel_pallas(
     X = X_binned_t.astype(jnp.int8)
     if Fp != F or Np != NX:
         X = jnp.pad(X, ((0, Fp - F), (0, Np - NX)))
+    if cat is not None and features:
+        F = features
     v = vals[:, :128]
     lor = leaf_of_row.astype(jnp.int32)
     if Np != N:
         lor = jnp.pad(lor, (0, Np - N), constant_values=-1)
     tblp = _pack_wave_table(table)
     nl0 = table[_T_NL0, 0:1].astype(jnp.int32)
+    W, cat_in, cat_spec = _cat_operand(cat)
     kernel = functools.partial(_wave_relabel_kernel, C=C, F=F, HB=HB,
-                               quantized=quantized)
+                               quantized=quantized, **W)
     newlor = pl.pallas_call(
         kernel,
         grid=(Np // n_blk,),
@@ -849,13 +1063,14 @@ def wave_relabel_pallas(
             pl.BlockSpec((128, 8), lambda n: (0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
+        ] + cat_spec,
         out_specs=pl.BlockSpec((1, n_blk), lambda n: (0, n),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((1, Np), jnp.int32),
-        name=kernel_name("wave_relabel", b=num_bins, q=quantized),
+        name=kernel_name("wave_relabel", b=num_bins, q=quantized,
+                         **({"f": F, "cat": True} if W else {})),
         interpret=interpret,
-    )(X, v, lor[None, :], tblp, nl0)
+    )(X, v, lor[None, :], tblp, nl0, *cat_in)
     return newlor[0, :N]
 
 

@@ -41,7 +41,8 @@ def predict_leaf_binned(
         # matrix products, not a gather a row and level (below)
         return predict_leaf_binned_paths(
             split_feature, threshold_bin, default_left, left_child,
-            right_child, num_leaves, X_t, meta)
+            right_child, num_leaves, X_t, meta, split_is_cat,
+            split_cat_bitset)
     N = X_t.shape[1]
     rows = jnp.arange(N, dtype=jnp.int32)
 
@@ -81,9 +82,11 @@ _PATH_ROWS = 1 << 15     # rows a block of the path-matrix walk
 
 def path_walk_applies(X_t, split_is_cat) -> bool:
     """Whether ``predict_leaf_binned_paths`` can stand in for the gather
-    walk: bins that bfloat16 holds exactly (uint8) and no categorical
-    split (a bitset lookup a node and row is a gather again)."""
-    return split_is_cat is None and X_t.dtype == jnp.uint8
+    walk: bins that bfloat16 holds exactly (uint8). Categorical nodes
+    are taken too (their bitset words are picked by select, not by a
+    gather), so ``split_is_cat`` does not matter."""
+    del split_is_cat
+    return X_t.dtype == jnp.uint8
 
 
 def tree_paths(left_child, right_child, num_leaves):
@@ -124,15 +127,19 @@ def tree_paths(left_child, right_child, num_leaves):
 
 def predict_leaf_binned_paths(split_feature, threshold_bin, default_left,
                               left_child, right_child, num_leaves, X_t,
-                              meta: FeatureMeta) -> jnp.ndarray:
-    """``predict_leaf_binned`` for numerical splits, as matrix products
-    in place of a gather a row and level: every node's decision for
-    every row of a block (the node's feature picked out of the block's
-    bins by a one-hot product, exact for uint8 bins in bfloat16), then
-    the leaf whose path agrees with all of them (``tree_paths``: the
-    decisions +-1 times the path signs sum to the path's length
-    exactly there and nowhere else). On the TPU a level of the gather
-    walk over 4.5M rows costs what this whole walk does."""
+                              meta: FeatureMeta, split_is_cat=None,
+                              split_cat_bitset=None) -> jnp.ndarray:
+    """``predict_leaf_binned`` as matrix products in place of a gather a
+    row and level: every node's decision for every row of a block (the
+    node's feature picked out of the block's bins by a one-hot product,
+    exact for uint8 bins in bfloat16), then the leaf whose path agrees
+    with all of them (``tree_paths``: the decisions +-1 times the path
+    signs sum to the path's length exactly there and nowhere else). A
+    categorical node (``split_is_cat``) sends a row left when bit ``bin``
+    of its bitset is set: the node's word ``bin >> 5`` is picked by a
+    select chain over its W words, elementwise on the same [M, R] block.
+    On the TPU a level of the gather walk over 4.5M rows costs what this
+    whole walk does."""
     F, N = X_t.shape
     M = split_feature.shape[0]
     P, plen = tree_paths(left_child, right_child, num_leaves)
@@ -154,6 +161,14 @@ def predict_leaf_binned_paths(split_feature, threshold_bin, default_left,
         is_missing = ((mt == MISSING_ZERO) & (bin_v == dbin)) \
             | ((mt == MISSING_NAN) & (bin_v == nanbin))
         go_left = jnp.where(is_missing, dleft, bin_v <= thr)
+        if split_is_cat is not None:
+            b = bin_v.astype(jnp.int32)
+            words = split_cat_bitset.astype(jnp.uint32)            # [M, W]
+            word = words[:, 0:1]
+            for w in range(1, words.shape[1]):
+                word = jnp.where((b >> 5) == w, words[:, w:w + 1], word)
+            go_cat = ((word >> (b & 31).astype(jnp.uint32)) & 1) == 1
+            go_left = jnp.where(split_is_cat[:, None], go_cat, go_left)
         d = jnp.where(go_left, 1.0, -1.0).astype(jnp.bfloat16)
         agree = jax.lax.dot_general(
             Pb, d, (((1,), (0,)), ((), ())),

@@ -20,7 +20,8 @@ say anything about results or speed.
 
 Families: the slots kernel (legacy, hi/lo wide-bin, F-gridded wide), the
 wave megakernel, wave_relabel, wave_apply (the decision matrix's, and the
-in-kernel decision's at F = 137, 968 and 4000), take_leaf_values, tiered,
+in-kernel decision's at F = 137, 968 and 4000), take_leaf_values, tiered
+(and a categorical root's row partitions as a grid axis),
 row-wise, pack4, bucketize — float and int8 values, at the smoke width
 (F = 28) and one wide shape (F = 128) — and the device predictor's forest
 kernel (against the host's float32 walk, not the interpreter).
@@ -111,6 +112,68 @@ def _relabel(B, int8):
                 _wave_table(rng, 28, B, 0))
     return make, lambda interp: lambda X, v, lor, t: wave_relabel_pallas(
         X, v, lor, t, B, interpret=interp)
+
+
+def _cat_words(rng, W):
+    """[128, 2W] int32 bitset words with bits in the first and the last
+    word (wave_cat_words's operand)."""
+    w = rng.randint(-2 ** 31, 2 ** 31, size=(128, 2 * W), dtype=np.int64)
+    return w.astype(np.int32)
+
+
+def _mega_cat(B, int8, wide_lo, K=8):
+    from lightgbm_tpu.ops.histogram_pallas import wave_pass_pallas
+    W = (B + 31) // 32
+
+    def make(rng):
+        return (_bins(rng, (B,) * 28).astype(np.int8), _vals(rng, int8),
+                rng.randint(0, 12, size=N).astype(np.int32),
+                _wave_table(rng, 28, B, K), _cat_words(rng, W),
+                rng.randint(0, 2, size=N).astype(np.float32))
+    return make, lambda interp: lambda X, v, lor, t, c, b: wave_pass_pallas(
+        X, v, lor, t, K, B, interpret=interp, wide_lo=wide_lo, cat=c,
+        in_bag=b)
+
+
+def _relabel_cat(B, int8):
+    from lightgbm_tpu.ops.histogram_pallas import wave_relabel_pallas
+    W = (B + 31) // 32
+
+    def make(rng):
+        return (_bins(rng, (B,) * 28).astype(np.int8), _vals(rng, int8),
+                rng.randint(0, 12, size=N).astype(np.int32),
+                _wave_table(rng, 28, B, 0), _cat_words(rng, W))
+    return make, lambda interp: lambda X, v, lor, t, c: wave_relabel_pallas(
+        X, v, lor, t, B, interpret=interp, cat=c)
+
+
+def _decide_cat(F, n=N - 1000):
+    from lightgbm_tpu.ops.histogram_pallas import wave_decide_pallas
+
+    def make(rng):
+        return (rng.randint(0, 256, size=(F, n)).astype(np.uint8),
+                rng.randint(0, 12, size=n).astype(np.int32),
+                _wave_table(rng, F, 256, 8), _cat_words(rng, 8))
+    return make, lambda interp: lambda X, lor, t, c: wave_decide_pallas(
+        X, lor, t, interpret=interp, cat=c)
+
+
+def _root_parts(P=4):
+    """The root histogram of a categorical table (grow_wave's
+    ROOT_PART_ROWS) at the Expo table's tiers: row partitions as a
+    vmapped axis, which each class's kernel takes as a grid axis."""
+    from lightgbm_tpu.ops.histogram_tiered import (build_histogram_tiered,
+                                                   build_tier_plan)
+    tiers = (8, 13, 23, 32, 64, 64, 244, 244)
+    plan = build_tier_plan(tiers)
+
+    def make(rng):
+        def parts(a):
+            return a.reshape(a.shape[0], P, N // P).transpose(1, 0, 2)
+        return parts(_bins(rng, tiers)), parts(_vals(rng, False))
+    return make, lambda interp: lambda X, v: jax.vmap(
+        lambda x, w: build_histogram_tiered(x, w, 256, plan,
+                                            interpret=interp))(X, v)
 
 
 def _apply():
@@ -305,11 +368,19 @@ def cases():
             (f"megakernel B64 {t}", lambda i=int8: _mega(64, i, 128)),
             (f"megakernel hi/lo B256 {t}", lambda i=int8: _mega(256, i, 64)),
             (f"wave_relabel B256 {t}", lambda i=int8: _relabel(256, i)),
+            (f"megakernel cat B64 {t}",
+             lambda i=int8: _mega_cat(64, i, 128)),
+            (f"megakernel cat hi/lo B256 {t}",
+             lambda i=int8: _mega_cat(256, i, 64)),
+            (f"wave_relabel cat B256 {t}",
+             lambda i=int8: _relabel_cat(256, i)),
         ]
     out += [("wave_apply", _apply),
             ("wave_apply in-kernel F137", lambda: _decide(137)),
             ("wave_apply in-kernel F968", lambda: _decide(968)),
             ("wave_apply in-kernel F4000", lambda: _decide(4000)),
+            ("wave_apply in-kernel cat F137", lambda: _decide_cat(137)),
+            ("root partitions cat tiered B256", _root_parts),
             ("take_leaf_values L255", lambda: _take_leaf(255)),
             ("take_leaf_values L2048", lambda: _take_leaf(2048)),
             ("predict_forest L255 F28", lambda: _predict_forest(255, 28)),

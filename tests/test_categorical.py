@@ -69,3 +69,101 @@ def test_unseen_category_goes_right():
     X_unseen[:5, 0] = 99  # category never seen in training
     out = bst.predict(X_unseen)
     assert np.all(np.isfinite(out[:5]))
+
+
+# ---- the search against a plain-Python port of the reference's ---------
+def _inner(g, h, count, G, H, num_bin, prm, stepping=True):
+    """FindBestThresholdCategoricalInner (feature_histogram.cpp), sorted
+    many-vs-many mode, line by line in float64: the best gain over the
+    parent and its left set of bins (bins 1..num_bin-1 are categories).
+    ``stepping=False`` evaluates every prefix (the rule before the
+    stepping was kept), for the cases that tell the two apart."""
+    eps = 1e-15
+    l2, smooth = prm["lambda_l2"], prm["cat_smooth"]
+    cf = count / H
+    cnt = np.rint(h * cf)
+    parent = G * G / (H + l2)
+    idx = [t for t in range(1, num_bin) if cnt[t] >= smooth]
+    idx.sort(key=lambda t: g[t] / (h[t] + smooth))
+    used = len(idx)
+    l2 += prm["cat_l2"]
+    max_num_cat = min(prm["max_cat_threshold"], (used + 1) // 2)
+    best, best_set = -np.inf, None
+    for order in (idx, idx[::-1]):
+        lg, lh, lc, group = 0.0, eps, 0.0, 0.0
+        for i in range(min(used, max_num_cat)):
+            t = order[i]
+            lg, lh, lc, group = lg + g[t], lh + h[t], lc + cnt[t], \
+                group + cnt[t]
+            if lc < prm["min_data_in_leaf"] \
+                    or lh < prm["min_sum_hessian_in_leaf"]:
+                continue
+            rc = count - lc
+            if rc < prm["min_data_in_leaf"] \
+                    or rc < prm["min_data_per_group"]:
+                break
+            rh = H - lh
+            if rh < prm["min_sum_hessian_in_leaf"]:
+                break
+            if stepping and group < prm["min_data_per_group"]:
+                continue
+            group = 0.0
+            rg = G - lg
+            gain = lg * lg / (lh + l2) + rg * rg / (rh + l2)
+            if gain > parent and gain > best:
+                best, best_set = gain, sorted(order[:i + 1])
+    return best - parent, best_set
+
+
+def _program_search(g, h, count, G, H, num_bin, prm):
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.categorical import (CatConfig,
+                                              find_best_split_categorical)
+    from lightgbm_tpu.ops.split import FeatureMeta, SplitHyperParams
+    B = len(g)
+    c = h * (count / H)                 # the search rounds it per bin
+    hist = jnp.asarray(np.stack([g, h, c])[:, None, :], jnp.float32)
+    meta = FeatureMeta(jnp.array([num_bin], jnp.int32),
+                       jnp.array([0], jnp.int32), jnp.array([0], jnp.int32),
+                       jnp.array([True]))
+    hp = SplitHyperParams(prm["min_data_in_leaf"],
+                          prm["min_sum_hessian_in_leaf"], 0.0,
+                          prm["lambda_l2"], 0.0, 0.0, 0.0)
+    cat = CatConfig(prm["max_cat_to_onehot"], prm["max_cat_threshold"],
+                    prm["cat_l2"], prm["cat_smooth"],
+                    prm["min_data_per_group"], (B + 31) // 32)
+    res, words = find_best_split_categorical(
+        hist, jnp.float32(G), jnp.float32(H), jnp.int32(count),
+        jnp.float32(0.0), meta, hp, cat)
+    w = np.asarray(words)
+    return float(res.gain), [b for b in range(B)
+                             if (int(w[b >> 5]) >> (b & 31)) & 1]
+
+
+def test_stepping_rule_matches_the_reference_search():
+    """Seeded histograms of 20-200 categories whose bins hold 5-400 rows,
+    so min_data_per_group (100) binds: the program's search picks the
+    reference's left set on every one, and the old rule (every prefix
+    evaluated) picks another on some of them."""
+    prm = dict(lambda_l2=0.0, cat_smooth=10.0, cat_l2=10.0,
+               max_cat_threshold=32, max_cat_to_onehot=4,
+               min_data_per_group=100, min_data_in_leaf=1,
+               min_sum_hessian_in_leaf=1.0)
+    told_apart = 0
+    for seed in range(24):
+        rng = np.random.RandomState(seed)
+        B = 64 if seed % 2 else 256
+        num_bin = rng.randint(20, B - 1)
+        rows = rng.randint(5, 400, size=B).astype(np.float64)
+        rows[num_bin:] = 0
+        rows[0] = rng.randint(0, 50)
+        h = rows * rng.uniform(0.1, 0.25, size=B)
+        g = h * rng.normal(0.0, 1.0, size=B)
+        count, G, H = float(rows.sum()), float(g.sum()), float(h.sum())
+        want_gain, want = _inner(g, h, count, G, H, num_bin, prm)
+        gain, got = _program_search(g, h, count, G, H, num_bin, prm)
+        assert got == want, (seed, got, want)
+        assert abs(gain - want_gain) <= 1e-4 * abs(want_gain), seed
+        told_apart += _inner(g, h, count, G, H, num_bin, prm,
+                             stepping=False)[1] != want
+    assert told_apart >= 3, told_apart

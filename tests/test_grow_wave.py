@@ -187,3 +187,52 @@ def test_lambdarank_device_matches_host_gradients():
     gh, hh = obj.get_gradients_numpy(score)
     np.testing.assert_allclose(np.asarray(gd), gh, rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(np.asarray(hd), hh, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("route,F,root_rows", [
+    ("mega", 8, 0), ("decide", 40, 0), ("mega", 8, 512)])
+def test_categorical_tree_on_the_kernels_equals_portable(route, F, root_rows,
+                                                         monkeypatch):
+    """A whole categorical tree grown on the megakernel (8 columns) or on
+    the in-kernel decision (40), both under the Pallas interpreter, is
+    the portable route's tree: every split's feature, threshold or
+    category set, every count and every leaf value (quantized gradients:
+    integer histograms on both routes). ``root_rows`` cuts the root
+    histogram into row partitions of that many rows (ROOT_PART_ROWS),
+    summed apart and then together: the same tree."""
+    from lightgbm_tpu.ops import grow_wave
+    if root_rows:
+        monkeypatch.setattr(grow_wave, "ROOT_PART_ROWS", root_rows)
+    rng = np.random.RandomState(11)
+    n = 1500
+    X = rng.normal(size=(n, F)).astype(np.float32)
+    X[:, 0] = rng.randint(0, 40, size=n)
+    X[:, 2] = rng.randint(0, 6, size=n)
+    eff = rng.normal(size=40)
+    y = (eff[X[:, 0].astype(int)] + X[:, 1] + 0.3 * X[:, 2]
+         > 0.2).astype(np.float32)
+    params = dict(objective="binary", num_leaves=15, verbose=-1,
+                  min_data_in_leaf=5, max_bin=63, use_quantized_grad=True,
+                  min_data_per_group=5, cat_smooth=1.0,
+                  histogram_impl="auto", binning_impl="host",
+                  deterministic=True)
+    routes = []
+    real = grow_wave.wave_route
+    monkeypatch.setattr(grow_wave, "wave_route",
+                        lambda X_t, cfg: routes.append(real(X_t, cfg))
+                        or routes[-1])
+
+    def grow():
+        ds = lgb.Dataset(X, label=y, categorical_feature=[0, 2])
+        return lgb.train(params, ds, num_boost_round=2).dump_model()[
+            "tree_info"]
+
+    monkeypatch.setenv("LIGHTGBM_TPU_PALLAS_INTERPRET", "1")
+    kernels = grow()
+    assert routes and set(routes) == {route}, routes
+    monkeypatch.delenv("LIGHTGBM_TPU_PALLAS_INTERPRET")
+    routes.clear()
+    portable = grow()
+    assert set(routes) == {"portable"}, routes
+    assert any(t["num_cat"] > 0 for t in portable)
+    assert kernels == portable

@@ -464,7 +464,7 @@ def test_update_batch_records_chunks_and_drain(monkeypatch, table):
 @pytest.mark.parametrize("F,cat_cols,decides", [
     (40, (), True),              # numeric past 32 columns: in the kernel
     (8, (), False),              # the wave megakernel
-    (40, (0, 3), False),         # categorical: the [128, N] matrix
+    (40, (0, 3), True),          # categorical: in the kernel, by bitset
 ])
 def test_wave_decide_trees_counts_the_in_kernel_route(monkeypatch, F,
                                                       cat_cols, decides):
@@ -485,8 +485,7 @@ def test_wave_decide_trees_counts_the_in_kernel_route(monkeypatch, F,
                      categorical_feature=list(cat_cols) or "auto")
     bst = lgb.Booster(params=params, train_set=ds)
     bst.update_batch(3, chunk=2)
-    assert bst._gbdt._wave_route() == (
-        "decide" if decides else "matrix" if cat_cols else "mega")
+    assert bst._gbdt._wave_route() == ("decide" if decides else "mega")
     chunks = [r for r in P.spans() if r["name"] == "train/chunk"][before:]
     assert [c["counts"]["wave_decide_trees"] for c in chunks] == (
         [2, 1] if decides else [0, 0])
@@ -879,7 +878,8 @@ def test_every_pallas_site_carries_an_explicit_distinct_name():
     assert all(by_case.values()), by_case
     names = {n for ns in by_case.values() for n in ns}
     assert all(n.startswith("lgbm_") for n in names), names
-    variant = re.compile(r"(_(k|b|lo|t|l|f|m|n|w|c)\d+|_q|_nan|_zero)*$")
+    variant = re.compile(
+        r"(_(k|b|lo|t|l|f|m|n|w|c)\d+|_q|_nan|_zero|_cat)*$")
     families = {variant.sub("", n) for n in names}
     assert families == {
         "lgbm_hist_slots", "lgbm_take_leaf_values", "lgbm_wave_pass",
@@ -889,7 +889,10 @@ def test_every_pallas_site_carries_an_explicit_distinct_name():
     # one name per compiled variant: bins, hi/lo split and operand type
     variants = [by_case[c][0] for c in (
         "megakernel B64 f32", "megakernel B64 int8",
-        "megakernel hi/lo B256 f32", "slots legacy F28 B256 f32",
+        "megakernel hi/lo B256 f32", "megakernel cat B64 f32",
+        "megakernel cat hi/lo B256 f32", "wave_relabel B256 f32",
+        "wave_relabel cat B256 f32", "wave_apply in-kernel F137",
+        "wave_apply in-kernel cat F137", "slots legacy F28 B256 f32",
         "slots hi/lo F28 B256 f32", "predict_forest L255 F28",
         "predict_forest L15 F130 K3 nan", "predict_forest L31 F28 cat",
         "predict_forest L255 F4 nan c2")]

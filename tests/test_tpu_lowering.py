@@ -28,3 +28,30 @@ def test_family_lowers_for_tpu(name):
              for a in map(np.asarray, make(np.random.RandomState(0)))]
     export = jax.export.export(jax.jit(fn_of(False)), platforms=["tpu"])
     assert export(*specs).mlir_module().count("tpu_custom_call") >= 1
+
+
+# A numeric table's wave kernels are the same program with or without
+# the categorical bitset operand the categorical tables take: their
+# lowering for the TPU, byte for byte, is the one recorded before that
+# operand existed (sha256 of the exported module's text; JAX 0.9.0).
+NUMERIC_TEXT = {
+    "megakernel B64 f32":
+        "6ab301573b194dd7734a5ecc0c20bd30d1754f396566cf54aa9f81c2b555e499",
+    "megakernel hi/lo B256 int8":
+        "4fd16f8eec4c12aed51e38367e8febdd3fea86d06ae8137b8e6b204c211b76e8",
+    "wave_relabel B256 f32":
+        "f679d4305e74ffdd79fa6fb96407d6bb68e39550533b387775ce061ac3ca5820",
+    "wave_apply in-kernel F137":
+        "7e6b411e7337dcd456b7323f239ed83aa2f203fc3eb03d2d0b704d10290ab898",
+}
+
+
+@pytest.mark.parametrize("name", sorted(NUMERIC_TEXT))
+def test_numeric_wave_kernels_lower_as_recorded(name):
+    import hashlib
+    make, fn_of = CASES[name]()
+    specs = [jax.ShapeDtypeStruct(a.shape, a.dtype)
+             for a in map(np.asarray, make(np.random.RandomState(0)))]
+    text = jax.export.export(jax.jit(fn_of(False)),
+                             platforms=["tpu"])(*specs).mlir_module()
+    assert hashlib.sha256(text.encode()).hexdigest() == NUMERIC_TEXT[name]

@@ -3,7 +3,7 @@
 
 On a TPU ``grow_tree_wave`` (ops/grow_wave.py) runs one wave megakernel
 pass per wave at F <= 32 (``wave_pass_pallas`` / ``wave_relabel_pallas``)
-and past it ``wave_decide_pallas`` (``wave_apply_pallas`` on categorical
+and past it ``wave_decide_pallas`` (``wave_apply_pallas`` on EFB
 tables) plus the F-gridded slot kernels. The rest
 of tier-1 trains on the CPU, where ``_use_pallas`` is false and every one
 of those is replaced by its XLA fall-back, so this file is where the
@@ -56,10 +56,10 @@ BASE = {"objective": "regression", "num_leaves": 5, "max_bin": 31,
         "histogram_impl": "auto", "binning_impl": "host"}
 
 MEGA = {"wave_pass_pallas", "wave_relabel_pallas"}
-# past 32 columns (ops/grow_wave.py wave_route): numeric tables decide
-# each row in the kernel, categorical ones keep the [128, N] matrix
+# past 32 columns (ops/grow_wave.py wave_route) every unbundled table,
+# categorical or not, decides each row in the kernel; only EFB storage
+# keeps the [128, N] matrix (wave_apply_pallas)
 WIDE = {"wave_decide_pallas", "build_histogram_slots_pallas"}
-MATRIX = {"wave_apply_pallas", "build_histogram_slots_pallas"}
 ROUTE_KERNELS = {"wave_decide_pallas", "wave_apply_pallas"}
 # regime -> (F, rows, params over BASE, categorical columns, NaN share,
 #            the kernels the interpreted arm must call).
@@ -89,7 +89,12 @@ REGIMES = {
         (), 0.0, WIDE),
     "categorical": (40, 400, {"max_cat_to_onehot": 4,
                               "max_cat_threshold": 16},
-                    (0, 3, 7, 11), 0.0, MATRIX),
+                    (0, 3, 7, 11), 0.0, WIDE),
+    # a categorical table on the megakernel at 256 bins: bitsets of 8
+    # words, a column of 250 categories, some past the 99 % cut in bin 0
+    "cat_f8_b255_megakernel": (8, 3000, {
+        "max_bin": 63, "num_leaves": 15, "min_data_per_group": 5,
+        "cat_smooth": 1.0}, (0, (5, 250)), 0.0, MEGA),
     "nan_bagging": (40, 400, {"bagging_fraction": 0.7, "bagging_freq": 1,
                               "bagging_seed": 5}, (), 0.1, WIDE),
     # a row-wise histogram pin at F <= 32 leaves the megakernel (its
@@ -107,11 +112,16 @@ LOSS_GAP_LIMIT = 2e-4       # read: at most 3.67e-5 (f100)
 
 
 def _data(F, n, cat_cols, nan_share, seed=3):
+    """``cat_cols``: a column of 9 categories, or (column, categories)."""
     rng = np.random.RandomState(seed)
     X = rng.normal(size=(n, F)).astype(np.float32)
     for c in cat_cols:
-        X[:, c] = rng.randint(0, 9, size=n)
+        c, k = c if isinstance(c, tuple) else (c, 9)
+        X[:, c] = rng.randint(0, k, size=n)
     y = (X[:, 0] - 0.5 * X[:, F // 2] + np.sin(X[:, 1])).astype(np.float32)
+    for c in cat_cols:
+        if isinstance(c, tuple):        # the categories' own effects
+            y += rng.normal(size=c[1])[X[:, c[0]].astype(np.int64)]
     if nan_share:
         X[rng.uniform(size=X.shape) < nan_share] = np.nan
     return X, y
@@ -150,8 +160,9 @@ def _train_both(regime, quantized, monkeypatch, fault=None):
     seen = _kernel_spy(monkeypatch, fault)
 
     def train():
-        ds = lgb.Dataset(X, label=y, categorical_feature=list(cat_cols)) \
-            if cat_cols else lgb.Dataset(X, label=y)
+        cats = [c[0] if isinstance(c, tuple) else c for c in cat_cols]
+        ds = lgb.Dataset(X, label=y, categorical_feature=cats) \
+            if cats else lgb.Dataset(X, label=y)
         return lgb.train(params, ds, num_boost_round=1).predict(X)
 
     monkeypatch.setenv(INTERP, "1")
@@ -292,3 +303,114 @@ def test_leaf_values_follow_their_histograms(arm, seed, monkeypatch):
     gap = _leaf_value_gap(bst, X, y, jnp.bfloat16 if arm == "interpreted"
                           else np.float32)
     assert gap < LEAF_VALUE_GAP_LIMIT, gap
+
+
+# ---- categorical entries in the row-deciding kernels, entry by entry
+def _cat_case(B, rng, F=8, n=9000, K=8):
+    """Binned rows with bin 0 in every column, a wave table whose
+    entries alternate numeric and categorical, and bitsets with bits set
+    in their first and last word. 9,000 rows are 5 row blocks: the
+    categorical megakernel sums 5 row partitions apart (wave_parts)."""
+    from lightgbm_tpu.ops import histogram_pallas as hpk
+    W = (B + 31) // 32
+    X = rng.randint(0, B, size=(F, n)).astype(np.uint8)
+    X[:, ::7] = 0
+    table = np.full((16, 128), -1, np.int32)
+    app, cand = [0, 3, 5, 7], list(range(K))
+    for base, leaves in ((0, app), (7, cand)):
+        m = len(leaves)
+        table[base, :m] = leaves
+        table[base + 1, :m] = rng.randint(0, F, size=m)
+        table[base + 2, :m] = rng.randint(0, B - 1, size=m)
+        table[base + 3, :m] = rng.randint(0, 2, size=m)
+        table[base + 4, :m] = rng.choice([0, 1, 2], size=m)
+        table[base + 5, :m] = rng.randint(0, B - 1, size=m)
+        table[base + 6, :m] = B
+    table[14, :K] = rng.randint(0, 2, size=K)
+    table[15, :] = 12
+    bits = rng.randint(0, 2 ** 32, size=(2, K, W), dtype=np.uint64) \
+        .astype(np.uint32)
+    bits[:, :, 0] |= 0x10                # a bit in the first word ...
+    bits[:, :, W - 1] |= 1 << 3          # ... and one in the last
+    bits[:, :, 0] &= ~np.uint32(1)       # bin 0 never goes left
+    is_cat = np.zeros((2, K), bool)
+    is_cat[:, 1::2] = True
+    tbl, words = hpk.wave_cat_words(jnp.asarray(table),
+                                    jnp.asarray(is_cat[0, :4]),
+                                    jnp.asarray(bits[0, :4]),
+                                    jnp.asarray(is_cat[1]),
+                                    jnp.asarray(bits[1]))
+    return X, table, is_cat, bits, tbl, words
+
+
+def _select_chain(X, table, is_cat, bits, lor):
+    """The portable route's rule (ops/grow_wave.py table_go_left), row by
+    row: the applied entry relabels, then the candidate entry of the new
+    leaf says which child's slot the row is in (-1: none)."""
+    def go_left(row, base, j, cat, words):
+        f, thr, dl = table[base + 1, j], table[base + 2, j], table[base + 3, j]
+        mt, db, nb = table[base + 4, j], table[base + 5, j], table[base + 6, j]
+        b = int(X[f, row])
+        if cat:
+            return bool((int(words[b >> 5]) >> (b & 31)) & 1)
+        if (mt == 1 and b == db) or (mt == 2 and b == nb - 1):
+            return bool(dl)
+        return b <= thr
+
+    new_lor, slot = lor.copy(), np.full(len(lor), -1, np.int32)
+    for r in range(len(lor)):
+        hit = np.flatnonzero(table[0, :4] == lor[r])
+        if len(hit):
+            j = hit[0]
+            if not go_left(r, 0, j, is_cat[0, j], bits[0, j]):
+                new_lor[r] = table[15, 0] + j
+        hit = np.flatnonzero(table[7, :8] == new_lor[r])
+        if len(hit):
+            j = hit[0]
+            if go_left(r, 7, j, is_cat[1, j], bits[1, j]) == bool(
+                    table[14, j]):
+                slot[r] = j
+    return new_lor, slot
+
+
+@pytest.mark.parametrize("kernel", ["megakernel", "relabel", "decide"])
+@pytest.mark.parametrize("B", [64, 256])
+def test_categorical_entries_match_the_select_chain(B, kernel):
+    """``_wave_logic`` (through the megakernel and the relabel-only pass)
+    and ``_wave_decide_kernel`` under the Pallas interpreter, with the
+    bitset operand of ``wave_cat_words``, against the select-chain rule
+    row by row: the new leaf of every row bit for bit, and for the
+    megakernel the int8 histograms of the candidates' smaller children
+    and their in-bag row counts."""
+    from lightgbm_tpu.ops import histogram_pallas as hpk
+    rng = np.random.RandomState(B)
+    X, table, is_cat, bits, tbl, words = _cat_case(B, rng)
+    n = X.shape[1]
+    lor = rng.randint(0, 12, size=n).astype(np.int32)
+    want_lor, want_slot = _select_chain(X, table, is_cat, bits, lor)
+    if kernel == "decide":
+        got_lor, got_slot = hpk.wave_decide_pallas(
+            jnp.asarray(X), jnp.asarray(lor), tbl, interpret=True, cat=words)
+        np.testing.assert_array_equal(np.asarray(got_slot), want_slot)
+    elif kernel == "relabel":
+        vals = jnp.zeros((2, n), jnp.int8)
+        got_lor = hpk.wave_relabel_pallas(
+            jnp.asarray(X.astype(np.int8)), vals, jnp.asarray(lor), tbl, B,
+            interpret=True, cat=words)
+    else:
+        vals = rng.randint(-20, 20, size=(2, n)).astype(np.int8)
+        in_bag = (rng.uniform(size=n) < 0.8).astype(np.float32)
+        got_lor, hist, counts = hpk.wave_pass_pallas(
+            jnp.asarray(X.astype(np.int8)), jnp.asarray(vals),
+            jnp.asarray(lor), tbl, 8, B, interpret=True,
+            wide_lo=64 if B > 128 else 128, cat=words,
+            in_bag=jnp.asarray(in_bag))
+        want = np.zeros((8, 2, X.shape[0], B), np.int64)
+        for r in np.flatnonzero(want_slot >= 0):
+            for f in range(X.shape[0]):
+                want[want_slot[r], :, f, X[f, r]] += vals[:, r]
+        np.testing.assert_array_equal(np.asarray(hist), want)
+        # each slot's in-bag rows: the smaller children's exact counts
+        np.testing.assert_array_equal(np.asarray(counts), np.bincount(
+            want_slot[(want_slot >= 0) & (in_bag > 0)], minlength=8))
+    np.testing.assert_array_equal(np.asarray(got_lor), want_lor)
