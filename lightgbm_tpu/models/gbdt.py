@@ -1045,6 +1045,14 @@ class GBDT:
         if sp is not None:
             # the chunk's categorical splits, on its train/chunk span
             sp.counts["cat_splits"] = sum(int(t.num_cat) for t in out)
+            wc = [h.wave_counts for h in flat
+                  if h.wave_counts is not None]
+            if wc:
+                # the wave megakernel's calls, slots and contracted rows
+                tot = np.sum(np.asarray(wc, np.int64), axis=0)
+                for key, v in zip(("waves", "wave_slots", "hist_rows"),
+                                  tot):
+                    sp.counts[key] = int(v)
         return out
 
     def _check_stopped(self) -> bool:

@@ -212,6 +212,11 @@ class DeviceTree(NamedTuple):
     num_waves: jnp.ndarray         # i32: histogram waves used (diagnostic,
     #                                maintained by the wave grower; the
     #                                serial growers leave it 0)
+    wave_counts: Optional[jnp.ndarray] = None  # [3] u32 on the wave
+    #                                megakernel's route: wave-pass calls,
+    #                                the sum of their slot counts K, rows
+    #                                that reached a histogram contraction
+    #                                (the root's N included); None elsewhere
 
 
 class _LoopState(NamedTuple):

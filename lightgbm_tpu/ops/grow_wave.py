@@ -793,6 +793,10 @@ def grow_tree_wave(
     hist_cache0 = hist_cache0.reshape(-1)
 
     tree = _root_tree(L, W, root_h, root_c)
+    if use_mega:
+        # the root's contraction took every row
+        tree = tree._replace(wave_counts=jnp.stack([
+            jnp.uint32(0), jnp.uint32(0), psum(jnp.uint32(N))]))
     empty = _empty_split_cache(L)
     state = _WaveState(
         tree=tree,
@@ -982,10 +986,12 @@ def grow_tree_wave(
                 if K < KMAX:
                     hist = jnp.pad(
                         hist, ((0, KMAX - K), (0, 0), (0, 0), (0, 0)))
+                # the rows its contraction took (wave_counts)
+                n_rows = out[-1].astype(jnp.uint32)
                 if not cat:
-                    return new_lor, hist
+                    return new_lor, hist, n_rows
                 cnt = jnp.pad(out[2], (0, KMAX - K)).astype(jnp.float32)
-                return new_lor, hist, cnt
+                return new_lor, hist, cnt, n_rows
             return branch
 
         def relabel_only_branch(args):
@@ -997,9 +1003,10 @@ def grow_tree_wave(
                                           cat=cat[0] if cat else None,
                                           features=F0 if cat else 0)
             hist = jnp.zeros((KMAX, C, F0, B), hist_dtype)
+            none = jnp.zeros((), jnp.uint32)
             if not cat:
-                return new_lor, hist
-            return new_lor, hist, jnp.zeros((KMAX,), jnp.float32)
+                return new_lor, hist, none
+            return new_lor, hist, jnp.zeros((KMAX,), jnp.float32), none
 
         mega_branches = [relabel_only_branch] \
             + [make_mega_branch(K) for K in buckets]
@@ -1381,9 +1388,15 @@ def grow_tree_wave(
                     .astype(jnp.int32), len(buckets) - 1),
                 0)
             with jax.named_scope("train/wave_pass"):
-                leaf_of_row, hist_wave, *cnt_wave = jax.lax.switch(
+                leaf_of_row, hist_wave, *cnt_wave, n_rows = jax.lax.switch(
                     kidx_m, mega_branches, (st.leaf_of_row,) + kargs)
-            st = st._replace(leaf_of_row=leaf_of_row)
+            # (wave-pass calls, their slots, rows contracted) this tree
+            k_call = jnp.asarray([0] + buckets, jnp.uint32)[kidx_m]
+            st = st._replace(
+                leaf_of_row=leaf_of_row,
+                tree=st.tree._replace(wave_counts=st.tree.wave_counts
+                                      + jnp.stack([jnp.minimum(k_call, 1),
+                                                   k_call, psum(n_rows)])))
             slot_small = None
         elif route == "decide":
             # ---- the kernel reads the binned rows and decides each row

@@ -35,6 +35,8 @@ Kernels:
   build_histogram_pallas        single set (K = 1 wrapper)    -> [C, F, B]
   wave_pass_pallas              fused split-apply (row relabel) + candidate
                                 smaller-child membership + slot histogram
+                                over those children's rows alone, compacted
+                                in VMEM first
   take_leaf_values_pallas       exact values[leaf_of_row] gather
 
 The MXU contraction runs in bfloat16 with float32 accumulation: one-hot
@@ -50,6 +52,7 @@ accumulation.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -523,9 +526,11 @@ def _wave_logic(x_ref, v_ref, lor_ref, tbl_ref, nl0_ref, newlor_ref, *,
     walks all 128 table rows (inactive rows have leaf -1 and never match
     — [128, R] compares cost ~2 VPU ops/row-block, so there is nothing
     to bucket), while the candidate side is bucketed to K because the
-    MXU contraction cost scales with it. Returns oh_small [K, R] (None
-    when with_hist=False). With ``cat_ref`` (a categorical table, W > 0)
-    every entry decides by its bitset (wave_cat_words)."""
+    MXU contraction cost scales with it. Returns (bins [F, R] i32,
+    candidate entry mask [K, R], in a candidate's smaller child [R],
+    that candidate's slot [R]); None when with_hist=False. With
+    ``cat_ref`` (a categorical table, W > 0) every entry decides by its
+    bitset (wave_cat_words)."""
     R = lor_ref.shape[1]
     xx_log = x_ref[0:F, :].astype(jnp.int32)               # [F, R]
     if HB > 1:
@@ -564,7 +569,7 @@ def _wave_logic(x_ref, v_ref, lor_ref, tbl_ref, nl0_ref, newlor_ref, *,
                                   cat_ref, 4 * W, W) if W else None)
     silC = (pC >> 23) & 1
     in_small = (((pC >> 24) & 1) == 1) & (glC == silC)     # [R]
-    return mC & in_small[None, :]                          # [K, R]
+    return xx_log, mC, in_small, (pC >> 25) & 127
 
 
 def _wave_relabel_kernel(x_ref, v_ref, lor_ref, tbl_ref, nl0_ref, *refs,
@@ -577,6 +582,71 @@ def _wave_relabel_kernel(x_ref, v_ref, lor_ref, tbl_ref, nl0_ref, *refs,
                 cat_ref=cat_ref, W=W)
 
 
+# ---- the wave pass contracts only the rows that carry weight. The
+# rows of a block that lie in a candidate's smaller child (about a fifth
+# of them over a tree, never more than half in one wave) are moved, in
+# order, into a VMEM staging buffer; the others never reach a one-hot.
+# Each 128-row sub-tile is placed by ONE small matmul: its data (bins,
+# value channels, slot + 1) against a [2*128, 128] one-hot whose rows are
+# the buffer's positions from the fill's 128-aligned floor on, added
+# into that window, so every store stays lane-aligned. The placement is
+# exact: every output sums one bfloat16 x 1 product, bins (<= 255) and
+# slots (< 128) are integers bfloat16 holds, and the values are the ones
+# _make_W rounds to bfloat16 (or int8) anyway. Whenever the buffer holds
+# a full tile of T rows the tile runs the slot histogram's contraction;
+# the grid's last step (on a categorical table the last of each row
+# partition, so a tile's rows belong to one partial histogram:
+# wave_parts) contracts the rest, its tail zeros: slot 0 is no slot, so
+# those lanes weigh nothing. On the chip (docs/PERF.md section 7d) a
+# branch around an empty sub-tile made the placement about ten times
+# slower, so every sub-tile is placed; a loop step places eight of
+# them: one a step left each matmul's latency bare (a tree 40 % slower),
+# all sixteen unrolled made the training program's first dispatch about
+# 10 s slower.
+_SUB = 128          # rows a placement sub-tile
+_GROUP = 8          # sub-tiles a loop step places
+
+
+def _stage_block(sel, dat_ref, stg_ref, fill_ref, idx_ref):
+    """Append the block's rows whose ``sel`` [R] is set, in order, to the
+    staging buffer stg_ref [D, 2T] at fill_ref[0]; dat_ref [D, R] f32
+    holds the block's data, idx_ref [R / 128, 128] i32 takes each row's
+    place among its sub-tile's selected rows. Positions past the fill
+    hold zeros."""
+    R = dat_ref.shape[1]
+    nsub = R // _SUB
+    sel = sel.astype(jnp.int32).reshape(nsub, _SUB)
+    ii = jax.lax.broadcasted_iota(jnp.int32, (_SUB, _SUB), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (_SUB, _SUB), 1)
+    # each row's selected predecessors in its sub-tile (counts < 128:
+    # exact through the MXU); an unselected row's lies below any window
+    pos = jax.lax.dot_general(
+        sel.astype(jnp.bfloat16), (ii < jj).astype(jnp.bfloat16),
+        (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(jnp.int32)
+    idx_ref[...] = jnp.where(sel > 0, pos, -2 * _SUB)
+    jt = jax.lax.broadcasted_iota(jnp.int32, (2 * _SUB, _SUB), 0)
+    G = math.gcd(_GROUP, nsub)
+
+    def place(g, fill):
+        rows = idx_ref[pl.ds(pl.multiple_of(g * G, G), G), :]   # [G, S]
+        cnts = [jnp.sum(jnp.where(rows[k:k + 1] >= 0, 1, 0))
+                for k in range(G)]
+        for k in range(G):
+            off = fill & (_SUB - 1)
+            base = pl.multiple_of(fill - off, _SUB)
+            onehot = (jt == off + rows[k:k + 1]).astype(jnp.bfloat16)
+            col = pl.multiple_of((g * G + k) * _SUB, _SUB)
+            d = dat_ref[:, pl.ds(col, _SUB)].astype(jnp.bfloat16)
+            stg_ref[:, pl.ds(base, 2 * _SUB)] += jax.lax.dot_general(
+                d, onehot, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            fill = fill + cnts[k]
+        return fill
+
+    fill_ref[0] = jax.lax.fori_loop(0, nsub // G, place, fill_ref[0])
+
+
 def _wave_kernel(x_ref, v_ref, lor_ref, tbl_ref, nl0_ref, *refs,
                  K, C, LO, HB, F, Fc, quantized, W=0):
     """Grid (N_blocks,). x_ref [F_pad, R]; v_ref [C, R]; lor_ref [1, R];
@@ -585,7 +655,12 @@ def _wave_kernel(x_ref, v_ref, lor_ref, tbl_ref, nl0_ref, *refs,
     inb_ref [1, R] int8 in-bag rows; newlor_ref [1, R]; out_ref
     [HB*C*K, Fh*LO] (VMEM-resident across the whole grid); on a
     categorical table cnt_ref [K, 128] i32, each slot's in-bag rows
-    (every lane the same; resident too).
+    (every lane the same; resident too); rows_ref [1] i32 in SMEM, the
+    rows that reached the contraction. Scratch: dat_ref [D, R] f32 a
+    block's staged data (rows 0:F bins, F:F+C values, F+C slot + 1; the
+    rows past them are never read), stg_ref [D, 2R] f32 the staging
+    buffer, fill_ref [1] i32 its fill, idx_ref [R / 128, 128] i32 the
+    rows' places in their sub-tiles (_stage_block).
 
     All per-row logic runs either on full [F, R] / [K, R] tiles or on a
     handful of [1, R] ops — 1-sublane [1, R] chains are ~8x below VPU
@@ -593,36 +668,81 @@ def _wave_kernel(x_ref, v_ref, lor_ref, tbl_ref, nl0_ref, *refs,
     reduction, and per-entry table values arrive as ONE packed int32 via
     a masked [K, R] reduction."""
     if W:
-        cat_ref, inb_ref, newlor_ref, out_ref, cnt_ref = refs
+        (cat_ref, inb_ref, newlor_ref, out_ref, cnt_ref, rows_ref,
+         dat_ref, stg_ref, fill_ref, idx_ref) = refs
     else:
-        (newlor_ref, out_ref), cat_ref = refs, None
+        (newlor_ref, out_ref, rows_ref, dat_ref, stg_ref, fill_ref,
+         idx_ref), cat_ref = refs, None
     # a categorical table's grid is (partition, block): each partition
     # starts its own histogram (wave_parts)
-    n = pl.program_id(1 if W else 0)
+    ax = 1 if W else 0
+    n = pl.program_id(ax)
+    T = dat_ref.shape[1]
 
     @pl.when(n == 0)
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
+        stg_ref[...] = jnp.zeros_like(stg_ref)
+        fill_ref[0] = 0
         if W:
             cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    oh_small = _wave_logic(x_ref, v_ref, lor_ref, tbl_ref, nl0_ref,
-                           newlor_ref, K=K, C=C, F=F, HB=HB,
-                           quantized=quantized, with_hist=True,
-                           cat_ref=cat_ref, W=W)
+    @pl.when((n == 0) & (pl.program_id(0) == 0))
+    def _():
+        rows_ref[0] = 0
+
+    xx, mC, in_small, slot = _wave_logic(
+        x_ref, v_ref, lor_ref, tbl_ref, nl0_ref, newlor_ref, K=K, C=C,
+        F=F, HB=HB, quantized=quantized, with_hist=True, cat_ref=cat_ref,
+        W=W)
     if W:
         # the smaller children's exact in-bag row counts
         inb = inb_ref[0, :].astype(jnp.int32) > 0
-        cnt_ref[...] += jnp.sum(jnp.where(oh_small & inb[None, :], 1, 0),
-                                axis=1, keepdims=True)
+        cnt_ref[...] += jnp.sum(
+            jnp.where(mC & (in_small & inb)[None, :], 1, 0),
+            axis=1, keepdims=True)
+    n_sel = jnp.sum(in_small.astype(jnp.int32))
+    rows_ref[0] += n_sel
 
-    # ---- slot histogram (shared contraction)
-    Wv = _make_W(v_ref[...], oh_small, C, K, quantized)
-    xx_all = x_ref[0:F, :].astype(jnp.int32)
-    if HB > 1:
-        xx_all = xx_all & 0xFF
-    _hist_chunks(xx_all, Wv, out_ref, Fc, C=C, K=K, LO=LO, HB=HB,
-                 quantized=quantized)
+    @pl.when(n_sel > 0)
+    def _():
+        dat_ref[0:F, :] = xx.astype(jnp.float32)
+        v = v_ref[...]
+        dat_ref[F:F + C, :] = (v.astype(jnp.int32) if quantized
+                               else v).astype(jnp.float32)
+        dat_ref[F + C:F + C + 1, :] = (slot + 1).astype(jnp.float32)[None]
+        _stage_block(in_small, dat_ref, stg_ref, fill_ref, idx_ref)
+
+    def contract(i, carry):
+        # ---- slot histogram of one staged tile (shared contraction)
+        tile = stg_ref[:, 0:T]
+        slot_t = tile[F + C:F + C + 1, :].astype(jnp.int32) - 1
+        oh_slot = slot_t == jax.lax.broadcasted_iota(jnp.int32, (K, T), 0)
+        Wv = _make_W(tile[F:F + C, :], oh_slot, C, K, quantized)
+        _hist_chunks(tile[0:F, :].astype(jnp.int32), Wv, out_ref, Fc, C=C,
+                     K=K, LO=LO, HB=HB, quantized=quantized)
+        stg_ref[:, 0:T] = stg_ref[:, T:2 * T]
+        stg_ref[:, T:2 * T] = jnp.zeros((stg_ref.shape[0], T), jnp.float32)
+        return carry
+
+    # the full tiles, and at the last step the rest (the fill is < 2T)
+    fill = fill_ref[0]
+    tiles = jnp.where(n == pl.num_programs(ax) - 1, (fill + T - 1) // T,
+                      fill // T)
+    jax.lax.fori_loop(0, tiles, contract, 0)
+    fill_ref[0] = jnp.maximum(fill - tiles * T, 0)
+
+
+def _wave_scratch(F, C, n_blk):
+    """The megakernel's scratch: a block's staged data, the staging
+    buffer (two tiles: a block appends at most one more while the fill
+    is under a tile), its fill, and the rows' places in their
+    sub-tiles."""
+    D = _round_up(F + C + 1, 8)
+    return [pltpu.VMEM((D, n_blk), jnp.float32),
+            pltpu.VMEM((D, 2 * n_blk), jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((n_blk // _SUB, _SUB), jnp.int32)]
 
 
 @functools.partial(jax.jit,
@@ -642,14 +762,17 @@ def wave_pass_pallas(
     features: int = 0,
 ) -> tuple[jnp.ndarray, ...]:
     """Fused wave pass: returns (new_leaf_of_row [N] i32,
-    hist [K, C, F, num_bins]), and on a categorical table a third,
-    counts [K] i32: each slot's in-bag rows (``in_bag`` > 0), which the
-    grower takes as the smaller children's exact counts. X/vals may be pre-padded (F to 32, rows to
-    a block multiple) by the caller so the pad/convert cost is paid once
-    per tree instead of once per wave; `leaf_of_row` keeps the true row
-    count and the outputs are sliced to it. `wide_lo` selects the
-    wide-bin (>128) hi/lo decomposition (_compute_dims); the VMEM
-    footprint of the output block is identical for either choice
+    hist [K, C, F, num_bins], rows i32), and on a categorical table
+    (new_leaf_of_row, hist, counts, rows) with counts [K] i32: each
+    slot's in-bag rows (``in_bag`` > 0), which the grower takes as the
+    smaller children's exact counts. ``rows`` is the number of rows that
+    reached the histogram's contraction: those in a candidate's smaller
+    child (the kernel compacts them first). X/vals may be pre-padded (F
+    to 32, rows to a block multiple) by the caller so the pad/convert
+    cost is paid once per tree instead of once per wave; `leaf_of_row`
+    keeps the true row count and the outputs are sliced to it. `wide_lo`
+    selects the wide-bin (>128) hi/lo decomposition (_compute_dims); the
+    VMEM footprint of the output block is identical for either choice
     (HB*LO = B), so the caller's K cap is unaffected. `cat`, on a
     categorical table, is every entry's bitset (wave_cat_words); without
     it the kernel is the numeric one, operand for operand. `features`,
@@ -692,14 +815,14 @@ def wave_pass_pallas(
     name = kernel_name("wave_pass", k=K, b=num_bins, lo=LO, q=quantized,
                        **({"f": F, "cat": True} if W else {}))
     if W:
-        newlor, out, cnt = _wave_pass_cat(
+        newlor, out, cnt, n_rows = _wave_pass_cat(
             kernel, name, interpret, X, v, lor, tblp, nl0, cat_in, cat_spec,
             in_bag, Fp=Fp, n_blk=n_blk, P=P, C=C, K=K, rows=rows, Fh=Fh,
-            LO=LO, out_dtype=out_dtype)
+            LO=LO, out_dtype=out_dtype, F=F)
         hist = _unflatten_hist(out, K, C, F, Fh, LO, HB, num_bins)
-        return newlor[0, :N], hist, cnt
+        return newlor[0, :N], hist, cnt, n_rows[0]
     grid = (Np // n_blk,)
-    newlor, out = pl.pallas_call(
+    newlor, out, n_rows = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -718,11 +841,14 @@ def wave_pass_pallas(
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((rows, Fh * LO), lambda n: (0, 0),
                          memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, Np), jnp.int32),
             jax.ShapeDtypeStruct((rows, Fh * LO), out_dtype),
+            jax.ShapeDtypeStruct((1,), jnp.int32),
         ],
+        scratch_shapes=_wave_scratch(F, C, n_blk),
         name=name,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
@@ -735,7 +861,7 @@ def wave_pass_pallas(
     )(X, v, lor[None, :], tblp, nl0)
 
     hist = _unflatten_hist(out, K, C, F, Fh, LO, HB, num_bins)
-    return newlor[0, :N], hist
+    return newlor[0, :N], hist, n_rows[0]
 
 
 # Row partitions of a categorical table's wave pass. A histogram bin's
@@ -759,7 +885,7 @@ def wave_parts(rows: int) -> int:
 
 def _wave_pass_cat(kernel, name, interpret, X, v, lor, tblp, nl0, cat_in,
                    cat_spec, in_bag, *, Fp, n_blk, P, C, K, rows, Fh, LO,
-                   out_dtype):
+                   out_dtype, F):
     """The categorical megakernel over a (partition, block) grid: each
     partition accumulates its own histogram and slot counts, summed here
     (wave_parts)."""
@@ -775,7 +901,7 @@ def _wave_pass_cat(kernel, name, interpret, X, v, lor, tblp, nl0, cat_in,
     def whole(o, i):
         return (0, 0)
 
-    newlor, out, cnt = pl.pallas_call(
+    newlor, out, cnt, n_rows = pl.pallas_call(
         kernel,
         grid=(P, inner),
         in_specs=[
@@ -794,18 +920,22 @@ def _wave_pass_cat(kernel, name, interpret, X, v, lor, tblp, nl0, cat_in,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((None, K, 128), lambda o, i: (o, 0, 0),
                          memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, Np), jnp.int32),
             jax.ShapeDtypeStruct((P, rows, Fh * LO), out_dtype),
             jax.ShapeDtypeStruct((P, K, 128), jnp.int32),
+            jax.ShapeDtypeStruct((1,), jnp.int32),
         ],
+        scratch_shapes=_wave_scratch(F, C, n_blk),
         name=name,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=HIST_VMEM_LIMIT_BYTES),
     )(X, v, lor[None, :], tblp, nl0, *cat_in, inb[None, :])
-    return newlor, jnp.sum(out, axis=0), jnp.sum(cnt[:, :, 0], axis=0)
+    return (newlor, jnp.sum(out, axis=0), jnp.sum(cnt[:, :, 0], axis=0),
+            n_rows)
 
 
 def _wave_apply_kernel(dec_ref, lor_ref, tbl_ref, nl0_ref, newlor_ref,

@@ -269,10 +269,10 @@ def test_wave_pass_wide_lo_parity():
     tbl_np = np.stack([np.asarray(t, np.int32) for t in tblr])
     tbl16 = jnp.asarray(np.pad(tbl_np, ((0, 0), (0, 128 - K)),
                                constant_values=-1))
-    lor64, hist64 = wave_pass_pallas(jnp.asarray(X), jnp.asarray(vals),
+    lor64, hist64, _ = wave_pass_pallas(jnp.asarray(X), jnp.asarray(vals),
                                      jnp.asarray(lor), tbl16, K, B,
                                      interpret=True, wide_lo=64)
-    lor128, hist128 = wave_pass_pallas(jnp.asarray(X), jnp.asarray(vals),
+    lor128, hist128, _ = wave_pass_pallas(jnp.asarray(X), jnp.asarray(vals),
                                        jnp.asarray(lor), tbl16, K, B,
                                        interpret=True, wide_lo=128)
     np.testing.assert_array_equal(np.asarray(lor64), np.asarray(lor128))
@@ -362,7 +362,7 @@ def test_wave_pass_matches_reference():
     tbl16 = jnp.asarray(np.pad(tbl_np, ((0, 0), (0, 128 - K)), constant_values=-1))
 
     ref_lor, ref_hist = _ref_wave_pass(X, vals, lor, tbl, K, B)
-    got_lor, got_hist = wave_pass_pallas(
+    got_lor, got_hist, _ = wave_pass_pallas(
         jnp.asarray(X), jnp.asarray(vals), jnp.asarray(lor), tbl16, K, B,
         interpret=True)
     np.testing.assert_array_equal(np.asarray(got_lor), ref_lor)
@@ -390,7 +390,7 @@ def test_wave_pass_quantized_int8_exact():
     tbl16 = jnp.asarray(np.pad(tbl_np, ((0, 0), (0, 128 - K)), constant_values=-1))
     ref_lor, ref_hist = _ref_wave_pass(X, vals.astype(np.int64), lor, tbl,
                                        K, B)
-    got_lor, got_hist = wave_pass_pallas(
+    got_lor, got_hist, _ = wave_pass_pallas(
         jnp.asarray(X), jnp.asarray(vals), jnp.asarray(lor), tbl16, K, B,
         interpret=True)
     assert got_hist.dtype == jnp.int32

@@ -491,6 +491,72 @@ def test_wave_decide_trees_counts_the_in_kernel_route(monkeypatch, F,
         [2, 1] if decides else [0, 0])
 
 
+def _smaller_children_rows(tree, leaf_of_row):
+    """The rows of each split's smaller child, counted from the rows'
+    leaves; which child is the smaller one is the tree's own record
+    (left on a tie), as the grower chose it."""
+    def rec(c):
+        return tree.internal_count[c] if c >= 0 else tree.leaf_count[~c]
+
+    def leaves(c):
+        return [~c] if c < 0 else (leaves(tree.left_child[c])
+                                   + leaves(tree.right_child[c]))
+    rows = 0
+    for i in range(tree.num_leaves - 1):
+        lc, rc = tree.left_child[i], tree.right_child[i]
+        small = lc if rec(lc) <= rec(rc) else rc
+        rows += int(np.isin(leaf_of_row, leaves(small)).sum())
+    return rows
+
+
+@pytest.mark.parametrize("leaves,cat_cols", [
+    (3, ()),                     # a budget of one candidate a wave
+    (3, (0, 2)),                 # the categorical megakernel
+    (31, ()),                    # trees end by gain, under the budget
+])
+def test_wave_counts_recount_from_the_trees(monkeypatch, leaves, cat_cols):
+    """`waves`, `wave_slots` and `hist_rows` on train/chunk: the wave
+    megakernel's calls, their slots K and the rows its contractions took
+    (the root's N included), summed over the chunk's trees. The kernels
+    run under the interpreter. A tree whose speculated candidates were
+    all split contracted its root's rows and each split's smaller child:
+    at 3 leaves a wave may speculate one candidate (the budget), so each
+    split took one call of K = 1; at 31 leaves on 600 rows the trees end
+    by gain, and a call may hold several candidates."""
+    monkeypatch.setenv("LIGHTGBM_TPU_DISABLE_BATCHED", "0")
+    monkeypatch.setenv("LIGHTGBM_TPU_PALLAS_INTERPRET", "1")
+    rng = np.random.RandomState(leaves + len(cat_cols))
+    X = rng.normal(size=(600, 6)).astype(np.float32)
+    for c in cat_cols:
+        X[:, c] = rng.randint(0, 6, size=len(X))
+    y = (X[:, 1] + 0.5 * X[:, 3] > 0).astype(np.float64)
+    params = dict(PARAMS, num_leaves=leaves, max_bin=15,
+                  min_data_in_leaf=20, binning_impl="host")
+    before = len([r for r in P.spans() if r["name"] == "train/chunk"])
+    ds = lgb.Dataset(X, label=y, params=params,
+                     categorical_feature=list(cat_cols) or "auto")
+    bst = lgb.Booster(params=params, train_set=ds)
+    bst.update_batch(3, chunk=2)
+    assert bst._gbdt._wave_route() == "mega"
+    trees = bst._gbdt.models           # the drain fills the counts in
+    chunks = [r for r in P.spans() if r["name"] == "train/chunk"][before:]
+    assert [c["counts"]["trees"] for c in chunks] == [2, 1]
+    leaf = bst.predict(X, pred_leaf=True).reshape(len(X), -1)
+    for c, ts in zip(chunks, ((0, 1), (2,))):
+        got = c["counts"]
+        splits = sum(trees[t].num_leaves - 1 for t in ts)
+        assert got["hist_rows"] == sum(
+            len(X) + _smaller_children_rows(trees[t], leaf[:, t])
+            for t in ts), got
+        if leaves == 3:
+            assert all(trees[t].num_leaves == 3 for t in ts)
+            assert got["waves"] == got["wave_slots"] == splits, got
+        else:
+            assert all(trees[t].num_leaves < leaves for t in ts)
+            assert got["waves"] <= splits <= got["wave_slots"], got
+            assert got["waves"] < splits
+
+
 def test_per_iteration_training_opens_no_span_per_tree():
     """2000 iterations through train_one_iter leave the calls that came
     before them in the ring: the only spans of that path are the stop

@@ -34,11 +34,14 @@ def test_family_lowers_for_tpu(name):
 # the categorical bitset operand the categorical tables take: their
 # lowering for the TPU, byte for byte, is the one recorded before that
 # operand existed (sha256 of the exported module's text; JAX 0.9.0).
+# The megakernel's two were recorded again when it began to compact its
+# rows before the contraction (ops/histogram_pallas.py _stage_block);
+# wave_relabel and the in-kernel wave_apply kept theirs.
 NUMERIC_TEXT = {
     "megakernel B64 f32":
-        "6ab301573b194dd7734a5ecc0c20bd30d1754f396566cf54aa9f81c2b555e499",
+        "cf7c0b0d17b9886468bf46448265973b8af4ff502c644a0f71ecd6b19fc84410",
     "megakernel hi/lo B256 int8":
-        "4fd16f8eec4c12aed51e38367e8febdd3fea86d06ae8137b8e6b204c211b76e8",
+        "0b3184fe4a56d0bbab10987f105e41d879a002575ea9d012d754e193c965b21f",
     "wave_relabel B256 f32":
         "f679d4305e74ffdd79fa6fb96407d6bb68e39550533b387775ce061ac3ca5820",
     "wave_apply in-kernel F137":

@@ -227,7 +227,7 @@ def test_planted_fault_is_seen(monkeypatch):
     scan of bins 3 to 61, PR 31)."""
     def fault(name, out):
         if name == "wave_pass_pallas":
-            return out[0], out[1].at[..., 61].set(0)
+            return (out[0], out[1].at[..., 61].set(0)) + tuple(out[2:])
         return out
 
     regime = "f28_b63_megakernel"
@@ -400,7 +400,7 @@ def test_categorical_entries_match_the_select_chain(B, kernel):
     else:
         vals = rng.randint(-20, 20, size=(2, n)).astype(np.int8)
         in_bag = (rng.uniform(size=n) < 0.8).astype(np.float32)
-        got_lor, hist, counts = hpk.wave_pass_pallas(
+        got_lor, hist, counts, _ = hpk.wave_pass_pallas(
             jnp.asarray(X.astype(np.int8)), jnp.asarray(vals),
             jnp.asarray(lor), tbl, 8, B, interpret=True,
             wide_lo=64 if B > 128 else 128, cat=words,
@@ -414,3 +414,159 @@ def test_categorical_entries_match_the_select_chain(B, kernel):
         np.testing.assert_array_equal(np.asarray(counts), np.bincount(
             want_slot[(want_slot >= 0) & (in_bag > 0)], minlength=8))
     np.testing.assert_array_equal(np.asarray(got_lor), want_lor)
+
+
+# ---- the wave pass compacts the rows that carry weight, then contracts
+def _compact_case(kind, K, select, n, B, F, quantized, seed):
+    """Binned rows, a wave table with K candidates, values and in-bag rows
+    for one direct call of ``wave_pass_pallas``. ``select``: "some" (a
+    random share of the rows lands in a candidate's smaller child, some
+    of it moved there by an applied split), "none" or "all" (every row
+    of the table in a candidate leaf, every one going to the smaller
+    child). Categorical tables alternate numeric and bitset entries
+    ("all" takes numeric entries only: bin 0 never goes left under a
+    bitset)."""
+    rng = np.random.RandomState(seed)
+    X = rng.randint(0, B, size=(F, n)).astype(np.uint8)
+    X[:, ::11] = 0
+    table = np.full((16, 128), -1, np.int32)
+    nl0 = 200
+    app = [K + 1, K + 2, K + 3] if select == "some" else []
+    cand = list(range(K))
+    if select == "some":
+        # the first candidates are the applied splits' new right children
+        cand[:len(app)] = [nl0 + j for j in range(min(len(app), K))]
+    for base, leaves in ((0, app), (7, cand)):
+        m = len(leaves)
+        if not m:
+            continue
+        table[base, :m] = leaves
+        table[base + 1, :m] = rng.randint(0, F, size=m)
+        table[base + 2, :m] = rng.randint(1, B - 2, size=m)
+        table[base + 3, :m] = rng.randint(0, 2, size=m)
+        table[base + 4, :m] = rng.choice([0, 1, 2], size=m)
+        table[base + 5, :m] = rng.randint(0, B - 1, size=m)
+        table[base + 6, :m] = B
+    table[14, :K] = rng.randint(0, 2, size=K)
+    table[15, :] = nl0
+    if select == "all":
+        table[9, :K] = B - 1            # every row goes left ...
+        table[11, :K] = 0               # ... with no missing rule
+        table[14, :K] = 1               # ... into the smaller child
+        lor = rng.randint(0, K, size=n)
+    elif select == "none":
+        lor = rng.randint(K + 5, K + 40, size=n)
+    else:
+        lor = rng.randint(0, K + 6, size=n)
+    W = (B + 31) // 32
+    bits = rng.randint(0, 2 ** 32, size=(2, max(K, 4), W),
+                       dtype=np.uint64).astype(np.uint32)
+    bits[:, :, 0] &= ~np.uint32(1)      # bin 0 never goes left
+    is_cat = np.zeros((2, max(K, 4)), bool)
+    if kind == "cat" and select != "all":
+        is_cat[:, 1::2] = True
+    if quantized:
+        vals = rng.randint(-127, 128, size=(2, n)).astype(np.int8)
+    else:
+        vals = rng.normal(size=(2, n)).astype(np.float32)
+    in_bag = (rng.uniform(size=n) < 0.8).astype(np.float32)
+    return X, table, is_cat, bits, lor.astype(np.int32), vals, in_bag
+
+
+def _wave_reference(X, table, is_cat, bits, lor, K, cat):
+    """new leaf of every row and the slot of its candidate's smaller
+    child (-1: none), vectorised over rows (ops/grow_wave.py
+    table_go_left's rule; on a categorical table a bitset entry tests
+    its bin's bit and has no missing rule)."""
+    def go_left(rows, base, j, side):
+        f, thr, dl = table[base + 1, j], table[base + 2, j], table[base + 3, j]
+        mt, db, nb = table[base + 4, j], table[base + 5, j], table[base + 6, j]
+        b = X[f, rows].astype(np.int64)
+        if cat and is_cat[side, j]:
+            w = bits[side, j].astype(np.int64)
+            return ((w[b >> 5] >> (b & 31)) & 1) == 1
+        miss = ((mt == 1) & (b == db)) | ((mt == 2) & (b == nb - 1))
+        return np.where(miss, dl == 1, b <= thr)
+
+    new_lor, slot = lor.copy(), np.full(len(lor), -1, np.int32)
+    for j in np.flatnonzero(table[0] >= 0):
+        rows = np.flatnonzero(lor == table[0, j])
+        new_lor[rows[~go_left(rows, 0, j, 0)]] = table[15, 0] + j
+    for j in np.flatnonzero(table[7, :K] >= 0):
+        rows = np.flatnonzero(new_lor == table[7, j])
+        gl = go_left(rows, 7, j, 1)
+        slot[rows[gl == bool(table[14, j])]] = j
+    return new_lor, slot
+
+
+# id -> (table kind, K, selection, rows, B, F, quantized). 9,000 rows are
+# 5 row blocks with a tail (not a block multiple); "all" at 9,000 rows
+# fills the staging buffer's tile four times over. A categorical table
+# sums its rows in partitions (wave_parts): at 140,000 rows 32 partitions
+# of 3 blocks, so "all" there flushes a full tile inside every partition
+# and the rest at each partition's end.
+COMPACT_CASES = {
+    "numeric_f28_b64_k1": ("num", 1, "some", 9000, 64, 28, False),
+    "numeric_f28_b64_k64": ("num", 64, "some", 9000, 64, 28, False),
+    "numeric_f28_b64_k128": ("num", 128, "some", 9000, 64, 28, False),
+    "numeric_quantized_k64": ("num", 64, "some", 9000, 64, 28, True),
+    "numeric_hilo_b256_k8": ("num", 8, "some", 9000, 256, 9, True),
+    "numeric_none_selected": ("num", 8, "none", 9000, 64, 28, False),
+    "numeric_all_selected": ("num", 8, "all", 9000, 64, 28, True),
+    "numeric_small_table": ("num", 4, "some", 700, 32, 6, False),
+    "cat_f8_b248_k8": ("cat", 8, "some", 9000, 248, 8, False),
+    "cat_f8_b248_k128_quantized": ("cat", 128, "some", 9000, 248, 8, True),
+    "cat_none_selected": ("cat", 8, "none", 9000, 248, 8, True),
+    "cat_all_selected_partitions": ("cat", 8, "all", 140000, 64, 8, True),
+}
+
+
+@pytest.mark.parametrize("case", list(COMPACT_CASES))
+def test_compacted_wave_pass_matches_reference(case):
+    """``wave_pass_pallas`` under the Pallas interpreter, direct, against
+    a NumPy reference: every row's new leaf, the candidates' smaller
+    children's histograms (exact on int8 values; float32 within 1e-6 of
+    each bin's magnitude, the sum of its rows' |value| as bfloat16 holds
+    them: the compacted tiles add the same rows in another grouping), on
+    a categorical table each slot's in-bag rows, and the count of rows
+    that reached the contraction, which is the selected rows'."""
+    from lightgbm_tpu.ops import histogram_pallas as hpk
+    kind, K, select, n, B, F, quantized = COMPACT_CASES[case]
+    X, table, is_cat, bits, lor, vals, in_bag = _compact_case(
+        kind, K, select, n, B, F, quantized, seed=len(case))
+    cat = kind == "cat"
+    want_lor, want_slot = _wave_reference(X, table, is_cat, bits, lor, K,
+                                          cat)
+    tbl, kw = jnp.asarray(table), {}
+    if cat:
+        tbl, words = hpk.wave_cat_words(
+            tbl, jnp.asarray(is_cat[0, :4]), jnp.asarray(bits[0, :4]),
+            jnp.asarray(is_cat[1, :K]), jnp.asarray(bits[1, :K]))
+        kw = dict(cat=words, in_bag=jnp.asarray(in_bag))
+    out = hpk.wave_pass_pallas(
+        jnp.asarray(X.astype(np.int8)), jnp.asarray(vals), jnp.asarray(lor),
+        tbl, K, B, interpret=True, wide_lo=64 if B > 128 else 128, **kw)
+    np.testing.assert_array_equal(np.asarray(out[0]), want_lor)
+    sel = np.flatnonzero(want_slot >= 0)
+    assert {"none": 0, "all": n}.get(select, len(sel)) == len(sel)
+    assert int(out[-1]) == len(sel)
+    v = vals.astype(np.float64) if quantized else np.asarray(
+        jnp.asarray(vals).astype(jnp.bfloat16).astype(jnp.float32),
+        np.float64)
+    want = np.zeros((K, 2, F, B))
+    mag = np.zeros((K, 2, F, B))
+    for f in range(F):
+        for c in range(2):
+            np.add.at(want, (want_slot[sel], c, f, X[f, sel]), v[c, sel])
+            np.add.at(mag, (want_slot[sel], c, f, X[f, sel]),
+                      np.abs(v[c, sel]))
+    got = np.asarray(out[1], np.float64)
+    if quantized:
+        assert out[1].dtype == jnp.int32
+        np.testing.assert_array_equal(got, want)
+    else:
+        assert np.all(np.abs(got - want) <= 1e-6 * mag), \
+            np.max(np.abs(got - want) - 1e-6 * mag)
+    if cat:
+        np.testing.assert_array_equal(np.asarray(out[2]), np.bincount(
+            want_slot[(want_slot >= 0) & (in_bag > 0)], minlength=K))
